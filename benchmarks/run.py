@@ -15,6 +15,8 @@ import sys
 
 
 def main() -> None:
+    from repro.core import enable_persistent_cache
+    enable_persistent_cache()
     mods = sys.argv[1:] or ["figure3_gemm", "engine_sweep", "autotune_sweep",
                             "cnn_inference", "lm_step", "roofline_report"]
     print("name,us_per_call,derived")

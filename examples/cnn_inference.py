@@ -9,10 +9,11 @@ import jax.numpy as jnp
 from repro.configs.darknet_ref import (DARKNET19_CFG, DARKNET_SMALL_CFG,
                                        SEGNET_SMALL_CFG)
 from repro.core.darknet.network import Network
-from repro.core import make_engine
+from repro.core import enable_persistent_cache, make_engine
 
 
 def main():
+    enable_persistent_cache()
     engine = make_engine("xla", "fp32_strict")
 
     for name, cfg_text, shape in [

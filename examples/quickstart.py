@@ -12,8 +12,10 @@ import jax.numpy as jnp
 from repro.configs.base import get_arch, reduced
 from repro.configs.darknet_ref import DARKNET_SMALL_CFG
 from repro.core.darknet.network import Network
-from repro.core import list_backends, make_engine
+from repro.core import enable_persistent_cache, list_backends, make_engine
 from repro.models import transformer as tfm
+
+enable_persistent_cache()
 
 # --- 1. the engine: fused act((x@w)*scale+shift), fp32 strict -------------
 # Backends resolve through the op registry; add your own with

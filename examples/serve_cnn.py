@@ -15,7 +15,7 @@ import jax
 import numpy as np
 
 from repro.configs.darknet_ref import DARKNET_SMALL_CFG
-from repro.core import make_engine
+from repro.core import enable_persistent_cache, make_engine
 from repro.core.darknet.network import Network
 from repro.serve.frontend import CNNServingEngine, ImageRequest
 
@@ -23,6 +23,7 @@ BUCKETS = (1, 2, 4, 8)
 
 
 def main():
+    enable_persistent_cache()
     net = Network(DARKNET_SMALL_CFG, make_engine("xla", "fp32_strict"))
     params = net.init(jax.random.PRNGKey(0))
     cache = net.compile_cache(params, buckets=BUCKETS)

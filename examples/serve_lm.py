@@ -15,13 +15,14 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_arch, reduced
-from repro.core import make_engine
+from repro.core import enable_persistent_cache, make_engine
 from repro.models import transformer as tfm
 from repro.serve.engine import Request
 from repro.serve.scheduler import PagedServingEngine
 
 
 def main():
+    enable_persistent_cache()
     cfg = reduced(get_arch("qwen2-0.5b"))
     engine = make_engine("xla", "fp32_strict")
     params = tfm.init_params(jax.random.PRNGKey(0), cfg)

@@ -10,10 +10,12 @@ import argparse
 import dataclasses
 
 from repro.configs.base import get_arch
+from repro.core import enable_persistent_cache
 from repro.launch.train import train_loop
 
 
 def main():
+    enable_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=8)

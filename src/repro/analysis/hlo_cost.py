@@ -371,14 +371,3 @@ class Analyzer:
 def analyze(text: str) -> dict:
     return Analyzer(text).totals()
 
-
-def xla_cost_dict(compiled) -> dict:
-    """XLA's own ``Compiled.cost_analysis()`` as a flat dict.
-
-    Newer jax returns a per-module list (one entry per partitioned module);
-    older jax returns the dict directly.  Single compat point for every
-    caller (dry-run, calibration tests)."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost

@@ -37,6 +37,7 @@ import sys
 from typing import Any, Callable, Iterator
 
 import jax
+from jax.extend import core as jax_core
 
 SEVERITIES = ("error", "warning")
 
@@ -49,15 +50,15 @@ DEFAULT_CONST_THRESHOLD = 1 << 20
 # used to carry a private copy of these; they now live here so the linter and
 # the regression suite can never drift).
 
-def eqn_subjaxprs(eqn) -> Iterator["jax.core.Jaxpr"]:
+def eqn_subjaxprs(eqn) -> Iterator[jax_core.Jaxpr]:
     """Sub-jaxprs referenced by one equation's params (scan/while bodies,
     pjit/custom_vjp calls, interpret-mode pallas_call kernel bodies)."""
     for val in eqn.params.values():
         vals = val if isinstance(val, (tuple, list)) else [val]
         for sub in vals:
-            if isinstance(sub, jax.core.ClosedJaxpr):
+            if isinstance(sub, jax_core.ClosedJaxpr):
                 yield sub.jaxpr
-            elif isinstance(sub, jax.core.Jaxpr):
+            elif isinstance(sub, jax_core.Jaxpr):
                 yield sub
 
 
@@ -159,7 +160,7 @@ class LintContext:
     """
     label: str = ""
     backend: str = ""
-    jaxpr: Any = None                    # jax.core.ClosedJaxpr | None
+    jaxpr: Any = None                    # jax_core.ClosedJaxpr | None
     hlo_text: str | None = None          # compiled (optimized) HLO text
     op_log: tuple = ()                   # engine dispatch records (dicts)
     head_hints: tuple = ()               # ((H, KV, head_dim), ...) for R001

@@ -58,7 +58,8 @@ activation=linear
 [softmax]
 """
 
-# ImageNet-scale darknet-19 trunk (224x224) — used by the full benchmark.
+# Darknet-19 (darknet19.cfg, Redmon & Farhadi, "YOLO9000", 2017) at its
+# published widths: 19 convolutions, 224x224x3 -> 1000 classes.
 DARKNET19_CFG = """
 [net]
 height=224
@@ -102,7 +103,7 @@ batch_normalize=1
 filters=64
 size=1
 stride=1
-pad=0
+pad=1
 activation=leaky
 
 [convolutional]
@@ -130,7 +131,7 @@ batch_normalize=1
 filters=128
 size=1
 stride=1
-pad=0
+pad=1
 activation=leaky
 
 [convolutional]
@@ -158,7 +159,7 @@ batch_normalize=1
 filters=256
 size=1
 stride=1
-pad=0
+pad=1
 activation=leaky
 
 [convolutional]
@@ -168,12 +169,75 @@ size=3
 stride=1
 pad=1
 activation=leaky
+
+[convolutional]
+batch_normalize=1
+filters=256
+size=1
+stride=1
+pad=1
+activation=leaky
+
+[convolutional]
+batch_normalize=1
+filters=512
+size=3
+stride=1
+pad=1
+activation=leaky
+
+[maxpool]
+size=2
+stride=2
+
+[convolutional]
+batch_normalize=1
+filters=1024
+size=3
+stride=1
+pad=1
+activation=leaky
+
+[convolutional]
+batch_normalize=1
+filters=512
+size=1
+stride=1
+pad=1
+activation=leaky
+
+[convolutional]
+batch_normalize=1
+filters=1024
+size=3
+stride=1
+pad=1
+activation=leaky
+
+[convolutional]
+batch_normalize=1
+filters=512
+size=1
+stride=1
+pad=1
+activation=leaky
+
+[convolutional]
+batch_normalize=1
+filters=1024
+size=3
+stride=1
+pad=1
+activation=leaky
+
+[convolutional]
+filters=1000
+size=1
+stride=1
+pad=1
+activation=linear
 
 [avgpool]
-
-[connected]
-output=1000
-activation=linear
 
 [softmax]
 """

@@ -13,8 +13,9 @@ from repro.core.backends import (AUTOTUNE_POLICIES, OP_SET, autotune_policy,
                                  autotune_report, get_autotune_policy,
                                  get_backend, list_backends, register_backend,
                                  set_autotune_policy)
-from repro.core.compile_cache import (StepCompileCache, normalize_buckets,
-                                      pick_bucket)
+from repro.core.compile_cache import (StepCompileCache,
+                                      enable_persistent_cache,
+                                      normalize_buckets, pick_bucket)
 from repro.core.engine import ComputeEngine, make_engine
 from repro.core.precision import Precision
 from repro.core import shard_backend as _shard_backend  # noqa: F401
@@ -26,4 +27,5 @@ __all__ = ["ComputeEngine", "make_engine", "Precision", "OP_SET",
            "register_backend", "get_backend", "list_backends",
            "AUTOTUNE_POLICIES", "autotune_policy", "autotune_report",
            "get_autotune_policy", "set_autotune_policy",
-           "StepCompileCache", "normalize_buckets", "pick_bucket"]
+           "StepCompileCache", "enable_persistent_cache",
+           "normalize_buckets", "pick_bucket"]

@@ -15,7 +15,8 @@ docs/autotune.md), so second processes on the same device measure nothing.
 Built-in backends:
 
   pallas : the TPU-target kernels (kernels/gemm.py, flash_attention.py) with
-           explicit VMEM BlockSpec tiling — interpret=True runs them on CPU.
+           explicit VMEM BlockSpec tiling — compiled on a TPU, interpreted
+           on the CPU backend (kernels.common.default_interpret).
   xla    : jax.lax dot_general / jnp formulations with the same precision
            policy and the same fused epilogue, expressed so XLA fuses them.
 
@@ -79,7 +80,9 @@ def op_scope(op: str) -> str:
 class OpContext:
     """Per-dispatch context handed to backend op implementations."""
     precision: Precision
-    interpret: bool = True
+    # None: kernels derive interpret mode from the platform
+    # (kernels.common.default_interpret).
+    interpret: bool | None = None
     # (bm, bk, bn) for GEMM-shaped ops on tiled backends, (bq, bk)
     # sequence tiles for attention, () otherwise.
     tiles: tuple = ()
@@ -129,7 +132,7 @@ class Backend:
                 f"(has: {sorted(self.ops)})") from None
 
     def tiles(self, op: str, shapes: tuple, dtype, *,
-              interpret: bool = True) -> tuple:
+              interpret: bool | None = None) -> tuple:
         """Block plan for one dispatch, resolved through the autotune
         cache under the active policy (see `tile_plan`)."""
         if self.tile_picker is None:  # untiled backend: skip the cache
@@ -320,7 +323,7 @@ def autotune_policy(policy: str):
 
 
 def _measure_plan(key: tuple, picker, candidates, bench,
-                  interpret: bool) -> tuple | None:
+                  interpret: bool | None) -> tuple | None:
     """Measured resolution of a cache miss: persisted pick if the per-device
     table has one, else time candidates and persist the winner.  Returns
     None when the backend has nothing to measure for this op (e.g. the
@@ -357,7 +360,8 @@ def _measure_plan(key: tuple, picker, candidates, bench,
 
 def tile_plan(op: str, shapes: tuple, dtype, backend: str,
               picker: Callable[[str, tuple, Any], tuple], *,
-              candidates=None, bench=None, interpret: bool = True) -> tuple:
+              candidates=None, bench=None,
+              interpret: bool | None = None) -> tuple:
     """Block-shape pick keyed on (op, shapes, dtype, backend), resolved
     under the active autotune policy (see `set_autotune_policy`)."""
     dtype_str = str(jnp.dtype(dtype))
@@ -619,7 +623,7 @@ def _pallas_tile_candidates(op: str, shapes: tuple, dtype) -> list[tuple]:
 
 
 def _pallas_tile_bench(op: str, shapes: tuple, dtype, tiles: tuple,
-                       interpret: bool):
+                       interpret: bool | None):
     if op == "attention":
         return kernel_ops.attention_bench_thunk(
             *kernel_ops.attention_dims(shapes), dtype, tiles,

@@ -20,9 +20,33 @@ asserts against it.
 from __future__ import annotations
 
 import collections
+import os
+import pathlib
 from typing import Callable, Iterable
 
 import jax
+
+# The checkout root (src/repro/core/ -> three levels up): a fixed location,
+# because the cache directory is part of what a cached entry is found by.
+_CHECKOUT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here.  Otherwise the cache goes to ``<checkout>/.jax_cache``
+    — the same path on every call and in every process, so a second run of
+    any entry point finds the first one's executables.  Entry points call
+    this from their ``main``; importing a module never does, and tests never
+    do."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(_CHECKOUT_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def normalize_buckets(buckets: Iterable[int]) -> tuple[int, ...]:

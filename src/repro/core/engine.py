@@ -7,7 +7,7 @@ through the backend/op registry (core/backends.py), so adding an execution
 target is `register_backend(...)` — no engine changes.  Built-in backends:
 
   pallas : the TPU-target kernels with explicit VMEM BlockSpec tiling —
-           interpret=True executes them on CPU for tests.
+           compiled on a TPU, interpreted on the CPU backend.
   xla    : jax.lax formulations with the same precision policy and the same
            fused epilogue, expressed so XLA fuses them.  Used where Pallas
            cannot lower (the 512-host-device dry-run on the CPU backend) and
@@ -38,7 +38,9 @@ class ComputeEngine:
     bm: int = 0
     bk: int = 0
     bn: int = 0
-    interpret: bool = True  # CPU container; False on real TPU
+    # None derives it from the platform (kernels.common.default_interpret);
+    # an explicit bool is for compiling against a described chip.
+    interpret: bool | None = None
 
     # ---------------------------------------------------------- dispatch ---
     def _resolve(self, op: str, shapes: tuple, dtype) -> backends.OpContext:
@@ -208,7 +210,7 @@ class ComputeEngine:
 # Default engines.  Dry-run/bench lowering uses XLA backend (Pallas cannot
 # lower on the CPU backend); kernel tests and the TPU target use pallas.
 def make_engine(backend: str = "xla", policy: str = "fp32_strict",
-                interpret: bool = True, **tiles) -> ComputeEngine:
+                interpret: bool | None = None, **tiles) -> ComputeEngine:
     backends.get_backend(backend)  # fail fast on unknown backends
     return ComputeEngine(backend=backend, precision=Precision(policy),
                          interpret=interpret, **tiles)

@@ -18,6 +18,26 @@ import functools
 import jax
 import jax.numpy as jnp
 
+
+def default_interpret() -> bool:
+    """Whether Pallas kernels run in the interpreter, derived from the
+    platform: True on the CPU backend (tests), False on a TPU.  Any other
+    platform raises RuntimeError — no kernel mode is guessed for it."""
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(f"Pallas kernels have no execution mode for platform "
+                       f"{platform!r} (supported: cpu, tpu)")
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """An explicit `interpret` wins (compile tests pass False on a CPU host
+    against a described chip); None derives it from the platform."""
+    return default_interpret() if interpret is None else bool(interpret)
+
+
 # Activations supported by the fused epilogue.  Darknet's default conv
 # activation is leaky ReLU with slope 0.1; LM blocks use silu/gelu.
 _LEAKY_SLOPE = 0.1
@@ -87,29 +107,28 @@ def im2col(x, kh: int, kw: int, stride: int, pad: int):
     """x: (B, H, W, C) -> patches (B, OH, OW, kh*kw*C).
 
     The canonical Darknet conv lowering: materialize patches, GEMM on the
-    engine.  Shared by every backend's im2col-based conv2d op.
+    engine.  Shared by every backend's im2col-based conv2d op.  Patches are
+    strided slices of the padded input — exact copies on every platform
+    (a patches convolution would run on the TPU's MXU at the default
+    precision and round every input to bf16).
 
     Carries a custom VJP whose backward is a col2im scatter-add (the
-    `deconv2d` idiom): patch cotangents accumulate back onto the input
-    positions each tap read.  This keeps conv2d's dL/dinput free of
-    `conv_general_dilated` equations — JAX's native transpose of
-    `conv_general_dilated_patches` would emit one outside any registry
-    dispatch scope, failing the R002 backward-trace gate.
+    `deconv2d` idiom) accumulated in fp32: patch cotangents accumulate back
+    onto the input positions each tap read.
     """
     return _im2col_fwd_impl(x, kh, kw, stride, pad)
 
 
 def _im2col_fwd_impl(x, kh, kw, stride, pad):
-    patches = jax.lax.conv_general_dilated_patches(
-        x, (kh, kw), (stride, stride), [(pad, pad), (pad, pad)],
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    # conv_general_dilated_patches returns channel-major (C, kh, kw) feature
-    # order; normalize to (kh, kw, C) to match HWIO weight layout.
-    b, oh, ow, _ = patches.shape
-    c = x.shape[-1]
-    patches = patches.reshape(b, oh, ow, c, kh * kw)
-    patches = jnp.swapaxes(patches, -1, -2)  # (..., kh*kw, C)
-    return patches.reshape(b, oh, ow, kh * kw * c)
+    _, h, w, _ = x.shape
+    oh = (h + 2 * pad - kh) // stride + 1
+    ow = (w + 2 * pad - kw) // stride + 1
+    xp = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
+    # Tap-major, channel-minor: (kh, kw, C) order, the HWIO weight layout.
+    return jnp.concatenate(
+        [xp[:, ki:ki + (oh - 1) * stride + 1:stride,
+            kj:kj + (ow - 1) * stride + 1:stride, :]
+         for ki in range(kh) for kj in range(kw)], axis=-1)
 
 
 def col2im(g, x_shape: tuple, kh: int, kw: int, stride: int, pad: int):

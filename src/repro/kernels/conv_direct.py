@@ -28,14 +28,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _COMPILER_PARAMS = getattr(pltpu, "CompilerParams",
-                               getattr(pltpu, "TPUCompilerParams", None))
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _COMPILER_PARAMS = None
+from repro.kernels.common import resolve_interpret
 
 
 def _conv_kernel(x_ref, w_ref, o_ref, *, kh: int, kw: int, th: int,
@@ -72,7 +67,7 @@ def _band_kernel(x_ref, w_ref, o_ref, *, kh: int, kw: int, th: int,
     o_ref[0, 0] = acc.reshape(th, ow, cout).astype(o_ref.dtype)
 
 
-def conv2d_direct(x, w, *, th: int = 8, interpret: bool = True):
+def conv2d_direct(x, w, *, th: int = 8, interpret: bool | None = None):
     """x: (B, H, W, Cin) pre-padded; w: (KH, KW, Cin, Cout).
 
     VALID conv, stride 1 -> (B, H-KH+1, W-KW+1, Cout).
@@ -94,10 +89,6 @@ def conv2d_direct(x, w, *, th: int = 8, interpret: bool = True):
         [jax.lax.dynamic_slice_in_dim(x, i * th, th + KH - 1, axis=1)
          for i in range(n_bands)], axis=1)   # (B, n_bands, th+KH-1, W, Cin)
     grid = (B, n_bands)
-    compiler_params = None
-    if not interpret and _COMPILER_PARAMS is not None:
-        compiler_params = _COMPILER_PARAMS(
-            dimension_semantics=("parallel", "parallel"))
     kernel = functools.partial(_band_kernel, kh=KH, kw=KW, th=th, ow=OW)
     out = pl.pallas_call(
         kernel,
@@ -110,7 +101,8 @@ def conv2d_direct(x, w, *, th: int = 8, interpret: bool = True):
         out_specs=pl.BlockSpec((1, 1, th, OW, Cout),
                                lambda b, i: (b, i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, n_bands, th, OW, Cout), x.dtype),
-        interpret=interpret,
-        **({"compiler_params": compiler_params} if compiler_params else {}),
+        interpret=resolve_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
     )(bands, w)
     return out.reshape(B, OH_pad, OW, Cout)[:, :OH]

@@ -55,17 +55,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _COMPILER_PARAMS = getattr(pltpu, "CompilerParams",
-                               getattr(pltpu, "TPUCompilerParams", None))
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _COMPILER_PARAMS = None
+from repro.kernels.common import resolve_interpret
 
 _NEG_INF = -1e30
 _LANES = 128  # stats scratch is lane-replicated for TPU vector layout
+
+# (batch*head, query tile) programs are independent; the innermost axis
+# carries the online-softmax / gradient accumulators.
+_SEMANTICS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+# Per-batch live extents (B,) int32 sit whole in scalar memory: a kernel
+# reads its row's extent as a scalar, and no (1, 1) VMEM block of a (B, 1)
+# array (a layout the TPU compiler refuses) is ever needed.
+KV_LEN_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _dot(a, b, dims):
@@ -75,11 +80,11 @@ def _dot(a, b, dims):
 
 
 def _flash_kernel(*refs, nk: int, bq: int, bk: int, sm_scale: float,
-                  causal: bool, q_offset: int, q_len: int,
+                  causal: bool, q_offset: int, q_len: int, heads: int,
                   has_kv_len: bool, return_lse: bool):
     if has_kv_len:
         q_ref, k_ref, v_ref, kvl_ref, *rest = refs
-        kv_len = kvl_ref[0, 0]
+        kv_len = kvl_ref[pl.program_id(0) // heads]
     else:
         q_ref, k_ref, v_ref, *rest = refs
         kv_len = None
@@ -147,12 +152,11 @@ def _flash_kernel(*refs, nk: int, bq: int, bk: int, sm_scale: float,
         o_ref[0, 0] = (acc_ref[...] / lsafe).astype(o_ref.dtype)
         if return_lse:
             # Per-row softmax residual m + log(l) in the *scaled* score
-            # space; fully-masked rows store 0 — any finite value works,
-            # since the backward forces their probability tiles to exact 0.
+            # space, one (bq, 1) column; fully-masked rows store 0 — any
+            # finite value works, since the backward forces their
+            # probability tiles to exact 0.
             m = m_ref[...][:, :1]
-            lse = jnp.where(l[:, 0] > 0.0, m[:, 0] + jnp.log(lsafe[:, 0]),
-                            0.0)
-            lse_ref[0, 0] = lse
+            lse_ref[0, 0] = jnp.where(l > 0.0, m + jnp.log(lsafe), 0.0)
 
 
 def _bwd_mask(*, i, j, bq, bk, causal, q_offset, kv_len):
@@ -182,7 +186,7 @@ def _bwd_block_live(*, i, j, bq, bk, causal, q_offset, kv_len):
 
 
 def _flash_bwd_dq_kernel(*refs, nk: int, bq: int, bk: int, sm_scale: float,
-                         causal: bool, q_offset: int, q_len: int,
+                         causal: bool, q_offset: int, q_len: int, heads: int,
                          has_kv_len: bool):
     """dQ = (P ∘ (dO Vᵀ − Δ)) K · sm_scale, streamed over KV tiles.
 
@@ -194,7 +198,7 @@ def _flash_bwd_dq_kernel(*refs, nk: int, bq: int, bk: int, sm_scale: float,
     if has_kv_len:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, kvl_ref,
          dq_ref, acc_ref) = refs
-        kv_len = kvl_ref[0, 0]
+        kv_len = kvl_ref[pl.program_id(0) // heads]
     else:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dq_ref, acc_ref) = refs
@@ -212,8 +216,8 @@ def _flash_bwd_dq_kernel(*refs, nk: int, bq: int, bk: int, sm_scale: float,
         k = k_ref[0, 0].astype(jnp.float32)        # (bk, d)
         v = v_ref[0, 0].astype(jnp.float32)        # (bk, d)
         do = do_ref[0, 0].astype(jnp.float32)      # (bq, d)
-        lse = lse_ref[0, 0].astype(jnp.float32)[:, None]      # (bq, 1)
-        delta = delta_ref[0, 0].astype(jnp.float32)[:, None]  # (bq, 1)
+        lse = lse_ref[0, 0]                        # (bq, 1) fp32
+        delta = delta_ref[0, 0]                    # (bq, 1) fp32
         s = _dot(q, k, ((1,), (1,))) * sm_scale    # (bq, bk)
         live = _bwd_mask(i=i, j=j, bq=bq, bk=bk, causal=causal,
                          q_offset=q_offset, kv_len=kv_len)
@@ -238,7 +242,7 @@ def _flash_bwd_dq_kernel(*refs, nk: int, bq: int, bk: int, sm_scale: float,
 
 def _flash_bwd_dkv_kernel(*refs, nq: int, nt: int, bq: int, bk: int,
                           sm_scale: float, causal: bool, q_offset: int,
-                          q_len: int, has_kv_len: bool):
+                          q_len: int, heads: int, has_kv_len: bool):
     """dV = Pᵀ dO and dK = (P ∘ (dO Vᵀ − Δ))ᵀ Q · sm_scale per kv tile.
 
     One program per (b, KV-HEAD, kv tile): the innermost grid axis sweeps
@@ -250,7 +254,7 @@ def _flash_bwd_dkv_kernel(*refs, nq: int, nt: int, bq: int, bk: int,
     if has_kv_len:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, kvl_ref,
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
-        kv_len = kvl_ref[0, 0]
+        kv_len = kvl_ref[pl.program_id(0) // heads]
     else:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
@@ -270,8 +274,8 @@ def _flash_bwd_dkv_kernel(*refs, nq: int, nt: int, bq: int, bk: int,
         k = k_ref[0, 0].astype(jnp.float32)        # (bk, d)
         v = v_ref[0, 0].astype(jnp.float32)        # (bk, d)
         do = do_ref[0, 0].astype(jnp.float32)      # (bq, d)
-        lse = lse_ref[0, 0].astype(jnp.float32)[:, None]      # (bq, 1)
-        delta = delta_ref[0, 0].astype(jnp.float32)[:, None]  # (bq, 1)
+        lse = lse_ref[0, 0]                        # (bq, 1) fp32
+        delta = delta_ref[0, 0]                    # (bq, 1) fp32
         s = _dot(q, k, ((1,), (1,))) * sm_scale    # (bq, bk)
         live = _bwd_mask(i=i, j=j, bq=bq, bk=bk, causal=causal,
                          q_offset=q_offset, kv_len=kv_len)
@@ -314,27 +318,15 @@ class _Config:
     bwd_key: tuple | None = None
 
 
-def _compiler_params(cfg: _Config):
-    if cfg.interpret or _COMPILER_PARAMS is None:
-        return {}
-    return {"compiler_params": _COMPILER_PARAMS(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))}
-
-
 def _forward(cfg: _Config, q, k, v, kvl, *, return_lse: bool):
     b, h, sq, d = q.shape
     _, kvh, skv, _ = k.shape
     grp = h // kvh
     bq, bk = cfg.bq, cfg.bk
     grid = (b * h, sq // bq, skv // bk)
-    scratch = []
-    if pltpu is not None:
-        scratch = [pltpu.VMEM((bq, _LANES), jnp.float32),   # m
-                   pltpu.VMEM((bq, _LANES), jnp.float32),   # l
-                   pltpu.VMEM((bq, d), jnp.float32)]        # acc
     kernel = functools.partial(
         _flash_kernel, nk=grid[2], bq=bq, bk=bk, sm_scale=cfg.sm_scale,
-        causal=cfg.causal, q_offset=cfg.q_offset, q_len=cfg.q_len,
+        causal=cfg.causal, q_offset=cfg.q_offset, q_len=cfg.q_len, heads=h,
         has_kv_len=kvl is not None, return_lse=return_lse)
     q_spec = pl.BlockSpec((1, 1, bq, d), lambda g, i, j: (g // h, g % h, i, 0))
     kv_spec = pl.BlockSpec((1, 1, bk, d),
@@ -342,24 +334,29 @@ def _forward(cfg: _Config, q, k, v, kvl, *, return_lse: bool):
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [q, k, v]
     if kvl is not None:
-        in_specs.append(pl.BlockSpec((1, 1), lambda g, i, j: (g // h, 0)))
+        in_specs.append(KV_LEN_SPEC)
         operands.append(kvl)
     out_specs = q_spec
     out_shape = jax.ShapeDtypeStruct((b, h, sq, d), q.dtype)
     if return_lse:
-        lse_spec = pl.BlockSpec((1, 1, bq), lambda g, i, j: (g // h, g % h, i))
+        # lse is stored as a (bq, 1) column per tile: a (1, bq) row block
+        # of a (B, H, Sq) array would put the head axis in the sublane dim.
+        lse_spec = pl.BlockSpec((1, 1, bq, 1),
+                                lambda g, i, j: (g // h, g % h, i, 0))
         out_specs = [q_spec, lse_spec]
         out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((b, h, sq), jnp.float32)]
+                     jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32)]
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((bq, _LANES), jnp.float32),   # m
+                        pltpu.VMEM((bq, _LANES), jnp.float32),   # l
+                        pltpu.VMEM((bq, d), jnp.float32)],       # acc
         interpret=cfg.interpret,
-        **_compiler_params(cfg),
+        compiler_params=_SEMANTICS,
     )(*operands)
     return out if return_lse else (out, None)
 
@@ -398,23 +395,23 @@ def _backward(cfg: _Config, q, k, v, kvl, do, lse, delta):
     q_spec = pl.BlockSpec((1, 1, bq, d), lambda g, i, j: (g // h, g % h, i, 0))
     kv_spec = pl.BlockSpec((1, 1, bk, d),
                            lambda g, i, j: (g // h, (g % h) // grp, j, 0))
-    row_spec = pl.BlockSpec((1, 1, bq), lambda g, i, j: (g // h, g % h, i))
+    row_spec = pl.BlockSpec((1, 1, bq, 1),
+                            lambda g, i, j: (g // h, g % h, i, 0))
     in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
     operands = [q, k, v, do, lse, delta]
     if has_kvl:
-        in_specs.append(pl.BlockSpec((1, 1), lambda g, i, j: (g // h, 0)))
+        in_specs.append(KV_LEN_SPEC)
         operands.append(kvl)
-    scratch = [pltpu.VMEM((bq, d), jnp.float32)] if pltpu is not None else []
     nk = skv // bk
     dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, nk=nk, **common),
+        functools.partial(_flash_bwd_dq_kernel, nk=nk, heads=h, **common),
         grid=(b * h, sq // bq, nk),
         in_specs=in_specs,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=cfg.interpret,
-        **_compiler_params(cfg),
+        compiler_params=_SEMANTICS,
     )(*operands)
 
     # dK/dV: one program per kv-head; the innermost axis walks the G query
@@ -427,27 +424,25 @@ def _backward(cfg: _Config, q, k, v, kvl, do, lse, delta):
     kvh_spec = pl.BlockSpec((1, 1, bk, d),
                             lambda n, jk, t: (n // kvh, n % kvh, jk, 0))
     rowh_spec = pl.BlockSpec(
-        (1, 1, bq),
-        lambda n, jk, t: (n // kvh, (n % kvh) * grp + t // nq, t % nq))
+        (1, 1, bq, 1),
+        lambda n, jk, t: (n // kvh, (n % kvh) * grp + t // nq, t % nq, 0))
     in_specs = [qh_spec, kvh_spec, kvh_spec, qh_spec, rowh_spec, rowh_spec]
     operands = [q, k, v, do, lse, delta]
     if has_kvl:
-        in_specs.append(pl.BlockSpec((1, 1), lambda n, jk, t: (n // kvh, 0)))
+        in_specs.append(KV_LEN_SPEC)
         operands.append(kvl)
-    scratch = []
-    if pltpu is not None:
-        scratch = [pltpu.VMEM((bk, d), jnp.float32),
-                   pltpu.VMEM((bk, d), jnp.float32)]
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, nq=nq, nt=nt, **common),
+        functools.partial(_flash_bwd_dkv_kernel, nq=nq, nt=nt, heads=kvh,
+                          **common),
         grid=(b * kvh, skv // bk, nt),
         in_specs=in_specs,
         out_specs=[kvh_spec, kvh_spec],
         out_shape=[jax.ShapeDtypeStruct((b, kvh, skv, d), k.dtype),
                    jax.ShapeDtypeStruct((b, kvh, skv, d), v.dtype)],
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32)],
         interpret=cfg.interpret,
-        **_compiler_params(cfg),
+        compiler_params=_SEMANTICS,
     )(*operands)
     return dq, dk, dv
 
@@ -473,7 +468,7 @@ def _flash_vjp_bwd(cfg: _Config, res, do):
         # needed.  Fully-masked rows have O == 0, so delta == 0 there by
         # construction.
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                        axis=-1)
+                        axis=-1, keepdims=True)
         dq, dk, dv = _backward(cfg, q, k, v, kvl, do, lse, delta)
     # kv_len is integer-valued: its cotangent is the symbolic zero float0.
     kvl_ct = (None if kvl is None
@@ -487,7 +482,7 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 def flash_attention(q, k, v, *, causal: bool = True, sm_scale=None,
                     bq: int = 256, bk: int = 256, kv_len=None,
                     q_offset: int | None = None, q_len: int = 0,
-                    interpret: bool = True, bq_bwd: int = 0,
+                    interpret: bool | None = None, bq_bwd: int = 0,
                     bk_bwd: int = 0, bwd_key: tuple | None = None):
     """q: (B, H, Sq, D); k, v: (B, KV, Skv, D) with H % KV == 0.
 
@@ -495,9 +490,9 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale=None,
     h // (H // KV) — the same kv*G+g head order as the grouped reshape
     ``(B, S, KV, G, D)``; H == KV is plain MHA.  Sq % bq == 0 and
     Skv % bk == 0 (the ops wrapper pads and passes ``kv_len`` to mask the
-    key padding).  ``kv_len``: optional (B, 1) int32 — keys at positions
-    >= kv_len are masked out for that batch row (key padding, decode
-    cache extent).
+    key padding).  ``kv_len``: optional (B,) or (B, 1) int32, read from
+    scalar memory — keys at positions >= kv_len are masked out for that
+    batch row (key padding, decode cache extent).
 
     Causal alignment: queries right-align against the LIVE key extent.
     Without kv_len that is Skv (``q_offset`` overrides it statically — the
@@ -527,19 +522,18 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale=None,
         sm_scale = 1.0 / (d ** 0.5)
     if q_offset is None:
         q_offset = skv - sq
-    kvl = (None if kv_len is None
-           else kv_len.astype(jnp.int32).reshape(b, 1))
+    kvl = None if kv_len is None else kv_len.astype(jnp.int32).reshape(b)
     cfg = _Config(causal=causal, sm_scale=float(sm_scale), bq=bq, bk=bk,
                   bq_bwd=bq_bwd, bk_bwd=bk_bwd, q_offset=q_offset,
-                  q_len=q_len if q_len else sq, interpret=interpret,
-                  bwd_key=bwd_key)
+                  q_len=q_len if q_len else sq,
+                  interpret=resolve_interpret(interpret), bwd_key=bwd_key)
     return _flash(cfg, q, k, v, kvl)
 
 
 def flash_attention_with_lse(q, k, v, *, causal: bool = True, sm_scale=None,
                              bq: int = 256, bk: int = 256, kv_len=None,
                              q_offset: int | None = None, q_len: int = 0,
-                             interpret: bool = True):
+                             interpret: bool | None = None):
     """Forward-only flash attention that also emits the softmax residual.
 
     Same operand/masking contract as `flash_attention`; returns
@@ -564,9 +558,10 @@ def flash_attention_with_lse(q, k, v, *, causal: bool = True, sm_scale=None,
         sm_scale = 1.0 / (d ** 0.5)
     if q_offset is None:
         q_offset = skv - sq
-    kvl = (None if kv_len is None
-           else kv_len.astype(jnp.int32).reshape(b, 1))
+    kvl = None if kv_len is None else kv_len.astype(jnp.int32).reshape(b)
     cfg = _Config(causal=causal, sm_scale=float(sm_scale), bq=bq, bk=bk,
                   bq_bwd=0, bk_bwd=0, q_offset=q_offset,
-                  q_len=q_len if q_len else sq, interpret=interpret)
-    return _forward(cfg, q, k, v, kvl, return_lse=True)
+                  q_len=q_len if q_len else sq,
+                  interpret=resolve_interpret(interpret))
+    o, lse = _forward(cfg, q, k, v, kvl, return_lse=True)
+    return o, lse[..., 0]

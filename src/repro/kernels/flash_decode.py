@@ -51,8 +51,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.flash_attention import (_COMPILER_PARAMS, _LANES,
-                                           _NEG_INF, _dot, pltpu)
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.common import resolve_interpret
+from repro.kernels.flash_attention import (_LANES, _NEG_INF, _SEMANTICS,
+                                           KV_LEN_SPEC, _dot)
 
 # The lse value an empty (fully-masked) KV span reports; `combine` weighs
 # such partials to zero.  Cross-device partial emitters
@@ -62,11 +65,12 @@ EMPTY_SPAN_LSE = _NEG_INF
 
 def _decode_kernel(q_ref, k_ref, v_ref, kvl_ref, o_ref, lse_ref,
                    m_ref, l_ref, acc_ref, *, nj: int, bq: int, bk: int,
-                   span: int, sm_scale: float, causal: bool, q_len: int):
+                   span: int, sm_scale: float, causal: bool, q_len: int,
+                   heads: int):
     """One (batch*head, split) program: online softmax over the split's
     span of KV blocks, emitting the span's partial (o, lse)."""
     s_idx, j = pl.program_id(1), pl.program_id(2)
-    kv_len = kvl_ref[0, 0]
+    kv_len = kvl_ref[pl.program_id(0) // heads]
     base = s_idx * span + j * bk          # global start of this KV block
 
     @pl.when(j == 0)
@@ -112,22 +116,20 @@ def _decode_kernel(q_ref, k_ref, v_ref, kvl_ref, o_ref, lse_ref,
         # never round-trips through a narrow operand dtype.
         o_ref[0, 0, 0] = acc_ref[...] / lsafe
         m = m_ref[...][:, :1]
-        # Span logsumexp in the scaled score space; empty spans emit the
-        # _NEG_INF sentinel the merge weighs to zero.
-        lse = jnp.where(l[:, 0] > 0.0, m[:, 0] + jnp.log(lsafe[:, 0]),
-                        _NEG_INF)
-        lse_ref[0, 0, 0] = lse
+        # Span logsumexp in the scaled score space, one (bq, 1) column;
+        # empty spans emit the _NEG_INF sentinel the merge weighs to zero.
+        lse_ref[0, 0, 0] = jnp.where(l > 0.0, m + jnp.log(lsafe), _NEG_INF)
 
 
 def flash_decode(q, k, v, kv_len, *, causal: bool = True, sm_scale=None,
                  bk: int = 256, n_splits: int = 4, q_len: int = 0,
-                 interpret: bool = True):
+                 interpret: bool | None = None):
     """q: (B, H, Sq, D); k, v: (B, KV, Skv, D) with H % KV == 0.
 
     Split-KV decode: Skv must equal ``n_splits * span`` with
     ``span % bk == 0`` (the ops wrapper pads and masks via ``kv_len``).
-    ``kv_len`` is REQUIRED — (B, 1) int32 live extents (padding and cache
-    masking ride the same operand).  Causal queries right-align against
+    ``kv_len`` is REQUIRED — (B,) or (B, 1) int32 live extents, read from
+    scalar memory (padding and cache masking ride the same operand).  Causal queries right-align against
     ``kv_len`` with ``q_len`` real rows (padded rows are sliced off by the
     caller).  Returns (B, H, Sq, D) fp32 — partials and the logsumexp
     merge never leave fp32; the caller casts.
@@ -146,38 +148,32 @@ def flash_decode(q, k, v, kv_len, *, causal: bool = True, sm_scale=None,
     kernel = functools.partial(
         _decode_kernel, nj=nj, bq=sq, bk=bk, span=span,
         sm_scale=float(sm_scale), causal=causal,
-        q_len=q_len if q_len else sq)
+        q_len=q_len if q_len else sq, heads=h)
     q_spec = pl.BlockSpec((1, 1, sq, d), lambda g, s, j: (g // h, g % h, 0, 0))
     kv_spec = pl.BlockSpec(
         (1, 1, bk, d),
         lambda g, s, j, nj=nj: (g // h, (g % h) // grp, s * nj + j, 0))
-    kvl_spec = pl.BlockSpec((1, 1), lambda g, s, j: (g // h, 0))
     o_spec = pl.BlockSpec((1, 1, 1, sq, d),
                           lambda g, s, j: (g // h, g % h, s, 0, 0))
-    lse_spec = pl.BlockSpec((1, 1, 1, sq),
-                            lambda g, s, j: (g // h, g % h, s, 0))
-    scratch = []
-    if pltpu is not None:
-        scratch = [pltpu.VMEM((sq, _LANES), jnp.float32),   # m
-                   pltpu.VMEM((sq, _LANES), jnp.float32),   # l
-                   pltpu.VMEM((sq, d), jnp.float32)]        # acc
-    compiler_params = {}
-    if not interpret and _COMPILER_PARAMS is not None:
-        compiler_params = {"compiler_params": _COMPILER_PARAMS(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))}
+    # Per-split lse as a (sq, 1) column: a (1, sq) row block would put the
+    # split axis in the sublane dim, which the TPU compiler refuses.
+    lse_spec = pl.BlockSpec((1, 1, 1, sq, 1),
+                            lambda g, s, j: (g // h, g % h, s, 0, 0))
     o_part, lse_part = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec, kvl_spec],
+        in_specs=[q_spec, kv_spec, kv_spec, KV_LEN_SPEC],
         out_specs=[o_spec, lse_spec],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, n_splits, sq, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, n_splits, sq), jnp.float32)],
-        scratch_shapes=scratch,
-        interpret=interpret,
-        **compiler_params,
-    )(q, k, v, kv_len)
-    return combine(o_part, lse_part)
+            jax.ShapeDtypeStruct((b, h, n_splits, sq, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((sq, _LANES), jnp.float32),   # m
+                        pltpu.VMEM((sq, _LANES), jnp.float32),   # l
+                        pltpu.VMEM((sq, d), jnp.float32)],       # acc
+        interpret=resolve_interpret(interpret),
+        compiler_params=_SEMANTICS,
+    )(q, k, v, kv_len.astype(jnp.int32).reshape(b))
+    return combine(o_part, lse_part[..., 0])
 
 
 def combine(o_part, lse_part):

@@ -48,16 +48,16 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import act_deriv, apply_act
+from repro.kernels.common import act_deriv, apply_act, resolve_interpret
 
-try:  # TPU compiler params: name moved across jax versions.
-    from jax.experimental.pallas import tpu as pltpu
-    _COMPILER_PARAMS = getattr(pltpu, "CompilerParams",
-                               getattr(pltpu, "TPUCompilerParams", None))
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _COMPILER_PARAMS = None
+# M/N tiles are independent (parallel); K carries the accumulator.
+_SEMANTICS_2D = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+# Batched GEMMs add a leading parallel batch grid dim.
+_SEMANTICS_3D = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
 # The backward's dispatch scope: contains backends.OP_SCOPE_PREFIX
 # ("repro.op."), so the R002 trace-lint rule accepts the backward kernels'
@@ -122,13 +122,6 @@ class _Config:
     batched: bool = False  # bmm: keys tagged "bdx"/"bdw", batch grid dim
 
 
-def _compiler_params(interpret: bool, semantics: tuple):
-    if interpret or _COMPILER_PARAMS is None:
-        return {}
-    return {"compiler_params": _COMPILER_PARAMS(
-        dimension_semantics=semantics)}
-
-
 def _gemm_forward(cfg: _Config, x, w, scale, shift, *, residuals: bool):
     """Run the fused forward kernel; with ``residuals``, additionally emit
     g = act'(pre-act) (when an activation is fused) and the raw fp32
@@ -182,21 +175,15 @@ def _gemm_forward(cfg: _Config, x, w, scale, shift, *, residuals: bool):
                      acc_ref, nsteps=grid[2], act=cfg.act,
                      out_dtype=out_dtype)
 
-    scratch = []
-    if pltpu is not None:
-        scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
-
     out = pl.pallas_call(
         kernel_fn,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=cfg.interpret,
-        # M/N tiles are independent (parallel); K carries the accumulator.
-        **_compiler_params(cfg.interpret,
-                           ("parallel", "parallel", "arbitrary")),
+        compiler_params=_SEMANTICS_2D,
     )(*args)
     y = out[0]
     idx = 1
@@ -234,7 +221,7 @@ def _bwd_matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, nsteps: int,
 
 
 def gemm_bwd_dx(dy, w, *, bm: int, bk: int, bn: int, out_dtype=None,
-                interpret: bool = True):
+                interpret: bool | None = None):
     """dX[m, k] = Σ_n dY[m, n] · W[k, n] — the input-gradient GEMM.
 
     dy: (M, N), w: (K, N) → (M, K).  Backward-problem tile roles:
@@ -247,7 +234,6 @@ def gemm_bwd_dx(dy, w, *, bm: int, bk: int, bn: int, out_dtype=None,
         f"dx problem {(m, n, k)} vs blocks {(bm, bk, bn)}")
     out_dtype = out_dtype or dy.dtype
     grid = (m // bm, k // bn, n // bk)
-    scratch = [pltpu.VMEM((bm, bn), jnp.float32)] if pltpu is not None else []
     call = pl.pallas_call(
         functools.partial(_bwd_matmul_kernel, nsteps=grid[2], grid_axis=2,
                           dims=((1,), (1,)), out_dtype=out_dtype),
@@ -258,15 +244,15 @@ def gemm_bwd_dx(dy, w, *, bm: int, bk: int, bn: int, out_dtype=None,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, k), out_dtype),
-        scratch_shapes=scratch,
-        interpret=interpret,
-        **_compiler_params(interpret, ("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        interpret=resolve_interpret(interpret),
+        compiler_params=_SEMANTICS_2D,
     )
     return call(dy, w)
 
 
 def gemm_bwd_dw(x, dy, *, bm: int, bk: int, bn: int, out_dtype=None,
-                interpret: bool = True):
+                interpret: bool | None = None):
     """dW[k, n] = Σ_m X[m, k] · dY[m, n] — the weight-gradient GEMM.
 
     x: (M, K), dy: (M, N) → (K, N).  Backward-problem tile roles:
@@ -279,7 +265,6 @@ def gemm_bwd_dw(x, dy, *, bm: int, bk: int, bn: int, out_dtype=None,
         f"dw problem {(k, m, n)} vs blocks {(bm, bk, bn)}")
     out_dtype = out_dtype or x.dtype
     grid = (k // bm, n // bn, m // bk)
-    scratch = [pltpu.VMEM((bm, bn), jnp.float32)] if pltpu is not None else []
     call = pl.pallas_call(
         functools.partial(_bwd_matmul_kernel, nsteps=grid[2], grid_axis=2,
                           dims=((0,), (0,)), out_dtype=out_dtype),
@@ -290,22 +275,21 @@ def gemm_bwd_dw(x, dy, *, bm: int, bk: int, bn: int, out_dtype=None,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((k, n), out_dtype),
-        scratch_shapes=scratch,
-        interpret=interpret,
-        **_compiler_params(interpret, ("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        interpret=resolve_interpret(interpret),
+        compiler_params=_SEMANTICS_2D,
     )
     return call(x, dy)
 
 
 def bmm_bwd_dx(dy, w, *, bm: int, bk: int, bn: int, out_dtype=None,
-               interpret: bool = True):
+               interpret: bool | None = None):
     """Batched dX: (B, M, N) × (B, K, N) → (B, M, K), per-batch grid dim."""
     b, m, n = dy.shape
     _, k, _ = w.shape
     assert m % bm == 0 and n % bk == 0 and k % bn == 0
     out_dtype = out_dtype or dy.dtype
     grid = (b, m // bm, k // bn, n // bk)
-    scratch = [pltpu.VMEM((bm, bn), jnp.float32)] if pltpu is not None else []
     call = pl.pallas_call(
         functools.partial(_bwd_matmul_kernel, nsteps=grid[3], grid_axis=3,
                           dims=((1,), (1,)), out_dtype=out_dtype),
@@ -316,23 +300,21 @@ def bmm_bwd_dx(dy, w, *, bm: int, bk: int, bn: int, out_dtype=None,
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda g, i, j, s: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((b, m, k), out_dtype),
-        scratch_shapes=scratch,
-        interpret=interpret,
-        **_compiler_params(interpret,
-                           ("parallel", "parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        interpret=resolve_interpret(interpret),
+        compiler_params=_SEMANTICS_3D,
     )
     return call(dy, w)
 
 
 def bmm_bwd_dw(x, dy, *, bm: int, bk: int, bn: int, out_dtype=None,
-               interpret: bool = True):
+               interpret: bool | None = None):
     """Batched dW: (B, M, K) × (B, M, N) → (B, K, N), per-batch grid dim."""
     b, m, k = x.shape
     _, _, n = dy.shape
     assert k % bm == 0 and m % bk == 0 and n % bn == 0
     out_dtype = out_dtype or x.dtype
     grid = (b, k // bm, n // bn, m // bk)
-    scratch = [pltpu.VMEM((bm, bn), jnp.float32)] if pltpu is not None else []
     call = pl.pallas_call(
         functools.partial(_bwd_matmul_kernel, nsteps=grid[3], grid_axis=3,
                           dims=((0,), (0,)), out_dtype=out_dtype),
@@ -343,10 +325,9 @@ def bmm_bwd_dw(x, dy, *, bm: int, bk: int, bn: int, out_dtype=None,
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda g, i, j, s: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((b, k, n), out_dtype),
-        scratch_shapes=scratch,
-        interpret=interpret,
-        **_compiler_params(interpret,
-                           ("parallel", "parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        interpret=resolve_interpret(interpret),
+        compiler_params=_SEMANTICS_3D,
     )
     return call(x, dy)
 
@@ -435,7 +416,7 @@ _gemm.defvjp(_gemm_vjp_fwd, _gemm_vjp_bwd)
 
 def gemm(x, w, *, scale=None, shift=None, act: str = "linear",
          out_dtype=None, bm: int = 256, bk: int = 512, bn: int = 256,
-         interpret: bool = True, bwd_key: tuple | None = None,
+         interpret: bool | None = None, bwd_key: tuple | None = None,
          bwd_dx: tuple = (), bwd_dw: tuple = ()):
     """Fused tiled GEMM: act((x @ w) * scale + shift).
 
@@ -460,7 +441,7 @@ def gemm(x, w, *, scale=None, shift=None, act: str = "linear",
     out_dtype = jnp.dtype(out_dtype or x.dtype)
     cfg = _Config(act=act, out_dtype=str(out_dtype), bm=bm, bk=bk, bn=bn,
                   has_scale=scale is not None, has_shift=shift is not None,
-                  interpret=interpret, bwd_key=bwd_key,
+                  interpret=resolve_interpret(interpret), bwd_key=bwd_key,
                   bwd_dx=tuple(bwd_dx), bwd_dw=tuple(bwd_dw))
     sp = None if scale is None else scale.reshape(1, n).astype(jnp.float32)
     bp = None if shift is None else shift.reshape(1, n).astype(jnp.float32)
@@ -475,7 +456,6 @@ def _bmm_forward(cfg: _Config, x, w):
     bm, bk, bn = cfg.bm, cfg.bk, cfg.bn
     out_dtype = jnp.dtype(cfg.out_dtype)
     grid = (b, m // bm, n // bn, k // bk)
-    scratch = [pltpu.VMEM((bm, bn), jnp.float32)] if pltpu is not None else []
     call = pl.pallas_call(
         functools.partial(_bwd_matmul_kernel, nsteps=grid[3], grid_axis=3,
                           dims=((1,), (0,)), out_dtype=out_dtype),
@@ -486,10 +466,9 @@ def _bmm_forward(cfg: _Config, x, w):
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda g, i, j, s: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((b, m, n), out_dtype),
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=cfg.interpret,
-        **_compiler_params(cfg.interpret,
-                           ("parallel", "parallel", "parallel", "arbitrary")),
+        compiler_params=_SEMANTICS_3D,
     )
     return call(x, w)
 
@@ -522,7 +501,7 @@ _bmm.defvjp(_bmm_vjp_fwd, _bmm_vjp_bwd)
 
 
 def bmm(x, w, *, out_dtype=None, bm: int = 256, bk: int = 256, bn: int = 256,
-        interpret: bool = True, bwd_key: tuple | None = None,
+        interpret: bool | None = None, bwd_key: tuple | None = None,
         bwd_dx: tuple = (), bwd_dw: tuple = ()):
     """Batched GEMM (B, M, K) @ (B, K, N) with per-batch grid dimension.
 
@@ -538,6 +517,6 @@ def bmm(x, w, *, out_dtype=None, bm: int = 256, bk: int = 256, bn: int = 256,
     out_dtype = jnp.dtype(out_dtype or x.dtype)
     cfg = _Config(act="linear", out_dtype=str(out_dtype), bm=bm, bk=bk,
                   bn=bn, has_scale=False, has_shift=False,
-                  interpret=interpret, bwd_key=bwd_key,
+                  interpret=resolve_interpret(interpret), bwd_key=bwd_key,
                   bwd_dx=tuple(bwd_dx), bwd_dw=tuple(bwd_dw), batched=True)
     return _bmm(cfg, x, w)

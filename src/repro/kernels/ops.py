@@ -176,7 +176,7 @@ def validate_attention_tiles(sq: int, skv: int, d: int, dtype,
 
 
 def bench_thunk(op: str, m: int, k: int, n: int, dtype,
-                tiles: tuple[int, int, int], *, interpret: bool = True):
+                tiles: tuple[int, int, int], *, interpret: bool | None = None):
     """Zero-arg thunk running one compiled call of the op's GEMM problem
     with pinned block shapes — the measurement unit for the autotuner
     (core/autotune.py times it with warmup + median-of-k).
@@ -240,7 +240,7 @@ def candidate_gemm_bwd_blocks(variant: str, rows: int, kdim: int, cols: int,
 
 def gemm_bwd_bench_thunk(variant: str, rows: int, kdim: int, cols: int,
                          dtype, tiles: tuple[int, int, int], *,
-                         interpret: bool = True):
+                         interpret: bool | None = None):
     """Measurement unit for a gemm_bwd candidate: one compiled call of the
     RAW backward kernel with pinned tiles on the padded problem.  Timing
     the kernel directly (not `jax.grad` of the forward) keeps the timed
@@ -362,7 +362,7 @@ def candidate_attention_blocks(b: int, sq: int, skv: int, h: int, kv: int,
 
 def attention_bench_thunk(b: int, sq: int, skv: int, h: int, kv: int,
                           d: int, dtype, tiles: tuple[int, int], *,
-                          interpret: bool = True):
+                          interpret: bool | None = None):
     """Zero-arg thunk running one compiled grouped-attention call with
     pinned (bq, bk) — the attention measurement unit for the autotuner.
     Benched causal (the prefill hot path); operands are zeros, which is
@@ -421,7 +421,7 @@ def candidate_attention_bwd_blocks(b: int, sq: int, skv: int, h: int,
 
 def attention_bwd_bench_thunk(b: int, sq: int, skv: int, h: int, kv: int,
                               d: int, dtype, tiles: tuple[int, int], *,
-                              interpret: bool = True):
+                              interpret: bool | None = None):
     """Measurement unit for a backward candidate: one compiled
     `jax.grad` of the causal grouped wrapper with the backward tiles
     PINNED (so the timed trace never re-enters the autotune cache) and
@@ -547,7 +547,7 @@ def validate_attention_decode_tiles(sq: int, skv: int, d: int, dtype,
 
 def attention_decode_bench_thunk(b: int, sq: int, skv: int, h: int, kv: int,
                                  d: int, dtype, tiles: tuple[int, int], *,
-                                 interpret: bool = True):
+                                 interpret: bool | None = None):
     """Measurement unit for a decode candidate: one compiled split-KV
     call with pinned (bk_split, n_splits) against a full-extent cache
     (kv_len = Skv, the worst-case live decode).  Zero operands are fair
@@ -564,7 +564,7 @@ def attention_decode_bench_thunk(b: int, sq: int, skv: int, h: int, kv: int,
     jax.jit, static_argnames=("causal", "bk_split", "n_splits", "interpret"))
 def attention_decode(q, k, v, kv_len=None, sm_scale=None, *,
                      causal: bool = True, bk_split: int = 0,
-                     n_splits: int = 0, interpret: bool = True):
+                     n_splits: int = 0, interpret: bool | None = None):
     """Split-KV flash-decoding attention, arbitrary sequence lengths.
 
     Same operand contract as `attention` — q (B, Sq, H, D), k/v compact
@@ -609,7 +609,8 @@ def attention_decode(q, k, v, kv_len=None, sm_scale=None, *,
     return o[:, :, :sq].transpose(0, 2, 1, 3).astype(q.dtype)
 
 
-def _cached_attention_decode_blocks(shapes: tuple, dtype, interpret: bool
+def _cached_attention_decode_blocks(shapes: tuple, dtype,
+                                    interpret: bool | None
                                     ) -> tuple[int, int]:
     """Default (bk_split, n_splits) pick, resolved through the registry's
     autotune cache under the lazy ("attention_decode",
@@ -672,7 +673,7 @@ def normalize_kv_len(kv_len, b: int, skv: int):
                               "interpret"))
 def attention(q, k, v, kv_len=None, sm_scale=None, *, causal: bool = True,
               bq: int = 0, bk: int = 0, bq_bwd: int = 0, bk_bwd: int = 0,
-              interpret: bool = True):
+              interpret: bool | None = None):
     """Grouped flash attention on the engine, arbitrary sequence lengths.
 
     q: (B, Sq, H, D); k, v: (B, Skv, KV, D) with KV <= H, H % KV == 0 —
@@ -726,7 +727,7 @@ def attention(q, k, v, kv_len=None, sm_scale=None, *, causal: bool = True,
 
 @functools.partial(jax.jit, static_argnames=("causal", "interpret"))
 def attention_partial(q, k, v, kv_len, sm_scale=None, *, causal: bool = True,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     """Forward-only partial attention over ONE KV span: returns (o, lse).
 
     The sequence-split building block (kernels/sharded.py): q (B, Sq, H, D)
@@ -776,7 +777,7 @@ def attention_partial(q, k, v, kv_len, sm_scale=None, *, causal: bool = True,
     return o, lse
 
 
-def _cached_attention_blocks(shapes: tuple, dtype, interpret: bool
+def _cached_attention_blocks(shapes: tuple, dtype, interpret: bool | None
                              ) -> tuple[int, int]:
     """Default (bq, bk) pick for direct `attention` calls, resolved through
     the registry's autotune cache under the same ("attention",
@@ -786,8 +787,8 @@ def _cached_attention_blocks(shapes: tuple, dtype, interpret: bool
                                                 interpret=interpret)
 
 
-def _cached_blocks(op: str, m: int, k: int, n: int, dtype, interpret: bool
-                   ) -> tuple[int, int, int]:
+def _cached_blocks(op: str, m: int, k: int, n: int, dtype,
+                   interpret: bool | None) -> tuple[int, int, int]:
     """Default block pick, resolved through the registry's autotune cache
     (same hooks and cache key as engine dispatch, so both paths agree and
     the "measure" policy covers direct kernel calls too).
@@ -806,7 +807,7 @@ def _cached_blocks(op: str, m: int, k: int, n: int, dtype, interpret: bool
                      "bwd_dx", "bwd_dw"))
 def matmul(x, w, scale=None, shift=None, *, act: str = "linear",
            out_dtype=None, bm: int = 0, bk: int = 0, bn: int = 0,
-           interpret: bool = True, bwd_dx: tuple = (), bwd_dw: tuple = ()):
+           interpret: bool | None = None, bwd_dx: tuple = (), bwd_dw: tuple = ()):
     """Fused GEMM on the compute engine, arbitrary (M, K) x (K, N).
 
     DIFFERENTIABLE end-to-end: the kernel carries a custom VJP (backward
@@ -836,7 +837,7 @@ def matmul(x, w, scale=None, shift=None, *, act: str = "linear",
     jax.jit, static_argnames=("out_dtype", "bm", "bk", "bn", "interpret",
                               "bwd_dx", "bwd_dw"))
 def bmm(x, w, *, out_dtype=None, bm: int = 0, bk: int = 0, bn: int = 0,
-        interpret: bool = True, bwd_dx: tuple = (), bwd_dw: tuple = ()):
+        interpret: bool | None = None, bwd_dx: tuple = (), bwd_dw: tuple = ()):
     """Batched GEMM (B, M, K) @ (B, K, N) on the engine.
 
     DIFFERENTIABLE via the same custom-VJP machinery as `matmul` —

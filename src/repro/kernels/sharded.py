@@ -37,7 +37,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import flash_decode as decode_kernel
@@ -69,62 +68,69 @@ def _axis_size(mesh, axes) -> int:
 
 
 def _shmap(body, mesh, in_specs, out_specs):
-    # check_rep=False: pallas_call has no replication rule, and every body
-    # here is replication-correct by construction (outputs either carry the
-    # sharded axis or are all-gathered).
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    # check_vma=False: pallas_call has no varying-manual-axes rule, and
+    # every body here is replication-correct by construction (outputs
+    # either carry the sharded axis or are all-gathered).
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
+def _on_mesh(body, mesh, args, specs, out_spec):
+    """`body(*args)` inside shard_map over `mesh`, each arg split by its
+    spec (P() replicates).  None args reach `body` as None and take no
+    spec.  On a multi-device mesh every kernel call goes through here, even
+    when nothing divides: the SPMD partitioner cannot split a Mosaic
+    kernel, so a replicated shard_map is what runs it whole per device."""
+    live = [i for i, a in enumerate(args) if a is not None]
+
+    def wrapped(*xs):
+        full = [None] * len(args)
+        for i, x in zip(live, xs):
+            full[i] = x
+        return body(*full)
+
+    return _shmap(wrapped, mesh, tuple(specs[i] for i in live),
+                  out_spec)(*(args[i] for i in live))
 
 
 # ------------------------------------------------------------------ GEMMs ---
 
 def matmul(x, w, scale=None, shift=None, *, act: str = "linear",
-           out_dtype=None, interpret: bool = True):
+           out_dtype=None, interpret: bool | None = None):
     """Row-sharded fused GEMM: (M, K) rows over the batch axes, w and the
     (N,) epilogue vectors replicated, output rows sharded — zero
     collectives.  M is the flattened token axis, so conv-as-im2col rows
-    shard here too.  Falls back to `ops.matmul` when off-mesh or the axes
-    don't divide M."""
-    plan = mesh_plan()
-    n = _axis_size(plan[0], plan[1]) if plan else 1
-    if plan is None or n <= 1 or x.shape[0] % n:
+    shard here too.  Off-mesh this is `ops.matmul`; on a mesh whose batch
+    axes don't divide M every device runs the whole GEMM."""
+    def body(x, w, scale, shift):
         return kernel_ops.matmul(x, w, scale, shift, act=act,
                                  out_dtype=out_dtype, interpret=interpret)
-    mesh, batch, _ = plan
-    args, specs = [x, w], [P(batch, None), P(None, None)]
-    has_scale, has_shift = scale is not None, shift is not None
-    if has_scale:
-        args.append(scale)
-        specs.append(P(None))
-    if has_shift:
-        args.append(shift)
-        specs.append(P(None))
 
-    def body(x, w, *rest):
-        it = iter(rest)
-        s = next(it) if has_scale else None
-        sh = next(it) if has_shift else None
-        return kernel_ops.matmul(x, w, s, sh, act=act, out_dtype=out_dtype,
-                                 interpret=interpret)
-
-    return _shmap(body, mesh, tuple(specs), P(batch, None))(*args)
-
-
-def bmm(x, w, *, out_dtype=None, interpret: bool = True):
-    """Batch-sharded (B, M, K) @ (B, K, N): both operands shard B over the
-    batch axes.  Falls back to `ops.bmm` when off-mesh or B doesn't
-    divide."""
     plan = mesh_plan()
-    n = _axis_size(plan[0], plan[1]) if plan else 1
-    if plan is None or n <= 1 or x.shape[0] % n:
-        return kernel_ops.bmm(x, w, out_dtype=out_dtype, interpret=interpret)
+    if plan is None:
+        return body(x, w, scale, shift)
     mesh, batch, _ = plan
+    n = _axis_size(mesh, batch)
+    rows = batch if (n > 1 and x.shape[0] % n == 0) else None
+    return _on_mesh(body, mesh, (x, w, scale, shift),
+                    (P(rows, None), P(None, None), P(None), P(None)),
+                    P(rows, None))
 
+
+def bmm(x, w, *, out_dtype=None, interpret: bool | None = None):
+    """Batch-sharded (B, M, K) @ (B, K, N): both operands shard B over the
+    batch axes.  Off-mesh this is `ops.bmm`; on a mesh whose batch axes
+    don't divide B every device runs the whole product."""
     def body(x, w):
         return kernel_ops.bmm(x, w, out_dtype=out_dtype, interpret=interpret)
 
-    spec = P(batch, None, None)
-    return _shmap(body, mesh, (spec, spec), spec)(x, w)
+    plan = mesh_plan()
+    if plan is None:
+        return body(x, w)
+    mesh, batch, _ = plan
+    n = _axis_size(mesh, batch)
+    spec = P(batch if (n > 1 and x.shape[0] % n == 0) else None, None, None)
+    return _on_mesh(body, mesh, (x, w), (spec, spec), spec)
 
 
 # -------------------------------------------------------------- attention ---
@@ -144,7 +150,7 @@ def _local_attention(q, k, v, kv_len, sm_scale, *, causal, interpret):
 
 
 def attention(q, k, v, kv_len=None, sm_scale=None, *, causal: bool = True,
-              interpret: bool = True):
+              interpret: bool | None = None):
     """Mesh-sharded grouped attention; operand contract of `ops.attention`.
 
     Batch rows shard over the strategy's batch axes and/or KV-head groups
@@ -155,7 +161,8 @@ def attention(q, k, v, kv_len=None, sm_scale=None, *, causal: bool = True,
     logsumexp combine across devices.  Differentiable on the batch/heads
     paths (the kernel's custom VJP flows through shard_map); the
     seq-split path is inference-only, like the split-KV formulation it
-    generalizes."""
+    generalizes.  When nothing divides, every device runs the whole
+    problem."""
     kernel_ops.validate_attention_shapes(q, k, v)
     b, sq, h, d = q.shape
     _, skv, kvh, _ = k.shape
@@ -177,27 +184,21 @@ def attention(q, k, v, kv_len=None, sm_scale=None, *, causal: bool = True,
     heads = model if (model and kvh % mesh.shape[model] == 0) else None
     kvl = (None if kv_len is None else jnp.broadcast_to(
         jnp.asarray(kv_len, jnp.int32).reshape(-1), (b,)))
-    if batch or heads:
-        bspec = batch if batch else None
-        spec = P(bspec, None, heads, None)
-        args, specs = [q, k, v], [spec, spec, spec]
-        if kvl is not None:
-            args.append(kvl)
-            specs.append(P(bspec))
-
-        def body(q, k, v, kvl=None):
-            return _local_attention(q, k, v, kvl, sm_scale, causal=causal,
-                                    interpret=interpret)
-
-        return _shmap(body, mesh, tuple(specs), spec)(*args)
     seq_axes = tuple(a for a in mesh.axis_names if mesh.shape[a] > 1)
     n_s = _axis_size(mesh, seq_axes)
-    if (n_s > 1 and skv % n_s == 0
+    if (not (batch or heads) and n_s > 1 and skv % n_s == 0
             and kernel_ops.use_decode_formulation(sq, skv)):
         return _seq_split_attention(q, k, v, kvl, sm_scale, mesh, seq_axes,
                                     causal=causal, interpret=interpret)
-    return _local_attention(q, k, v, kvl, sm_scale, causal=causal,
-                            interpret=interpret)
+
+    def body(q, k, v, kvl):
+        return _local_attention(q, k, v, kvl, sm_scale, causal=causal,
+                                interpret=interpret)
+
+    bspec = batch if batch else None
+    spec = P(bspec, None, heads, None)
+    return _on_mesh(body, mesh, (q, k, v, kvl), (spec, spec, spec,
+                                                 P(bspec)), spec)
 
 
 def _seq_split_attention(q, k, v, kvl, sm_scale, mesh, axes, *, causal,

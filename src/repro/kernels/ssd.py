@@ -26,14 +26,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _COMPILER_PARAMS = getattr(pltpu, "CompilerParams",
-                               getattr(pltpu, "TPUCompilerParams", None))
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _COMPILER_PARAMS = None
+from repro.kernels.common import resolve_interpret
 
 
 def _ssd_kernel(x_ref, dt_ref, da_ref, b_ref, c_ref, y_ref, st_ref, *,
@@ -78,7 +73,8 @@ def _ssd_kernel(x_ref, dt_ref, da_ref, b_ref, c_ref, y_ref, st_ref, *,
     st_ref[...] = st_new
 
 
-def ssd_scan(x, dt, dA, B, C, *, chunk: int = 128, interpret: bool = True):
+def ssd_scan(x, dt, dA, B, C, *, chunk: int = 128,
+             interpret: bool | None = None):
     """x: (BH, S, P); dt, dA: (BH, S); B, C: (BH, S, N) -> y (BH, S, P).
 
     S % chunk == 0 (the ops wrapper pads with dt=0 rows — exact, as in
@@ -88,11 +84,6 @@ def ssd_scan(x, dt, dA, B, C, *, chunk: int = 128, interpret: bool = True):
     N = B.shape[-1]
     assert S % chunk == 0, (S, chunk)
     grid = (BH, S // chunk)
-    scratch = [pltpu.VMEM((P, N), jnp.float32)] if pltpu is not None else []
-    compiler_params = None
-    if not interpret and _COMPILER_PARAMS is not None:
-        compiler_params = _COMPILER_PARAMS(
-            dimension_semantics=("parallel", "arbitrary"))
     kernel = functools.partial(_ssd_kernel, nq=grid[1], Q=chunk)
     return pl.pallas_call(
         kernel,
@@ -106,7 +97,8 @@ def ssd_scan(x, dt, dA, B, C, *, chunk: int = 128, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((1, chunk, P), lambda g, j: (g, j, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, P), x.dtype),
-        scratch_shapes=scratch,
-        interpret=interpret,
-        **({"compiler_params": compiler_params} if compiler_params else {}),
+        scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
+        interpret=resolve_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
     )(x, dt, dA, B, C)

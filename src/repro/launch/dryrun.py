@@ -26,7 +26,6 @@ from repro.analysis import roofline as rl
 from repro.configs.base import (ARCH_IDS, SHAPES, cell_supported, get_arch,
                                 input_specs)
 from repro.core import make_engine
-from repro.launch import mesh as mesh_mod
 from repro.launch.mesh import make_production_mesh
 from repro.models import transformer as tfm
 from repro.serve import kvcache
@@ -81,7 +80,7 @@ def lower_cell(arch_id: str, shape_id: str, *, multi_pod: bool = False,
               "chips": chips, "policy": policy_name, "fsdp": fsdp,
               "kind": shape.kind, "num_microbatches": num_microbatches,
               "strategy": strategy, "moe_dispatch": cfg.moe_dispatch}
-    with mesh_mod.set_mesh(mesh), hints.strategy(strategy):
+    with jax.set_mesh(mesh), hints.strategy(strategy):
         pspecs = policy.param_pspecs(cfg, mesh, fsdp=fsdp,
                                      strategy=strategy)
         params_sh = _named(mesh, pspecs)
@@ -143,7 +142,7 @@ def lower_cell(arch_id: str, shape_id: str, *, multi_pod: bool = False,
         # XLA's cost_analysis undercounts while bodies (counted once);
         # recorded for reference, the roofline uses the trip-count-aware
         # analyzer (analysis/hlo_cost.py).
-        cost = hlo_cost.xla_cost_dict(compiled)
+        cost = compiled.cost_analysis()
         record["xla_cost"] = {"flops": float(cost.get("flops", 0.0)),
                               "bytes": float(cost.get("bytes accessed",
                                                       0.0))}

@@ -3,32 +3,23 @@ importing this module must not touch jax device state)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-    _AXIS_KW = lambda n: {"axis_types": (AxisType.Auto,) * n}
-except ImportError:  # older jax: Auto is the only behaviour
-    _AXIS_KW = lambda n: {}
+
+def _auto_axes(n: int) -> dict:
+    return {"axis_types": (AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """(16, 16) = 256 chips/pod; multi_pod adds a leading pod axis (512)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_AXIS_KW(len(axes)))
+    return jax.make_mesh(shape, axes, **_auto_axes(len(axes)))
 
 
 def make_mesh(shape: tuple, axes: tuple):
     """Arbitrary mesh (elastic restarts, tests)."""
-    return jax.make_mesh(shape, axes, **_AXIS_KW(len(axes)))
-
-
-def set_mesh(mesh):
-    """Context manager installing `mesh`.  jax >= 0.5 has jax.set_mesh; on
-    older jax the Mesh object itself is the context manager."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    return jax.make_mesh(shape, axes, **_auto_axes(len(axes)))
 
 
 def dp_size(mesh) -> int:

@@ -61,14 +61,8 @@ def attn_shard_mode(cfg) -> str:
     else 'seq' (query-sequence parallel; GSPMD all-gathers KV)."""
     if cfg.is_mla:
         return "heads"
-    names = hints._current_axis_names()
-    if "model" not in names:
-        return "heads"  # no mesh: modes identical (hints are no-ops)
-    try:
-        tp = jax.sharding.get_abstract_mesh().shape["model"]
-    except Exception:  # pragma: no cover
-        return "heads"
-    return "heads" if cfg.n_kv_heads % tp == 0 else "seq"
+    # No 'model' axis: size 1, modes identical (hints are no-ops).
+    return "heads" if cfg.n_kv_heads % hints.axis_size("model") == 0 else "seq"
 
 
 # ------------------------------------------------------------------- init ---

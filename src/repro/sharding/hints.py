@@ -16,6 +16,7 @@ from __future__ import annotations
 import contextlib
 
 import jax
+from jax._src import mesh as mesh_lib
 from jax.sharding import PartitionSpec as P
 
 # Distribution strategy (set by the launcher, read at trace time):
@@ -47,29 +48,37 @@ def batch_axes() -> tuple:
 
 
 def physical_mesh():
-    """The installed CONCRETE device mesh (``with mesh:`` /
-    `launch.mesh.set_mesh`), or None off-mesh.  Unlike the abstract mesh an
-    allocation-free trace installs, the physical mesh carries real devices —
-    it is the mesh `shard_map`-based backends (core/shard_backend.py,
-    kernels/sharded.py) wrap kernels over."""
-    try:
-        phys = jax.interpreters.pxla.thread_resources.env.physical_mesh
-    except Exception:  # pragma: no cover - pxla internals moved
-        return None
-    if phys is None or getattr(phys, "empty", True):
-        return None
-    return phys
+    """The installed CONCRETE device mesh, or None off-mesh: the mesh
+    `jax.set_mesh` installs, else the one a ``with mesh:`` block (what
+    `use_mesh` does) installs.  Unlike the abstract mesh an allocation-free
+    trace installs, the physical mesh carries real devices — it is the mesh
+    `shard_map`-based backends (core/shard_backend.py, kernels/sharded.py)
+    wrap kernels over.  Readable inside a jit trace, where
+    `jax.sharding.get_mesh` refuses."""
+    for mesh in (mesh_lib.get_concrete_mesh(),
+                 mesh_lib.thread_resources.env.physical_mesh):
+        if not mesh.empty:
+            return mesh
+    return None
+
+
+def _current_mesh():
+    """The abstract mesh when one is installed (`jax.set_mesh`, an
+    allocation-free trace), else the physical mesh, else None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return physical_mesh() if mesh.empty else mesh
 
 
 def _current_axis_names():
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:  # older jax: fall back to the physical mesh context
-        mesh = None
-    if mesh is not None and not getattr(mesh, "empty", False):
-        return tuple(mesh.axis_names)
-    phys = physical_mesh()
-    return tuple(phys.axis_names) if phys is not None else ()
+    mesh = _current_mesh()
+    return () if mesh is None else tuple(mesh.axis_names)
+
+
+def axis_size(name: str) -> int:
+    """Size of mesh axis `name` in the installed mesh (1 when absent or
+    off-mesh)."""
+    mesh = _current_mesh()
+    return 1 if mesh is None else dict(mesh.shape).get(name, 1)
 
 
 def mesh_active() -> bool:

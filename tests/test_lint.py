@@ -75,7 +75,7 @@ def test_r002_scope_inherited_through_kernel_call():
 
 
 def test_r003_fires_on_fp64_leak():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         traced = jax.jit(lambda x: x * jnp.float64(2.0)).trace(
             jnp.zeros((4,), jnp.float64))
     report = lint.run_lint(lint.LintContext(jaxpr=traced.jaxpr))

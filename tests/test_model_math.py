@@ -11,7 +11,7 @@ except ImportError:  # optional dev dep — property tests skip without it
 
 from repro.core import backends, make_engine
 from repro.kernels import ref as kref
-from repro.launch.mesh import make_mesh, set_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import ssm as ssm_mod
 from repro.models.attention import (blockwise_attention, gqa_forward,
                                     gqa_init, mla_forward, mla_init)
@@ -202,7 +202,7 @@ def test_gqa_prefill_routes_through_registry_attention_at_every_scale():
     assert off_counts.get(("xla", "attention")) == 1
 
     mesh = make_mesh((1,), ("data",))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         snap = backends.dispatch_counts()
         y_on = gqa_forward(ENGINE, p, x, cos, sin, cfg)
         on_counts = backends.counts_since(snap)

@@ -9,7 +9,6 @@ sizes, so none of this needs the forced device-count flag.
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.sharding import hints
@@ -95,17 +94,13 @@ def test_current_axis_names_physical_fallback():
 
 
 def test_current_axis_names_abstract_mesh():
-    """When this jax exposes an abstract-mesh API, it wins over the
-    physical context (the allocation-free dry-run path)."""
-    get_abs = getattr(jax.sharding, "get_abstract_mesh", None)
-    set_abs = getattr(jax.sharding, "use_abstract_mesh", None) or getattr(
-        jax.sharding, "set_mesh", None)
-    abs_cls = getattr(jax.sharding, "AbstractMesh", None)
-    if not (get_abs and set_abs and abs_cls):
-        pytest.skip("no abstract-mesh API in this jax")
-    amesh = abs_cls((("pod", 1), ("data", 1)))
-    with set_abs(amesh):
+    """An installed abstract mesh wins over the physical context (the
+    allocation-free dry-run path)."""
+    amesh = jax.sharding.AbstractMesh((1, 1), ("pod", "data"))
+    with jax.sharding.use_abstract_mesh(amesh):
         assert hints._current_axis_names() == ("pod", "data")
+        assert hints.axis_size("data") == 1
+        assert hints.physical_mesh() is None
 
 
 # ----------------------------------------------------------- mesh helpers ---
@@ -117,6 +112,23 @@ def test_physical_mesh_and_topology():
         assert hints.physical_mesh() is not None
         assert tuple(hints.physical_mesh().axis_names) == ("data", "model")
         assert hints.mesh_topology() == (("data", 1), ("model", 1))
+    assert hints.physical_mesh() is None
+
+
+def test_physical_mesh_sees_set_mesh_inside_jit():
+    """`jax.set_mesh` installs the concrete mesh where the sharded backend
+    looks for it — at trace time, inside jit."""
+    m = mesh1("data", "model")
+    seen = []
+
+    def f(x):
+        seen.append(hints.physical_mesh())
+        return x
+
+    with jax.set_mesh(m):
+        assert hints.physical_mesh() == m
+        jax.jit(f)(jnp.ones(2))
+    assert seen == [m]
     assert hints.physical_mesh() is None
 
 
