@@ -15,7 +15,9 @@ decoding in lockstep, it drains its request queue into padded-bucket
 dispatches through a `CompileCache` — each step stacks up to top-bucket
 images, pads to the smallest compiled bucket that fits, runs ONE compiled
 call, and completes every request in the batch.  Per-request latency and
-aggregate images/sec come out of `stats()`.
+aggregate images/sec come out of `stats()`.  Every step is also recorded as
+one span cut into contiguous stages (`CNN_STAGES`), kept in a bounded ring
+with an anchor that places them on a profiler trace's clock.
 """
 from __future__ import annotations
 
@@ -39,6 +41,15 @@ STATS_KEYS = ("engine", "requests", "steps", "wall_s", "latency_s",
               "throughput")
 REQUEST_KEYS = ("submitted", "completed", "rejected", "truncated")
 LATENCY_KEYS = ("avg", "max", "p50", "p95", "p99")
+
+# The stages of one `CNNServingEngine.step`, in order; they tile the step
+# span.  A kept record is the six boundary stamps (`time.perf_counter_ns`)
+# around the five stages.
+CNN_STAGES = ("cnn.batch", "cnn.put", "cnn.dispatch", "cnn.wait",
+              "cnn.fetch")
+# Records kept whole, in a preallocated ring of 3 MB: at a few ms per
+# step, the last few minutes of serving.
+STEPS_KEPT = 1 << 16
 
 
 class RejectedRequest(ValueError):
@@ -206,6 +217,20 @@ class CNNServingEngine(ServingFrontend):
     input plan); each step() drains up to top-bucket requests, stacks them
     into one ragged batch, and dispatches through `CompileCache.run` — the
     pad/slice and the one-trace-per-bucket guarantee live there.
+
+    Each working step is one span of contiguous stages (`CNN_STAGES`),
+    stamped with `time.perf_counter_ns()` where the work happens:
+    `cnn.batch` pops the requests and stacks their images, `cnn.put` copies
+    the batch to the device (enqueue), `cnn.dispatch` is `CompileCache.run`
+    (bucket pick, pad, the executable call, the row slice; returns once
+    enqueued), `cnn.wait` blocks until the output is ready (input transfer,
+    device program, output), `cnn.fetch` copies the output to the host and
+    completes each request.  `stats()["stages"]` aggregates them over every
+    step; the last `STEPS_KEPT` records are kept whole (`step_records()`)
+    in a preallocated ring.  `anchor_ns`,
+    one `(time.time_ns(), time.perf_counter_ns())` pair taken at
+    construction, converts the stamps to epoch time, the clock of a
+    profiler trace (`profile_start_time` + an event's start).
     """
 
     def __init__(self, cache: CompileCache):
@@ -217,8 +242,10 @@ class CNNServingEngine(ServingFrontend):
         self._completed = 0
         self._rejected = 0
         self._steps = 0
-        self._wall_s = 0.0
         self._latency = LatencyAgg()
+        self._stage_ns = [0] * len(CNN_STAGES)
+        self._ring = np.zeros((STEPS_KEPT, len(CNN_STAGES) + 1), np.int64)
+        self.anchor_ns = (time.time_ns(), time.perf_counter_ns())
 
     def submit(self, req: ImageRequest) -> None:
         try:
@@ -240,26 +267,62 @@ class CNNServingEngine(ServingFrontend):
         """Drain one micro-batch through the compile cache."""
         if not self.pending:
             return 0
-        t0 = time.perf_counter()
+        ns = time.perf_counter_ns
+        t0 = ns()
         batch = [self.pending.popleft()
                  for _ in range(min(self.max_batch, len(self.pending)))]
-        x = jnp.asarray(np.stack([r.image for r in batch]))
-        y = np.asarray(jax.block_until_ready(self.cache.run(x)))
-        t1 = time.perf_counter()
+        x = np.stack([r.image for r in batch])
+        t1 = ns()
+        x = jnp.asarray(x)
+        t2 = ns()
+        y = self.cache.run(x)
+        t3 = ns()
+        jax.block_until_ready(y)
+        t4 = ns()
+        y = np.asarray(y)
+        t_done = time.perf_counter()
         for i, r in enumerate(batch):
             r.result = y[i]
             r.done = True
-            r.t_done = t1
+            r.t_done = t_done
             self._latency.add(r.latency_s)
         self._completed += len(batch)
+        t5 = ns()
+        total = self._stage_ns
+        total[0] += t1 - t0
+        total[1] += t2 - t1
+        total[2] += t3 - t2
+        total[3] += t4 - t3
+        total[4] += t5 - t4
+        self._ring[self._steps % len(self._ring)] = t0, t1, t2, t3, t4, t5
         self._steps += 1
-        self._wall_s += t1 - t0
         return len(batch)
 
+    def step_records(self) -> dict:
+        """The kept step records, oldest first: `step`, the step ids (n,);
+        the six boundary stamps of each step in both clocks, `perf_ns`
+        (`time.perf_counter_ns`) and `epoch_ns` (`time.time_ns`, through
+        the anchor), (n, 6), around the `stages` in order; and
+        `overwritten`, the records the ring has dropped since
+        construction."""
+        n, cap = self._steps, len(self._ring)
+        lost = max(0, n - cap)
+        kept = (np.roll(self._ring, -(n % cap), axis=0) if lost
+                else self._ring[:n].copy())
+        epoch, perf = self.anchor_ns
+        return {"stages": CNN_STAGES, "step": np.arange(lost, n),
+                "perf_ns": kept, "epoch_ns": kept - perf + epoch,
+                "overwritten": lost}
+
     def stats(self) -> dict:
+        n = self._steps
+        stages = {name: {"count": n, "total_ns": tot,
+                         "mean_ns": (tot / n) if n else 0.0}
+                  for name, tot in zip(CNN_STAGES, self._stage_ns)}
         return build_stats(
             engine="cnn", submitted=self._submitted,
             completed=self._completed, rejected=self._rejected, truncated=0,
-            steps=self._steps, wall_s=self._wall_s,
+            steps=n, wall_s=sum(self._stage_ns) * 1e-9,
             latency=self._latency, items=self._completed,
-            extra={"images": self._completed, "cache": self.cache.stats()})
+            extra={"images": self._completed, "cache": self.cache.stats(),
+                   "stages": stages})

@@ -1,6 +1,8 @@
 """One serving surface for CNN and LM traffic: the `ServingFrontend`
 protocol, the micro-batching `CNNServingEngine`, and the shared stats
 schema both engines emit."""
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -229,3 +231,128 @@ def test_stats_schema_is_shared_across_cnn_and_lm_engines():
     # both request types share the frontend Request base (lifecycle+latency)
     assert issubclass(LMRequest, fe.Request)
     assert issubclass(fe.ImageRequest, fe.Request)
+
+
+# --------------------------------------------- CNNServingEngine step spans ---
+
+def _serve_one_at_a_time(eng, n):
+    """n single-image steps; returns each step() call's duration (ns) by a
+    clock read outside the engine."""
+    outside = []
+    for i, im in enumerate(_images(n)):
+        eng.submit(fe.ImageRequest(rid=i, image=im))
+        a = time.perf_counter_ns()
+        assert eng.step() == 1
+        outside.append(time.perf_counter_ns() - a)
+    return outside
+
+
+def test_step_stages_are_ordered_and_tile_the_step_span():
+    _, _, eng = _cnn_engine()
+    eng.run([fe.ImageRequest(rid=i, image=im)
+             for i, im in enumerate(_images(7))])
+    rec = eng.step_records()
+    assert rec["step"].tolist() == [0, 1]
+    t = rec["perf_ns"]
+    assert t.shape == (2, len(fe.CNN_STAGES) + 1)
+    assert (np.diff(t, axis=1) >= 0).all()        # t0 <= t1 <= ... <= t5
+    assert t[0, -1] <= t[1, 0]                    # steps do not overlap
+    # the stages tile the span: their totals are the spans' durations
+    st = eng.stats()["stages"]
+    np.testing.assert_array_equal(
+        np.diff(t, axis=1).sum(axis=0),
+        [st[s]["total_ns"] for s in fe.CNN_STAGES])
+    assert eng.stats()["wall_s"] * 1e9 == pytest.approx(
+        (t[:, -1] - t[:, 0]).sum(), abs=1.0)
+    epoch, perf = eng.anchor_ns
+    np.testing.assert_array_equal(rec["epoch_ns"] - rec["perf_ns"],
+                                  epoch - perf)
+    assert rec["overwritten"] == 0
+
+
+@pytest.mark.parametrize("kept,served", [(1, 4), (3, 5), (5, 5)])
+def test_step_record_ring_is_bounded_and_counts_what_it_overwrote(
+        monkeypatch, kept, served):
+    monkeypatch.setattr(fe, "STEPS_KEPT", kept)
+    _, _, eng = _cnn_engine(buckets=(1,))
+    _serve_one_at_a_time(eng, served)
+    rec = eng.step_records()
+    lost = max(0, served - kept)
+    assert rec["step"].tolist() == list(range(lost, served))  # newest, in order
+    assert rec["overwritten"] == lost
+    assert rec["perf_ns"].shape == (served - lost, len(fe.CNN_STAGES) + 1)
+    assert (np.diff(rec["perf_ns"][:, 0]) > 0).all()
+    assert eng._ring.shape == (kept, len(fe.CNN_STAGES) + 1)
+    _serve_one_at_a_time(eng, 2)
+    rec = eng.step_records()
+    assert rec["step"].tolist() == list(range(served + 2 - kept, served + 2))
+    assert rec["overwritten"] == served + 2 - kept
+    assert (np.diff(rec["perf_ns"][:, 0]) > 0).all()
+    assert eng.stats()["stages"]["cnn.wait"]["count"] == served + 2
+
+
+@pytest.mark.parametrize("kept", [
+    pytest.param(fe.STEPS_KEPT, id="ring_holds_every_step"),
+    pytest.param(2, id="ring_overwrote_steps")])
+def test_wall_s_is_the_sum_of_step_spans(monkeypatch, kept):
+    monkeypatch.setattr(fe, "STEPS_KEPT", kept)
+    _, _, eng = _cnn_engine(buckets=(1,))
+    outside = _serve_one_at_a_time(eng, 4)
+    st = eng.stats()
+    wall_ns = st["wall_s"] * 1e9
+    assert wall_ns == pytest.approx(
+        sum(s["total_ns"] for s in st["stages"].values()), abs=1.0)
+    assert 0 < wall_ns <= sum(outside)
+    t = eng.step_records()["perf_ns"]
+    spans = t[:, -1] - t[:, 0]
+    if kept >= 4:
+        assert wall_ns == pytest.approx(spans.sum(), abs=1.0)
+    else:                       # the aggregates still count dropped steps
+        assert wall_ns > spans.sum()
+
+
+def test_stats_stages_keys():
+    _, _, eng = _cnn_engine()
+    assert set(eng.stats()["stages"]) == set(fe.CNN_STAGES)
+    eng.run([fe.ImageRequest(rid=i, image=im)
+             for i, im in enumerate(_images(5))])
+    st = eng.stats()
+    for name in fe.CNN_STAGES:
+        s = st["stages"][name]
+        assert set(s) == {"count", "total_ns", "mean_ns"}
+        assert s["count"] == st["steps"] == 2
+        assert s["mean_ns"] == s["total_ns"] / 2
+
+
+def test_step_spans_share_the_profiler_clock(tmp_path):
+    """Through the anchor, each step span lies inside the profiler's own
+    span of the same call: `profile_start_time` (epoch ns) plus the
+    event's start, to its end."""
+    from jax.profiler import ProfileData
+    _, _, eng = _cnn_engine(buckets=(1,))
+    eng.cache.warmup()
+    imgs = _images(3)
+    opts = jax.profiler.ProfileOptions()
+    opts.host_tracer_level = 1
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        for i, im in enumerate(imgs):
+            eng.submit(fe.ImageRequest(rid=i, image=im))
+            with jax.profiler.TraceAnnotation(f"test.cnn_step.{i}"):
+                eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    start = next(dict(p.stats)["profile_start_time"] for p in data.planes
+                 if p.name == "Task Environment")
+    spans = {e.name: (start + e.start_ns, start + e.start_ns + e.duration_ns)
+             for p in data.planes for line in p.lines for e in line.events
+             if e.name.startswith("test.cnn_step.")}
+    rec = eng.step_records()
+    slack = 50_000                                # ns
+    for i in range(len(imgs)):
+        lo, hi = spans[f"test.cnn_step.{i}"]
+        t0, t5 = rec["epoch_ns"][i, 0], rec["epoch_ns"][i, -1]
+        assert lo - slack <= t0 <= t5 <= hi + slack, (i, lo, t0, t5, hi)
