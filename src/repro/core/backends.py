@@ -479,13 +479,43 @@ def record_dispatch(backend: str, op: str, shapes: tuple | None = None,
                     dtype=None, tiles: tuple = ()) -> None:
     """Count one engine dispatch and append its detail record
     ``{backend, op, shapes, dtype, tiles}`` to the bounded log (oldest
-    records win; past the limit only the counter advances)."""
+    records win; past the limit only the counter advances).  A GEMM-shaped
+    dispatch with a (bm, bk, bn) plan also records ``gemm``, its real
+    (m, k, n), and ``padded``, the extents the kernel runs on
+    (`kernel_ops.gemm_padding`; equal to ``gemm`` for an exact plan)."""
     _DISPATCH[(backend, op)] += 1
     if len(_DISPATCH_LOG) < _DISPATCH_LOG_LIMIT:
-        _DISPATCH_LOG.append({
-            "backend": backend, "op": op, "shapes": shapes,
-            "dtype": None if dtype is None else str(jnp.dtype(dtype)),
-            "tiles": tuple(tiles or ())})
+        rec = {"backend": backend, "op": op, "shapes": shapes,
+               "dtype": None if dtype is None else str(jnp.dtype(dtype)),
+               "tiles": tuple(tiles or ())}
+        dims = gemm_dims(op, shapes) if len(rec["tiles"]) == 3 else None
+        if dims is not None:
+            rec["gemm"] = dims
+            rec["padded"] = kernel_ops.gemm_padding(*dims, rec["tiles"])
+        _DISPATCH_LOG.append(rec)
+
+
+def gemm_padded(records) -> dict:
+    """How much of a dispatch-log slice's tiled GEMM work the wrapper pads.
+
+    Returns ``{gemms, padded, operand_bytes, padded_operand_bytes,
+    shapes}``: the GEMM dispatches with a (bm, bk, bn) plan, how many of
+    them pad any operand, the bytes of their x and w operands (per batch
+    element for bmm) as given and as the kernel runs them, and each padded
+    dispatch as ``((m, k, n), (mp, kp, np))``.
+    """
+    gemms = [r for r in records if "padded" in r]
+    out = {"gemms": len(gemms), "padded": 0, "operand_bytes": 0,
+           "padded_operand_bytes": 0, "shapes": []}
+    for r in gemms:
+        item = jnp.dtype(r["dtype"]).itemsize
+        (m, k, n), (mp, kp, np_) = r["gemm"], r["padded"]
+        out["operand_bytes"] += (m * k + k * n) * item
+        out["padded_operand_bytes"] += (mp * kp + kp * np_) * item
+        if r["padded"] != r["gemm"]:
+            out["padded"] += 1
+            out["shapes"].append((r["gemm"], r["padded"]))
+    return out
 
 
 def dispatch_counts() -> dict[tuple[str, str], int]:

@@ -289,6 +289,7 @@ class CompiledNetwork:
         # served from disk).
         self.op_counts = backends.counts_since(before)
         self.op_log = tuple(backends.dispatch_log()[log_mark:])
+        self.gemm_padded = backends.gemm_padded(self.op_log)
         self.autotune_keys = tuple(
             k for k in backends.autotune_report() if k not in before_tuned)
 
@@ -360,7 +361,9 @@ class CompiledNetwork:
           reps: timed repetitions after one untimed warm call.
 
         Returns `{per_call_s, reps, batch_size, trace_count, op_counts,
-        autotune}`.
+        gemm_padded, autotune}`; ``gemm_padded`` says how many of the
+        lowering's tiled GEMM dispatches pad an operand
+        (`backends.gemm_padded`).
         """
         if x is None:
             x = jnp.zeros(self.in_spec.shape, self.in_spec.dtype)
@@ -374,6 +377,7 @@ class CompiledNetwork:
                 "batch_size": self.batch_size,
                 "trace_count": self._trace_count,
                 "op_counts": dict(self.op_counts),
+                "gemm_padded": self.gemm_padded,
                 "autotune": self.autotune_report()}
 
 
@@ -393,8 +397,10 @@ class CompileCache:
     execution — tests/test_compile_cache.py asserts this.
 
     Observability: `hits`/`misses` count bucket-cache lookups, `stats()`
-    reports traces, the per-bucket dispatch histogram, and the pad-waste
-    fraction (padded rows / total dispatched rows).
+    reports traces, the per-bucket dispatch histogram, the pad-waste
+    fraction (padded rows / total dispatched rows), and ``gemm_padded``:
+    the compiled buckets' tiled GEMM dispatches and how many of them pad
+    an operand (`backends.gemm_padded`).
     """
 
     def __init__(self, net: Network, params: dict,
@@ -487,6 +493,16 @@ class CompileCache:
             out.update(cn.autotune_report())
         return out
 
+    def _gemm_padded(self) -> dict:
+        """`CompiledNetwork.gemm_padded` summed over the compiled buckets
+        (read at compile time, so `stats()` stays cheap per step)."""
+        out = {"gemms": 0, "padded": 0, "operand_bytes": 0,
+               "padded_operand_bytes": 0, "shapes": []}
+        for cn in self._compiled.values():
+            for key, val in cn.gemm_padded.items():
+                out[key] += val
+        return out
+
     def stats(self) -> dict:
         total = self._rows_real + self._rows_pad
         tuned = self.autotune_report()
@@ -501,5 +517,6 @@ class CompileCache:
             "rows_real": self._rows_real,
             "rows_padded": self._rows_pad,
             "pad_waste": (self._rows_pad / total) if total else 0.0,
+            "gemm_padded": self._gemm_padded(),
             "autotune": {"keys": len(tuned), "sources": dict(sources)},
         }

@@ -26,24 +26,23 @@ activation epilogue is fused, the forward kernel additionally emits the
 ``act'(pre-act)`` residual (and the raw fp32 accumulator when a `scale`
 epilogue needs its gradient) from the same VMEM tile it already holds — the
 pre-activation never round-trips through HBM twice.  The backward runs two
-tiled pallas kernels on the padded problem:
+tiled pallas kernels on the forward's operands:
 
   dX = (dY ∘ act'(u) ∘ scale) Wᵀ    rows M, contraction N, cols K
   dW = Xᵀ (dY ∘ act'(u) ∘ scale)    rows K, contraction M, cols N
 
 each with its own (bm, bk, bn) plan resolved LAZILY at backward-trace time
 from the measured ``"gemm_bwd"`` autotune keys (variant-tagged: ("dx", m, n,
-k) / ("dw", k, m, n) in the backward problem's own dims) and gcd-clamped to
-divide the forward-padded extents — exactly the pattern flash_attention.py
-established for ``attention_bwd``.  dscale/dshift are column reductions of
-the residuals (no kernel needed).  Inference-only traces never resolve (or
+k) / ("dw", k, m, n) in the backward problem's own dims), its operands
+zero-padded up to that plan's multiples where it does not divide them —
+the pattern flash_attention.py established for ``attention_bwd``.
+dscale/dshift are column reductions of the residuals (no kernel needed).  Inference-only traces never resolve (or
 measure) a backward key.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -196,7 +195,7 @@ def _gemm_forward(cfg: _Config, x, w, scale, shift, *, residuals: bool):
 
 
 # ------------------------------------------------------ backward kernels ---
-# Two tiled GEMMs per backward, each on the forward-padded problem with its
+# Two tiled GEMMs per backward, each on the forward's operands with its
 # OWN (bm, bk, bn) plan (the backward problems transpose the roles of the
 # forward dims, so the forward winner is usually mis-aligned for them).
 
@@ -344,35 +343,32 @@ def gemm_bwd_problem(variant: str, m: int, k: int, n: int
     raise ValueError(f"unknown gemm_bwd variant {variant!r}")
 
 
-def _resolve_bwd_tiles(cfg: _Config, variant: str, padded: tuple, dtype
+def _resolve_bwd_tiles(cfg: _Config, variant: str, dtype
                        ) -> tuple[int, int, int]:
     """Backward (bm, bk, bn) for one variant: the explicit pin, else the
     measured ``("gemm_bwd", (variant, rows, contraction, cols), dtype)``
     autotune key (ops-level calls thread `bwd_key`), else the forward tiles
-    permuted into the variant's roles.  Whatever the source, each tile is
-    clamped to a divisor of the forward-padded extent (gcd keeps the MXU
-    alignment: both operands are multiples of it)."""
+    permuted into the variant's roles.  The backward pads its operands to
+    the plan's multiples (`_pad_to`), so any plan is safe."""
     pin = cfg.bwd_dx if variant.endswith("dx") else cfg.bwd_dw
     if pin:
-        plan = pin
-    elif cfg.bwd_key is not None:
+        return tuple(pin)
+    if cfg.bwd_key is not None:
         from repro.core import backends
         key_shapes = (variant,) + gemm_bwd_problem(variant, *cfg.bwd_key)
-        plan = backends.get_backend("pallas").tiles(
+        return backends.get_backend("pallas").tiles(
             "gemm_bwd", key_shapes, dtype, interpret=cfg.interpret)
-    elif variant.endswith("dx"):
-        plan = (cfg.bm, cfg.bn, cfg.bk)
-    else:
-        plan = (cfg.bk, cfg.bm, cfg.bn)
-    bm2, bk2, bn2 = plan
-    rows, kdim, cols = padded
-    if rows % bm2:
-        bm2 = math.gcd(rows, bm2)
-    if kdim % bk2:
-        bk2 = math.gcd(kdim, bk2)
-    if cols % bn2:
-        bn2 = math.gcd(cols, bn2)
-    return bm2, bk2, bn2
+    if variant.endswith("dx"):
+        return cfg.bm, cfg.bn, cfg.bk
+    return cfg.bk, cfg.bm, cfg.bn
+
+
+def _pad_to(a, blocks: tuple[int, int]):
+    """Zero-pad the last two dims of ``a`` up to multiples of ``blocks``
+    (zeros are exact in a GEMM); ``a`` itself when they already divide."""
+    pads = [(0, 0)] * (a.ndim - 2) + [(0, -(-d // b) * b - d) for d, b in
+                                      zip(a.shape[-2:], blocks)]
+    return jnp.pad(a, pads) if any(hi for _, hi in pads) else a
 
 
 # ---------------------------------------------------------- gemm (fused) ---
@@ -402,12 +398,14 @@ def _gemm_vjp_bwd(cfg: _Config, res, dy):
                   if cfg.has_scale else None)
         dacc = dyg * scale if cfg.has_scale else dyg     # dL/d(x@w)
         dacc = dacc.astype(x.dtype)
-        tiles = _resolve_bwd_tiles(cfg, "dx", (m, n, k), x.dtype)
-        dx = gemm_bwd_dx(dacc, w, bm=tiles[0], bk=tiles[1], bn=tiles[2],
-                         out_dtype=x.dtype, interpret=cfg.interpret)
-        tiles = _resolve_bwd_tiles(cfg, "dw", (k, m, n), x.dtype)
-        dw = gemm_bwd_dw(x, dacc, bm=tiles[0], bk=tiles[1], bn=tiles[2],
-                         out_dtype=w.dtype, interpret=cfg.interpret)
+        bm, bk, bn = _resolve_bwd_tiles(cfg, "dx", x.dtype)
+        dx = gemm_bwd_dx(_pad_to(dacc, (bm, bk)), _pad_to(w, (bn, bk)),
+                         bm=bm, bk=bk, bn=bn, out_dtype=x.dtype,
+                         interpret=cfg.interpret)[:m, :k]
+        bm, bk, bn = _resolve_bwd_tiles(cfg, "dw", x.dtype)
+        dw = gemm_bwd_dw(_pad_to(x, (bk, bm)), _pad_to(dacc, (bk, bn)),
+                         bm=bm, bk=bk, bn=bn, out_dtype=w.dtype,
+                         interpret=cfg.interpret)[:k, :n]
     return dx, dw, dscale, dshift
 
 
@@ -421,17 +419,18 @@ def gemm(x, w, *, scale=None, shift=None, act: str = "linear",
     """Fused tiled GEMM: act((x @ w) * scale + shift).
 
     x: (M, K), w: (K, N) with M % bm == K % bk == N % bn == 0 (ops.matmul
-    pads); scale/shift: (N,) vectors or None.  fp32 accumulation always.
+    picks such blocks, or pads); scale/shift: (N,) vectors or None.  fp32
+    accumulation always.
 
     DIFFERENTIABLE (``jax.custom_vjp``): the forward emits act'(pre-act)
     (and the raw accumulator when `scale` is given) as residuals; two
-    backward pallas kernels compute dX/dW on the same padded problem.
+    backward pallas kernels compute dX/dW from the same operands.
     ``bwd_dx``/``bwd_dw`` pin the backward (bm, bk, bn) plans; () resolves
     them at backward-trace time from the measured ``"gemm_bwd"`` autotune
     keys when ``bwd_key`` (the unpadded engine (m, k, n)) is threaded
-    through, else permutes the forward tiles.  Non-dividing picks are
-    gcd-clamped, so any MXU-aligned pin is safe.  Forward-only callers
-    never touch a backward key.
+    through, else permutes the forward tiles.  The backward pads its
+    operands to whatever plan it runs, so any MXU-aligned pin is safe.
+    Forward-only callers never touch a backward key.
     """
     m, k = x.shape
     k2, n = w.shape
@@ -488,12 +487,14 @@ def _bmm_vjp_bwd(cfg: _Config, res, dy):
     n = w.shape[-1]
     with jax.named_scope(GEMM_BWD_SCOPE):
         dyc = dy.astype(x.dtype)
-        tiles = _resolve_bwd_tiles(cfg, "bdx", (m, n, k), x.dtype)
-        dx = bmm_bwd_dx(dyc, w, bm=tiles[0], bk=tiles[1], bn=tiles[2],
-                        out_dtype=x.dtype, interpret=cfg.interpret)
-        tiles = _resolve_bwd_tiles(cfg, "bdw", (k, m, n), x.dtype)
-        dw = bmm_bwd_dw(x, dyc, bm=tiles[0], bk=tiles[1], bn=tiles[2],
-                        out_dtype=w.dtype, interpret=cfg.interpret)
+        bm, bk, bn = _resolve_bwd_tiles(cfg, "bdx", x.dtype)
+        dx = bmm_bwd_dx(_pad_to(dyc, (bm, bk)), _pad_to(w, (bn, bk)),
+                        bm=bm, bk=bk, bn=bn, out_dtype=x.dtype,
+                        interpret=cfg.interpret)[:, :m, :k]
+        bm, bk, bn = _resolve_bwd_tiles(cfg, "bdw", x.dtype)
+        dw = bmm_bwd_dw(_pad_to(x, (bk, bm)), _pad_to(dyc, (bk, bn)),
+                        bm=bm, bk=bk, bn=bn, out_dtype=w.dtype,
+                        interpret=cfg.interpret)[:, :k, :n]
     return dx, dw
 
 

@@ -1,13 +1,15 @@
 """jit'd public wrappers around the Pallas kernels.
 
 These handle the "any shape of matrices" property the paper advertises
-(Fig. 3 deliberately uses non-sweet-spot dims): inputs are zero-padded up to
-block multiples, the kernel runs on the padded problem, and the result is
-sliced back.  Zero padding is exact for GEMM (0-rows/cols contribute 0), and
-the epilogue is applied inside the kernel on padded columns whose outputs are
-discarded by the slice.  For attention, key padding is masked exactly via
-the kernel's ``kv_len`` operand (zero keys would NOT be softmax-neutral)
-and padded query rows are sliced off.
+(Fig. 3 deliberately uses non-sweet-spot dims).  GEMM tile plans fit each
+extent exactly where they can (`pick_blocks`: every block divides its
+extent at the hardware alignment or spans it whole), so the kernel runs on
+the operands as they are.  An extent no such block fits is zero-padded up
+to a block multiple and the result sliced back: zero padding is exact for
+GEMM (0-rows/cols contribute 0), and the epilogue runs on padded columns
+whose outputs the slice discards.  For attention, key padding is masked
+exactly via the kernel's ``kv_len`` operand (zero keys would NOT be
+softmax-neutral) and padded query rows are sliced off.
 """
 from __future__ import annotations
 
@@ -26,78 +28,141 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-def pick_blocks(m: int, k: int, n: int, dtype) -> tuple[int, int, int]:
-    """Block-shape heuristic for the VMEM working set (pure function).
+# Double-buffered VMEM working set target (~half of a 16 MiB/core VMEM).
+_VMEM_BUDGET = 8 * 1024 * 1024
 
-    Targets: MXU alignment (multiples of (8,128) lanes — we use 128 where the
-    dim allows), and a double-buffered working set
-    2*(bm*bk + bk*bn) + 2*bm*bn floats comfortably under ~8 MiB of VMEM.
+# Alignment of each GEMM block dimension (bm, bk, bn): bm counts sublanes
+# (8), bk and bn count lanes (128).  Mosaic takes a block dimension that is
+# aligned or that spans its array's whole dimension.
+_ALIGN = (8, 128, 128)
+
+# Block caps (bm, bk, bn) per op.  bmm runs smaller blocks: the batch grid
+# dimension multiplies the working set's live tiles.
+_CAPS = {"matmul": (256, 2048, 256), "bmm": (128, 256, 128)}
+
+
+def _working_set(bm: int, bk: int, bn: int, itemsize: int) -> int:
+    """Bytes resident in VMEM for one grid step: double-buffered x/w tiles
+    plus the fp32 accumulator and output tile, each laid out in whole
+    (8, 128) tiles (a full-extent block of 27 lanes occupies 128)."""
+    rm, rn = _round_up(bm, 8), _round_up(bn, 128)
+    x_tile = rm * _round_up(bk, 128)
+    w_tile = _round_up(bk, 8) * rn
+    return 2 * (x_tile + w_tile) * itemsize + 2 * rm * rn * 4
+
+
+def _exact_blocks(extent: int, align: int, cap: int) -> list[int]:
+    """Blocks that tile ``extent`` with no padding, in order of preference:
+    the aligned divisors up to ``cap``, largest first, headed by the whole
+    extent when it fits under the cap or when the best divisor under the
+    cap is less than half of it (a full extent is legal at any size)."""
+    within = [b for b in range(min(extent, cap) // align * align, 0, -align)
+              if extent % b == 0]
+    if extent <= cap or not within or within[0] < cap // 2:
+        return [extent] + [b for b in within if b != extent]
+    return within
+
+
+def pick_blocks(m: int, k: int, n: int, dtype,
+                caps: tuple[int, int, int] = _CAPS["matmul"]
+                ) -> tuple[int, int, int]:
+    """Exact tile plan for an (m, k, n) GEMM (pure function).
+
+    Each block divides its extent at the hardware alignment (bm a multiple
+    of 8, bk/bn of 128) or equals the whole extent, so the wrapper pads
+    and slices nothing.  Among such plans: the first, in each axis's
+    `_exact_blocks` order (bm outermost, bk innermost), whose
+    double-buffered `_working_set` fits `_VMEM_BUDGET` — large row blocks
+    first, since they read the weights fewest times.  Where no exact plan
+    fits, the axes that cannot be tiled exactly fall back to the padded
+    pick of `padded_blocks`, each on its own.
 
     Callers go through the process-wide autotune cache in core/backends.py
     (keyed on (op, shapes, dtype, backend)) rather than invoking this
     per call; `_cached_blocks` below routes the default path there too.
     """
     itemsize = jnp.dtype(dtype).itemsize
-    bm = min(_round_up(m, 8), 256)
-    bn = min(_round_up(n, 128), 256)
-    # Grow bk while the working set stays under budget.
-    budget = _VMEM_BUDGET
+    exact = [_exact_blocks(d, a, c) for d, a, c in zip((m, k, n), _ALIGN,
+                                                        caps)]
+    padded = padded_blocks(m, k, n, dtype, caps)
+    # First every axis exact; then each axis may take its padded pick.
+    for prefs in (exact, [e + [p] for e, p in zip(exact, padded)]):
+        for bm in prefs[0]:
+            for bn in prefs[2]:
+                for bk in prefs[1]:
+                    if _working_set(bm, bk, bn, itemsize) <= _VMEM_BUDGET:
+                        return bm, bk, bn
+    return padded
+
+
+def padded_blocks(m: int, k: int, n: int, dtype,
+                  caps: tuple[int, int, int] = _CAPS["matmul"]
+                  ) -> tuple[int, int, int]:
+    """Aligned block heuristic that pads every extent to a block multiple:
+    bm/bn the aligned extent up to its cap, bk grown by doubling while the
+    working set stays under budget.  The GEMM backward's picker
+    (`default_gemm_bwd_blocks`) and the fallback of `pick_blocks`."""
+    itemsize = jnp.dtype(dtype).itemsize
+    cap_m, cap_k, cap_n = caps
+    bm = min(_round_up(m, 8), cap_m)
+    bn = min(_round_up(n, 128), cap_n)
     bk = 128
-    while bk < 2048:
+    while bk < cap_k:
         nxt = bk * 2
-        ws = _working_set(bm, nxt, bn, itemsize)
-        if ws > budget or nxt > _round_up(k, 128):
+        if (_working_set(bm, nxt, bn, itemsize) > _VMEM_BUDGET
+                or nxt > _round_up(k, 128)):
             break
         bk = nxt
     return bm, bk, bn
 
 
-# Double-buffered VMEM working set target (~half of a 16 MiB/core VMEM).
-_VMEM_BUDGET = 8 * 1024 * 1024
-
-
-def _working_set(bm: int, bk: int, bn: int, itemsize: int) -> int:
-    """Bytes resident in VMEM for one grid step: double-buffered x/w tiles
-    plus the fp32 accumulator and output tile."""
-    return 2 * (bm * bk + bk * bn) * itemsize + 2 * bm * bn * 4
+def _op_caps(op: str) -> tuple[int, int, int]:
+    return _CAPS["bmm" if op == "bmm" else "matmul"]
 
 
 def default_blocks(op: str, m: int, k: int, n: int, dtype
                    ) -> tuple[int, int, int]:
-    """Per-op heuristic pick: `pick_blocks` with the bmm clamp (the batch
-    grid dimension multiplies the working set's live tiles, so bmm runs
-    smaller blocks)."""
-    bm, bk, bn = pick_blocks(m, k, n, dtype)
-    if op == "bmm":
-        bm, bk, bn = min(bm, 128), min(bk, 256), min(bn, 128)
-    return bm, bk, bn
+    """Per-op heuristic pick: `pick_blocks` under the op's block caps."""
+    return pick_blocks(m, k, n, dtype, _op_caps(op))
+
+
+def gemm_padding(m: int, k: int, n: int, tiles: tuple[int, int, int]
+                 ) -> tuple[int, int, int]:
+    """The extents the GEMM kernel runs on under a (bm, bk, bn) plan: each
+    extent rounded up to its block (equal to (m, k, n) for an exact
+    plan)."""
+    return tuple(_round_up(d, t) for d, t in zip((m, k, n), tiles))
 
 
 def candidate_blocks(op: str, m: int, k: int, n: int, dtype
                      ) -> list[tuple[int, int, int]]:
     """Candidate set for measured autotuning: the heuristic pick plus its
-    axis-wise half/double neighbors, clamped to MXU-aligned sizes (bm mult
-    of 8, bk/bn mult of 128) and filtered to the VMEM working-set budget.
+    axis-wise neighbors, filtered to legal plans (`validate_gemm_tiles`).
 
+    On an axis the pick tiles exactly, the neighbors are the next smaller
+    and next larger exact blocks (aligned divisors of the extent, or the
+    extent itself); on an axis it pads, the aligned half and double.
     Small by design (<= 7 points): measurement happens once per (op,
     shapes, dtype, backend) key per device, ever, so the sweep only needs
     to cover the heuristic's failure directions, not the full design space.
     """
     base = default_blocks(op, m, k, n, dtype)
-    itemsize = jnp.dtype(dtype).itemsize
-    bm, bk, bn = base
     cands = [base]
-    for vm, vk, vn in ((bm // 2, bk, bn), (bm * 2, bk, bn),
-                       (bm, bk // 2, bn), (bm, bk * 2, bn),
-                       (bm, bk, bn // 2), (bm, bk, bn * 2)):
-        cand = (max(8, min(_round_up(vm, 8), 512)),
-                max(128, min(_round_up(vk, 128), 2048)),
-                max(128, min(_round_up(vn, 128), 512)))
-        if cand in cands:
-            continue
-        if _working_set(*cand, itemsize) > _VMEM_BUDGET:
-            continue
-        cands.append(cand)
+    for axis, (dim, align) in enumerate(zip((m, k, n), _ALIGN)):
+        tile = base[axis]
+        if dim % tile == 0:
+            ladder = sorted(set(
+                [b for b in range(align, dim + 1, align) if dim % b == 0]
+                + [dim]))
+            i = ladder.index(tile)
+            near = ladder[max(i - 1, 0):i + 2]
+        else:
+            near = [_round_up(tile // 2, align), tile * 2]
+        for v in near:
+            cand = base[:axis] + (v,) + base[axis + 1:]
+            if cand in cands or validate_gemm_tiles(m, k, n, dtype, cand):
+                continue
+            cands.append(cand)
     return cands
 
 
@@ -107,9 +172,10 @@ def validate_gemm_tiles(m: int, k: int, n: int, dtype,
 
     The conditions the tiled kernels assume (the trace linter's R004 and
     the autotune cache's plan-time gate both call this): three positive
-    ints, MXU lane alignment (bm multiple of 8 sublanes, bk/bn multiples
-    of the 128-lane width), the double-buffered `_working_set` under the
-    VMEM budget, and no tile longer than its padded problem extent (the
+    ints; each block aligned (bm a multiple of 8 sublanes, bk/bn multiples
+    of the 128-lane width) or equal to its whole extent, the only other
+    block shape Mosaic takes; the double-buffered `_working_set` under the
+    VMEM budget; and no tile longer than its padded problem extent (the
     grid would schedule pure-padding steps).  Returns problem strings;
     empty means legal.
     """
@@ -117,24 +183,21 @@ def validate_gemm_tiles(m: int, k: int, n: int, dtype,
             isinstance(t, int) and not isinstance(t, bool) and t > 0
             for t in tiles):
         return [f"plan {tiles!r} is not three positive ints (bm, bk, bn)"]
-    bm, bk, bn = tiles
     problems = []
-    if bm % 8:
-        problems.append(f"bm={bm} is not a multiple of 8 sublanes")
-    if bk % 128:
-        problems.append(f"bk={bk} is not a multiple of the 128-lane width")
-    if bn % 128:
-        problems.append(f"bn={bn} is not a multiple of the 128-lane width")
-    ws = _working_set(bm, bk, bn, jnp.dtype(dtype).itemsize)
-    if ws > _VMEM_BUDGET:
-        problems.append(f"working set {ws} B exceeds the VMEM budget "
-                        f"{_VMEM_BUDGET} B")
-    for name, tile, dim, align in (("bm", bm, m, 8), ("bk", bk, k, 128),
-                                   ("bn", bn, n, 128)):
+    for name, tile, dim, align, unit in zip(
+            ("bm", "bk", "bn"), tiles, (m, k, n), _ALIGN,
+            ("8 sublanes", "the 128-lane width", "the 128-lane width")):
+        if tile % align and tile != dim:
+            problems.append(f"{name}={tile} is not a multiple of {unit} "
+                            f"nor the full extent {dim}")
         if tile > _round_up(dim, align):
             problems.append(f"{name}={tile} exceeds the padded problem "
                             f"extent {_round_up(dim, align)} (dead grid "
                             f"steps)")
+    ws = _working_set(*tiles, jnp.dtype(dtype).itemsize)
+    if ws > _VMEM_BUDGET:
+        problems.append(f"working set {ws} B exceeds the VMEM budget "
+                        f"{_VMEM_BUDGET} B")
     return problems
 
 
@@ -221,21 +284,37 @@ def _gemm_bwd_base_op(variant: str) -> str:
 
 def default_gemm_bwd_blocks(variant: str, rows: int, kdim: int, cols: int,
                             dtype) -> tuple[int, int, int]:
-    """Heuristic (bm, bk, bn) for a backward GEMM: the backward problem is
-    a plain GEMM over its own (rows, contraction, cols), so the forward
-    heuristic applies directly — with the bmm clamp for the batched
-    "bdx"/"bdw" variants (the batch grid dim multiplies live tiles)."""
-    return default_blocks(_gemm_bwd_base_op(variant), rows, kdim, cols,
-                          dtype)
+    """Heuristic (bm, bk, bn) for a backward GEMM: the aligned padding
+    heuristic (`padded_blocks`) on the backward problem's own (rows,
+    contraction, cols), under the op's block caps (the bmm caps for the
+    batched "bdx"/"bdw" variants: the batch grid dim multiplies live
+    tiles).  The backward pads its operands to the plan's multiples."""
+    return padded_blocks(rows, kdim, cols, dtype,
+                         _op_caps(_gemm_bwd_base_op(variant)))
 
 
 def candidate_gemm_bwd_blocks(variant: str, rows: int, kdim: int, cols: int,
                               dtype) -> list[tuple[int, int, int]]:
-    """Candidate set for measured gemm_bwd autotuning: the forward GEMM
-    sweep (heuristic + axis-wise neighbors, MXU-aligned, VMEM
-    working-set-filtered) on the backward problem's own dims."""
-    return candidate_blocks(_gemm_bwd_base_op(variant), rows, kdim, cols,
-                            dtype)
+    """Candidate set for measured gemm_bwd autotuning: the heuristic pick
+    plus its axis-wise half/double neighbors, clamped to MXU-aligned sizes
+    (bm mult of 8, bk/bn mult of 128) and filtered to the VMEM
+    working-set budget."""
+    base = default_gemm_bwd_blocks(variant, rows, kdim, cols, dtype)
+    itemsize = jnp.dtype(dtype).itemsize
+    bm, bk, bn = base
+    cands = [base]
+    for vm, vk, vn in ((bm // 2, bk, bn), (bm * 2, bk, bn),
+                       (bm, bk // 2, bn), (bm, bk * 2, bn),
+                       (bm, bk, bn // 2), (bm, bk, bn * 2)):
+        cand = (max(8, min(_round_up(vm, 8), 512)),
+                max(128, min(_round_up(vk, 128), 2048)),
+                max(128, min(_round_up(vn, 128), 512)))
+        if cand in cands:
+            continue
+        if _working_set(*cand, itemsize) > _VMEM_BUDGET:
+            continue
+        cands.append(cand)
+    return cands
 
 
 def gemm_bwd_bench_thunk(variant: str, rows: int, kdim: int, cols: int,
@@ -813,7 +892,8 @@ def matmul(x, w, scale=None, shift=None, *, act: str = "linear",
     DIFFERENTIABLE end-to-end: the kernel carries a custom VJP (backward
     GEMM kernels under lazily-resolved ``"gemm_bwd"`` autotune keys — the
     unpadded (m, k, n) threads through as the key), and this wrapper's
-    pad/slice are gradient-transparent.  ``bwd_dx``/``bwd_dw`` pin the
+    pad/slice (only where the plan does not tile an extent exactly) are
+    gradient-transparent.  ``bwd_dx``/``bwd_dw`` pin the
     backward (bm, bk, bn) plans; () resolves them at backward-trace time.
     """
     m, k = x.shape
@@ -821,16 +901,19 @@ def matmul(x, w, scale=None, shift=None, *, act: str = "linear",
     out_dtype = out_dtype or x.dtype
     if not (bm and bk and bn):
         bm, bk, bn = _cached_blocks("matmul", m, k, n, x.dtype, interpret)
-    mp, kp, np_ = _round_up(m, bm), _round_up(k, bk), _round_up(n, bn)
-    xp = jnp.pad(x, ((0, mp - m), (0, kp - k)))
-    wp = jnp.pad(w, ((0, kp - k), (0, np_ - n)))
-    sp = jnp.pad(scale, (0, np_ - n)) if scale is not None else None
-    bp = jnp.pad(shift, (0, np_ - n)) if shift is not None else None
-    out = gemm_kernel.gemm(xp, wp, scale=sp, shift=bp, act=act,
+    mp, kp, np_ = gemm_padding(m, k, n, (bm, bk, bn))
+    if (mp, kp) != (m, k):
+        x = jnp.pad(x, ((0, mp - m), (0, kp - k)))
+    if (kp, np_) != (k, n):
+        w = jnp.pad(w, ((0, kp - k), (0, np_ - n)))
+    if np_ != n:
+        scale = None if scale is None else jnp.pad(scale, (0, np_ - n))
+        shift = None if shift is None else jnp.pad(shift, (0, np_ - n))
+    out = gemm_kernel.gemm(x, w, scale=scale, shift=shift, act=act,
                            out_dtype=out_dtype, bm=bm, bk=bk, bn=bn,
                            interpret=interpret, bwd_key=(m, k, n),
                            bwd_dx=bwd_dx, bwd_dw=bwd_dw)
-    return out[:m, :n]
+    return out if (mp, np_) == (m, n) else out[:m, :n]
 
 
 @functools.partial(
@@ -849,10 +932,12 @@ def bmm(x, w, *, out_dtype=None, bm: int = 0, bk: int = 0, bn: int = 0,
     out_dtype = out_dtype or x.dtype
     if not (bm and bk and bn):
         bm, bk, bn = _cached_blocks("bmm", m, k, n, x.dtype, interpret)
-    mp, kp, np_ = _round_up(m, bm), _round_up(k, bk), _round_up(n, bn)
-    xp = jnp.pad(x, ((0, 0), (0, mp - m), (0, kp - k)))
-    wp = jnp.pad(w, ((0, 0), (0, kp - k), (0, np_ - n)))
-    out = gemm_kernel.bmm(xp, wp, out_dtype=out_dtype, bm=bm, bk=bk, bn=bn,
+    mp, kp, np_ = gemm_padding(m, k, n, (bm, bk, bn))
+    if (mp, kp) != (m, k):
+        x = jnp.pad(x, ((0, 0), (0, mp - m), (0, kp - k)))
+    if (kp, np_) != (k, n):
+        w = jnp.pad(w, ((0, 0), (0, kp - k), (0, np_ - n)))
+    out = gemm_kernel.bmm(x, w, out_dtype=out_dtype, bm=bm, bk=bk, bn=bn,
                           interpret=interpret, bwd_key=(m, k, n),
                           bwd_dx=bwd_dx, bwd_dw=bwd_dw)
-    return out[:, :m, :n]
+    return out if (mp, np_) == (m, n) else out[:, :m, :n]

@@ -262,6 +262,8 @@ def test_policy_context_manager_restores_on_error():
 # -------------------------------------------------- candidate enumeration ---
 
 def test_candidates_include_heuristic_and_respect_budget():
+    """Every candidate is a legal plan: each block aligned (bm to 8, bk/bn
+    to 128) or the full extent, and under the VMEM budget."""
     for op, m, k, n in [("matmul", 512, 288, 128), ("bmm", 128, 128, 128),
                         ("matmul", 64, 2048, 64)]:
         base = kernel_ops.default_blocks(op, m, k, n, "float32")
@@ -269,9 +271,13 @@ def test_candidates_include_heuristic_and_respect_budget():
         assert cands[0] == base
         assert len(cands) == len(set(cands)) >= 2
         for bm, bk, bn in cands:
-            assert bm % 8 == 0 and bk % 128 == 0 and bn % 128 == 0
+            assert bm % 8 == 0 or bm == m
+            assert bk % 128 == 0 or bk == k
+            assert bn % 128 == 0 or bn == n
             assert kernel_ops._working_set(
                 bm, bk, bn, 4) <= kernel_ops._VMEM_BUDGET
+            assert not kernel_ops.validate_gemm_tiles(m, k, n, "float32",
+                                                      (bm, bk, bn))
 
 
 def test_measured_pick_matches_heuristic_numerics():
@@ -493,8 +499,8 @@ def test_gemm_bwd_candidates_mxu_aligned_and_vmem_filtered():
             assert kernel_ops._working_set(
                 bm, bk, bn, 4) <= kernel_ops._VMEM_BUDGET
         if variant.startswith("b"):       # the bmm clamp applies
-            assert base == kernel_ops.default_blocks(
-                "bmm", rows, kdim, cols, "float32")
+            assert base == kernel_ops.padded_blocks(
+                rows, kdim, cols, "float32", kernel_ops._CAPS["bmm"])
 
 
 def test_gemm_bwd_variant_rejected():
@@ -554,7 +560,7 @@ def test_gemm_bwd_persisted_roundtrip_zero_retiming(monkeypatch):
 def test_gemm_bwd_measured_pick_matches_heuristic_numerics():
     """Backward tiling only changes the schedule: gradients under the
     measured picks equal gradients under the heuristic picks (odd dims
-    force the gcd-clamped padded path too).  Max-relative tolerance, not
+    force the padded backward path too).  Max-relative tolerance, not
     elementwise: which candidate wins the timing varies with machine
     load, and a different tile shape can shift fp32 reduction order by
     one ulp at the gradient's magnitude."""

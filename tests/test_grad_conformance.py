@@ -8,8 +8,8 @@ this suite proves it numerically and structurally:
     `ref` backend (conftest.py), over the darknet_ref layer zoo and LM MLP
     shapes — fp32 at 1e-5, bf16 at a loose tier;
   * every fused-epilogue activation (linear/relu/leaky/silu) checked, and
-    odd/unaligned shapes that force the padded kernel path (backward tiles
-    gcd-clamped to the forward-padded extents);
+    odd/unaligned shapes (full-extent forward blocks; the backward pads
+    its operands to its own plan);
   * `jax.checkpoint` remat parity — the custom VJPs compose with remat;
   * a finite-difference spot check on small shapes (hypothesis property
     when installed, seeded deterministic fallback always);

@@ -113,6 +113,45 @@ def test_r004_fires_on_misaligned_plan():
     assert "bm=12" in msgs and "bk=100" in msgs and "bn=130" in msgs
 
 
+@pytest.mark.parametrize("tiles, bad", [
+    ((196, 27, 1000), None),        # every block the full extent: legal
+    ((56, 27, 1000), None),         # bm aligned, bk/bn full: legal
+    ((196, 20, 1000), "bk=20"),     # unaligned and not the full K
+    ((100, 27, 1000), "bm=100"),    # unaligned and not the full M
+    ((196, 27, 500), "bn=500"),     # unaligned and not the full N
+])
+def test_r004_full_extent_blocks_legal_unaligned_partial_blocks_not(tiles,
+                                                                   bad):
+    """A block is legal when aligned or equal to its whole extent (what
+    Mosaic takes); an unaligned block short of the extent is an R004
+    finding."""
+    ctx = lint.LintContext(op_log=(
+        {"backend": "pallas", "op": "matmul", "shapes": (196, 27, 1000),
+         "dtype": "float32", "tiles": tiles},))
+    report = lint.run_lint(ctx)
+    if bad is None:
+        assert report.findings == [], report.format()
+    else:
+        _only_rule(report, "R004")
+        assert bad in report.findings[0].message
+
+
+@pytest.mark.parametrize("batch", [1, 8])
+def test_darknet19_pallas_dispatch_plans_lint_clean(batch):
+    """Darknet-19 @224's exact plans (full-extent blocks included) pass
+    R004 at both served buckets."""
+    from repro.configs.darknet_ref import DARKNET19_CFG
+    net = Network(DARKNET19_CFG, engine=make_engine("pallas"))
+    params = jax.eval_shape(net.init, jax.random.PRNGKey(0))
+    mark = backends.dispatch_log_size()
+    jax.jit(net.apply).trace(
+        params, jax.ShapeDtypeStruct((batch, 224, 224, 3), jnp.float32))
+    log = tuple(backends.dispatch_log()[mark:])
+    assert sum("gemm" in r for r in log) == 19
+    report = lint.run_lint(lint.LintContext(op_log=log), rules=("R004",))
+    assert report.findings == [], report.format()
+
+
 def test_r004_catches_pinned_engine_tiles_via_dispatch_log():
     """End to end: an engine with hand-pinned misaligned tiles leaves its
     plan in the dispatch log at trace time, where R004 finds it."""
