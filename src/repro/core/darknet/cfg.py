@@ -5,7 +5,16 @@ given to Darknet, to efficiently implement a CNN".  This parses the standard
 Darknet INI-ish format into typed layer specs.
 
 Supported sections: net, convolutional, deconvolutional, maxpool, avgpool,
-upsample, route, shortcut, connected, softmax, dropout (inference no-op).
+upsample, route, shortcut, connected, softmax, dropout (inference no-op),
+yolo.
+
+``[yolo]`` is a detection head as darknet's ``forward_yolo_layer`` runs it
+at inference: the logistic on entries 0-1 (x, y) and 4.. (objectness and
+the classes) of each of its ``len(mask)`` anchors, which lie anchor-major
+on the channel axis; entries 2-3 (w, h) pass through.  A network with
+``[yolo]`` sections returns the tuple of their outputs.  Box decoding
+(anchors, grid offsets, exp of w and h) and NMS are the host's work after
+the forward, as in darknet, and are not done here.
 """
 from __future__ import annotations
 
@@ -16,11 +25,12 @@ _INT_KEYS = {"batch", "height", "width", "channels", "filters", "size",
              "stride", "pad", "padding", "groups", "batch_normalize",
              "output", "from", "reverse", "flatten"}
 _FLOAT_KEYS = {"momentum", "decay", "learning_rate", "probability", "scale"}
-_LIST_KEYS = {"layers"}
+_LIST_KEYS = {"layers", "mask"}
+_NUMBER_LIST_KEYS = {"anchors"}
 
 SECTION_TYPES = ("net", "convolutional", "deconvolutional", "maxpool",
                  "avgpool", "upsample", "route", "shortcut", "connected",
-                 "softmax", "dropout")
+                 "softmax", "dropout", "yolo")
 
 
 @dataclasses.dataclass
@@ -32,20 +42,25 @@ class Section:
         return self.options.get(key, default)
 
 
+def _number(val: str):
+    try:
+        return int(val)
+    except ValueError:
+        return float(val)
+
+
 def _coerce(key: str, val: str):
     val = val.strip()
     if key in _LIST_KEYS:
         return [int(v) for v in val.split(",") if v.strip()]
+    if key in _NUMBER_LIST_KEYS:  # some cfgs give fractional anchors
+        return [_number(v) for v in val.split(",") if v.strip()]
     if key in _INT_KEYS:
         return int(val)
     if key in _FLOAT_KEYS:
         return float(val)
     try:
-        return int(val)
-    except ValueError:
-        pass
-    try:
-        return float(val)
+        return _number(val)
     except ValueError:
         return val
 

@@ -9,6 +9,8 @@ Deconvolution (transpose conv) is GEMM + col2im, same engine.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,6 +19,20 @@ from repro.core import ComputeEngine
 from repro.kernels.common import apply_act, im2col  # noqa: F401  (re-export)
 
 _BN_EPS = 1e-5
+# The named scope of each glue layer (route, shortcut, upsample, yolo) in a
+# lowered network, beside the engine ops' "repro.op.<op>".
+LAYER_SCOPE_PREFIX = "repro.layer."
+
+
+def _scoped(fn):
+    """Run a glue layer under ``jax.named_scope("repro.layer.<name>")``."""
+    scope = LAYER_SCOPE_PREFIX + fn.__name__
+
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.named_scope(scope):
+            return fn(*args, **kwargs)
+    return wrapped
 
 
 def fold_batchnorm(gamma, beta, mean, var, bias=None):
@@ -88,17 +104,29 @@ def avgpool_global(x):
     return x.mean(axis=(1, 2))  # darknet [avgpool] is global
 
 
+@_scoped
 def upsample(x, *, stride: int):
-    b, h, w, c = x.shape
     return jnp.repeat(jnp.repeat(x, stride, axis=1), stride, axis=2)
 
 
+@_scoped
 def shortcut(x, other, *, act: str = "linear"):
     return apply_act(x + other, act)
 
 
+@_scoped
 def route(tensors):
     return jnp.concatenate(tensors, axis=-1)
+
+
+@_scoped
+def yolo(x, *, classes: int):
+    """Darknet [yolo] at inference (``forward_yolo_layer``): of each
+    anchor's ``5 + classes`` entries, anchor-major on the channel axis,
+    the logistic on 0-1 (x, y) and 4.. (objectness, classes); 2-3 (w, h)
+    stay linear."""
+    entry = np.arange(x.shape[-1]) % (5 + classes)
+    return jnp.where((entry < 2) | (entry >= 4), jax.nn.sigmoid(x), x)
 
 
 def connected(engine: ComputeEngine, params: dict, x, *, act: str):

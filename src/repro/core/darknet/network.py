@@ -16,6 +16,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import math
 import time
 import warnings
 from typing import Any, Iterable
@@ -37,7 +38,11 @@ class LayerPlan:
 
 
 class Network:
-    """Built from a darknet cfg; functional apply(params, x)."""
+    """Built from a darknet cfg; functional apply(params, x).
+
+    The output is the last layer's, or, for a detector, the tuple of its
+    ``[yolo]`` layers' outputs in cfg order, as darknet's detectors return
+    theirs.  `layer_counts` counts the planned layers by kind."""
 
     def __init__(self, cfg_text: str, engine: ComputeEngine | None = None):
         self.engine = engine or ComputeEngine()
@@ -90,11 +95,19 @@ class Network:
                 h, w, c = 1, 1, n
             elif t in ("softmax", "dropout"):
                 pass
+            elif t == "yolo":
+                n = len(s.get("mask", range(s.get("num", 1))))
+                want = n * (5 + s.get("classes", 20))
+                if c != want:
+                    raise ValueError(f"layer {i}: [yolo] with {n} anchors "
+                                     f"needs {want} channels, got {c}")
             else:
                 raise ValueError(f"unplanned layer {t}")
             shapes.append((h, w, c))
             self.plans.append(LayerPlan(i, t, dict(s.options), (h, w, c)))
         self.out_shape = shapes[-1]
+        self.layer_counts = dict(collections.Counter(
+            p.type for p in self.plans))
 
     # ----------------------------------------------------------------- init
     def init(self, key) -> dict:
@@ -126,9 +139,11 @@ class Network:
 
     # -------------------------------------------------------------- forward
     def apply(self, params: dict, x):
-        """x: (B, H, W, C) -> network output."""
+        """x: (B, H, W, C) -> network output (a tuple of the heads' for a
+        network with ``[yolo]`` layers)."""
         eng = self.engine
         outputs: list = []
+        heads: list = []
         for p in self.plans:
             t, o = p.type, p.options
             if t == "convolutional":
@@ -167,8 +182,11 @@ class Network:
                 x = L.softmax(x)
             elif t == "dropout":
                 pass  # inference no-op
+            elif t == "yolo":
+                x = L.yolo(x, classes=o.get("classes", 20))
+                heads.append(x)
             outputs.append(x)
-        return x
+        return tuple(heads) if heads else x
 
     def num_params(self, params) -> int:
         return sum(int(p.size) for p in jax.tree_util.tree_leaves(params))
@@ -290,6 +308,9 @@ class CompiledNetwork:
         self.op_counts = backends.counts_since(before)
         self.op_log = tuple(backends.dispatch_log()[log_mark:])
         self.gemm_padded = backends.gemm_padded(self.op_log)
+        outs = jax.tree.leaves(traced.out_info)
+        self.outputs = {"arrays": len(outs), "bytes_per_item": sum(
+            math.prod(o.shape[1:]) * o.dtype.itemsize for o in outs)}
         self.autotune_keys = tuple(
             k for k in backends.autotune_report() if k not in before_tuned)
 
@@ -361,9 +382,11 @@ class CompiledNetwork:
           reps: timed repetitions after one untimed warm call.
 
         Returns `{per_call_s, reps, batch_size, trace_count, op_counts,
-        gemm_padded, autotune}`; ``gemm_padded`` says how many of the
-        lowering's tiled GEMM dispatches pad an operand
-        (`backends.gemm_padded`).
+        gemm_padded, outputs, layers, autotune}`; ``gemm_padded`` says how
+        many of the lowering's tiled GEMM dispatches pad an operand
+        (`backends.gemm_padded`), ``outputs`` the output arrays and their
+        bytes per batch row (``{arrays, bytes_per_item}``), ``layers`` the
+        planned layers by kind.
         """
         if x is None:
             x = jnp.zeros(self.in_spec.shape, self.in_spec.dtype)
@@ -378,6 +401,8 @@ class CompiledNetwork:
                 "trace_count": self._trace_count,
                 "op_counts": dict(self.op_counts),
                 "gemm_padded": self.gemm_padded,
+                "outputs": dict(self.outputs),
+                "layers": dict(self.net.layer_counts),
                 "autotune": self.autotune_report()}
 
 
@@ -392,15 +417,17 @@ class CompileCache:
     split into top-bucket chunks.
 
     Padding is sound because every planned layer is row-independent across
-    the batch dim (conv/pool/connected/softmax all act per-image), so the
+    the batch dim (conv/pool/connected/softmax/yolo all act per-image), so the
     real rows of a padded dispatch are bitwise identical to an exact-batch
     execution — tests/test_compile_cache.py asserts this.
 
     Observability: `hits`/`misses` count bucket-cache lookups, `stats()`
     reports traces, the per-bucket dispatch histogram, the pad-waste
-    fraction (padded rows / total dispatched rows), and ``gemm_padded``:
+    fraction (padded rows / total dispatched rows), ``gemm_padded``:
     the compiled buckets' tiled GEMM dispatches and how many of them pad
-    an operand (`backends.gemm_padded`).
+    an operand (`backends.gemm_padded`), ``outputs``: the output arrays and
+    their bytes per image (None before the first compile), and ``layers``:
+    the planned layers by kind.
     """
 
     def __init__(self, net: Network, params: dict,
@@ -451,9 +478,9 @@ class CompileCache:
         x: (n, H, W, C) with the cache dtype; n >= 1.  Batches above the top
         bucket are processed in top-bucket chunks and concatenated.
 
-        Returns the (n, ...) network output for the real rows.  Raises
-        ValueError on an empty batch or a dtype differing from the cache's
-        compiled dtype.
+        Returns the (n, ...) network output for the real rows (a tuple of
+        them for a multi-output network).  Raises ValueError on an empty
+        batch or a dtype differing from the cache's compiled dtype.
         """
         n = x.shape[0]
         if n == 0:
@@ -463,8 +490,9 @@ class CompileCache:
                              f"got {jnp.dtype(x.dtype)}")
         top = self.buckets[-1]
         if n > top:
-            return jnp.concatenate(
-                [self.run(x[i:i + top]) for i in range(0, n, top)], axis=0)
+            return jax.tree.map(
+                lambda *ys: jnp.concatenate(ys, axis=0),
+                *[self.run(x[i:i + top]) for i in range(0, n, top)])
         b = self.bucket_for(n)
         cn = self.get(b)
         xb = x if b == n else jnp.concatenate(
@@ -473,6 +501,8 @@ class CompileCache:
         self._dispatches[b] += 1
         self._rows_real += n
         self._rows_pad += b - n
+        if isinstance(y, tuple):
+            return tuple(a[:n] for a in y)
         return y[:n]
 
     @property
@@ -518,5 +548,8 @@ class CompileCache:
             "rows_padded": self._rows_pad,
             "pad_waste": (self._rows_pad / total) if total else 0.0,
             "gemm_padded": self._gemm_padded(),
+            "outputs": next((dict(cn.outputs)
+                             for cn in self._compiled.values()), None),
+            "layers": dict(self.net.layer_counts),
             "autotune": {"keys": len(tuned), "sources": dict(sources)},
         }

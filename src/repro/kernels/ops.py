@@ -30,6 +30,13 @@ def _round_up(x: int, m: int) -> int:
 
 # Double-buffered VMEM working set target (~half of a 16 MiB/core VMEM).
 _VMEM_BUDGET = 8 * 1024 * 1024
+# The GEMM kernels' budget for `_working_set`: Mosaic's 16 MiB scoped VMEM
+# limit over the most it allocates per byte of that model.  Float32 at
+# HIGHEST keeps split operands and partial products beside the tiles:
+# compiles for a described v5e need 1.6-2.4x the model (the (676, 768, 256)
+# plan of a 26x26 3x3 conv over three K steps 16.2 MiB for 6.8 modeled,
+# and refused; Darknet-19's largest, (392, 1152, 256), 14.1 for 6.5).
+_GEMM_VMEM_BUDGET = 6.5 * 1024 * 1024
 
 # Alignment of each GEMM block dimension (bm, bk, bn): bm counts sublanes
 # (8), bk and bn count lanes (128).  Mosaic takes a block dimension that is
@@ -72,7 +79,7 @@ def pick_blocks(m: int, k: int, n: int, dtype,
     of 8, bk/bn of 128) or equals the whole extent, so the wrapper pads
     and slices nothing.  Among such plans: the first, in each axis's
     `_exact_blocks` order (bm outermost, bk innermost), whose
-    double-buffered `_working_set` fits `_VMEM_BUDGET` — large row blocks
+    double-buffered `_working_set` fits `_GEMM_VMEM_BUDGET` — large row blocks
     first, since they read the weights fewest times.  Where no exact plan
     fits, the axes that cannot be tiled exactly fall back to the padded
     pick of `padded_blocks`, each on its own.
@@ -90,7 +97,8 @@ def pick_blocks(m: int, k: int, n: int, dtype,
         for bm in prefs[0]:
             for bn in prefs[2]:
                 for bk in prefs[1]:
-                    if _working_set(bm, bk, bn, itemsize) <= _VMEM_BUDGET:
+                    if (_working_set(bm, bk, bn, itemsize)
+                            <= _GEMM_VMEM_BUDGET):
                         return bm, bk, bn
     return padded
 
@@ -109,7 +117,7 @@ def padded_blocks(m: int, k: int, n: int, dtype,
     bk = 128
     while bk < cap_k:
         nxt = bk * 2
-        if (_working_set(bm, nxt, bn, itemsize) > _VMEM_BUDGET
+        if (_working_set(bm, nxt, bn, itemsize) > _GEMM_VMEM_BUDGET
                 or nxt > _round_up(k, 128)):
             break
         bk = nxt
@@ -195,9 +203,9 @@ def validate_gemm_tiles(m: int, k: int, n: int, dtype,
                             f"extent {_round_up(dim, align)} (dead grid "
                             f"steps)")
     ws = _working_set(*tiles, jnp.dtype(dtype).itemsize)
-    if ws > _VMEM_BUDGET:
+    if ws > _GEMM_VMEM_BUDGET:
         problems.append(f"working set {ws} B exceeds the VMEM budget "
-                        f"{_VMEM_BUDGET} B")
+                        f"{_GEMM_VMEM_BUDGET:.0f} B")
     return problems
 
 
@@ -311,7 +319,7 @@ def candidate_gemm_bwd_blocks(variant: str, rows: int, kdim: int, cols: int,
                 max(128, min(_round_up(vn, 128), 512)))
         if cand in cands:
             continue
-        if _working_set(*cand, itemsize) > _VMEM_BUDGET:
+        if _working_set(*cand, itemsize) > _GEMM_VMEM_BUDGET:
             continue
         cands.append(cand)
     return cands

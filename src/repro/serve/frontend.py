@@ -87,9 +87,11 @@ class Request:
 
 @dataclasses.dataclass
 class ImageRequest(Request):
-    """One image through a compiled CNN; `result` holds the network output."""
+    """One image through a compiled CNN; `result` holds the image's row of
+    the network output (a tuple of its rows of each output for a
+    multi-output network, such as a detector's heads)."""
     image: np.ndarray | None = None
-    result: np.ndarray | None = None
+    result: np.ndarray | tuple | None = None
 
 
 class ServingFrontend(abc.ABC):
@@ -224,7 +226,8 @@ class CNNServingEngine(ServingFrontend):
     the batch to the device (enqueue), `cnn.dispatch` is `CompileCache.run`
     (bucket pick, pad, the executable call, the row slice; returns once
     enqueued), `cnn.wait` blocks until the output is ready (input transfer,
-    device program, output), `cnn.fetch` copies the output to the host and
+    device program, output), `cnn.fetch` copies the output to the host
+    (every output of a multi-output network, in one `jax.device_get`) and
     completes each request.  `stats()["stages"]` aggregates them over every
     step; the last `STEPS_KEPT` records are kept whole (`step_records()`)
     in a preallocated ring.  `anchor_ns`,
@@ -279,10 +282,13 @@ class CNNServingEngine(ServingFrontend):
         t3 = ns()
         jax.block_until_ready(y)
         t4 = ns()
-        y = np.asarray(y)
+        if isinstance(y, tuple):
+            rows = list(zip(*jax.device_get(y)))
+        else:
+            rows = np.asarray(y)
         t_done = time.perf_counter()
         for i, r in enumerate(batch):
-            r.result = y[i]
+            r.result = rows[i]
             r.done = True
             r.t_done = t_done
             self._latency.add(r.latency_s)

@@ -275,7 +275,7 @@ def test_candidates_include_heuristic_and_respect_budget():
             assert bk % 128 == 0 or bk == k
             assert bn % 128 == 0 or bn == n
             assert kernel_ops._working_set(
-                bm, bk, bn, 4) <= kernel_ops._VMEM_BUDGET
+                bm, bk, bn, 4) <= kernel_ops._GEMM_VMEM_BUDGET
             assert not kernel_ops.validate_gemm_tiles(m, k, n, "float32",
                                                       (bm, bk, bn))
 
@@ -497,7 +497,7 @@ def test_gemm_bwd_candidates_mxu_aligned_and_vmem_filtered():
         for bm, bk, bn in cands:
             assert bm % 8 == 0 and bk % 128 == 0 and bn % 128 == 0
             assert kernel_ops._working_set(
-                bm, bk, bn, 4) <= kernel_ops._VMEM_BUDGET
+                bm, bk, bn, 4) <= kernel_ops._GEMM_VMEM_BUDGET
         if variant.startswith("b"):       # the bmm clamp applies
             assert base == kernel_ops.padded_blocks(
                 rows, kdim, cols, "float32", kernel_ops._CAPS["bmm"])

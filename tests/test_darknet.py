@@ -38,7 +38,7 @@ def test_parse_roundtrip():
 
 def test_parse_rejects_unknown_section():
     with pytest.raises(ValueError):
-        cfg_mod.parse_cfg("[net]\nheight=8\nwidth=8\nchannels=1\n[yolo]\n")
+        cfg_mod.parse_cfg("[net]\nheight=8\nwidth=8\nchannels=1\n[crnn]\n")
 
 
 def test_conv_pad_rule():

@@ -3,8 +3,8 @@ alignment (bm to 8 sublanes, bk/bn to 128 lanes) or spans it whole, so
 `ops.matmul` runs the kernel on its operands as given — no `jnp.pad` of
 the activations or weights, no slice of the output.
 
-Covers the plan rule over Darknet-19's 19 conv GEMMs at buckets 1 and 8
-and the qwen2-0.5b projections, the padded fallback where no exact plan
+Covers the plan rule over Darknet-19's 19 conv GEMMs at buckets 1 and 8,
+YOLOv3-416's at bucket 1 and the qwen2-0.5b projections, the padded fallback where no exact plan
 fits the VMEM budget (and the `gemm_padded` counter that reports it), the
 numbers of full-extent unaligned blocks against float64 numpy, and one
 gradient through the custom VJP at such a shape.
@@ -27,6 +27,16 @@ DARKNET19_GEMMS = [
     (196, 2304, 512), (49, 4608, 1024), (49, 1024, 512), (49, 4608, 1024),
     (49, 1024, 512), (49, 4608, 1024), (49, 1024, 1000),
 ]
+# YOLOv3-416's distinct conv GEMMs per image: the stem and downsamples,
+# the residual 1x1/3x3 pairs, the heads (N = 255) and the convolutions
+# after the cross-scale routes (K = 768, 384).
+YOLOV3_GEMMS = [
+    (173056, 27, 32), (43264, 288, 64), (43264, 64, 32), (10816, 576, 128),
+    (10816, 128, 64), (2704, 1152, 256), (2704, 256, 128), (676, 2304, 512),
+    (676, 512, 256), (169, 4608, 1024), (169, 1024, 512), (169, 1024, 255),
+    (169, 512, 256), (676, 768, 256), (676, 512, 255), (676, 256, 128),
+    (2704, 384, 128), (2704, 256, 255),
+]
 # qwen2-0.5b (d 896, 14 heads / 2 KV heads of 64, MLP 4864, vocabulary
 # 151936): q/o, k/v, gate/up, down and head projections.
 QWEN2_PROJECTIONS = [(896, 896), (896, 128), (896, 4864), (4864, 896),
@@ -35,6 +45,8 @@ QWEN2_PROJECTIONS = [(896, 896), (896, 128), (896, 4864), (4864, 896),
 PLAN_CASES = (
     [pytest.param(b * hw, k, n, id=f"darknet19-b{b}-conv{i + 1}")
      for b in (1, 8) for i, (hw, k, n) in enumerate(DARKNET19_GEMMS)]
+    + [pytest.param(m, k, n, id=f"yolov3-{m}x{k}x{n}")
+       for m, k, n in YOLOV3_GEMMS]
     + [pytest.param(m, k, n, id=f"qwen2-{m}x{k}x{n}")
        for m in (1, 64, 512) for k, n in QWEN2_PROJECTIONS])
 
@@ -51,7 +63,7 @@ def test_plan_is_exact_fits_vmem_and_pads_nothing(m, k, n):
     assert bm % 8 == 0 or bm == m
     assert bk % 128 == 0 or bk == k
     assert bn % 128 == 0 or bn == n
-    assert ops._working_set(*plan, 4) <= ops._VMEM_BUDGET
+    assert ops._working_set(*plan, 4) <= ops._GEMM_VMEM_BUDGET
     assert not ops.validate_gemm_tiles(m, k, n, "float32", plan)
     assert ops.gemm_padding(m, k, n, plan) == (m, k, n)
     jaxpr = jax.make_jaxpr(
@@ -69,11 +81,14 @@ def test_plan_is_exact_fits_vmem_and_pads_nothing(m, k, n):
     (196, 2304, 512, (196, 1152, 256)),     # conv9 at bucket 1
     (49, 1024, 1000, (49, 512, 1000)),      # conv19: bn the whole N
     (50176, 27, 32, (256, 27, 32)),         # conv1: bk, bn whole
+    (676, 2304, 512, (676, 384, 256)),      # YOLOv3 26x26 3x3: M = 676
+    (676, 768, 256, (676, 384, 256)),       # has no aligned divisor
 ])
 def test_plan_examples(m, k, n, plan):
     """Full extents above the cap where the aligned divisors under it are
     small (M = 392: 392 rather than 56), bk shrunk to an exact divisor
-    that fits the budget."""
+    that fits the budget (for M = 676, bk 768 would need 16.2 MiB of
+    Mosaic's 16 MiB scoped VMEM on a v5e)."""
     assert ops.default_blocks("matmul", m, k, n, "float32") == plan
 
 

@@ -20,7 +20,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs.darknet_ref import DARKNET19_CFG
+from repro.configs.darknet_ref import DARKNET19_CFG, YOLOV3_CFG
 from repro.core import make_engine
 from repro.core.darknet.network import Network
 from repro.kernels import ops, sharded
@@ -141,6 +141,18 @@ def test_darknet19_network_compiles(one_chip):
     params = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
                           jax.eval_shape(net.init, jax.random.key(0)))
     _assert_kernel(net.apply, params, _spec(one_chip, (8, 224, 224, 3)))
+
+
+def test_yolov3_network_compiles(one_chip):
+    """YOLOv3-416 at bucket 1: GEMM extents Darknet-19 never had (K = 27 at
+    M = 173056, N = 255 heads, K = 768 and 384 after the cross-scale
+    routes, M = 676 with no aligned row divisor, whose (676, 768, 256)
+    plan once overflowed Mosaic's scoped VMEM)."""
+    net = Network(YOLOV3_CFG,
+                  make_engine("pallas", "fp32_strict", interpret=False))
+    params = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
+                          jax.eval_shape(net.init, jax.random.key(0)))
+    _assert_kernel(net.apply, params, _spec(one_chip, (1, 416, 416, 3)))
 
 
 # (rows, query length, key length): rows 64 shard over the 4-chip data
