@@ -562,6 +562,15 @@ def im2col_conv2d(matmul_impl: Callable) -> Callable:
     return conv2d
 
 
+def im2col_phased(records) -> dict:
+    """How many of a dispatch-log slice's conv2d dispatches build their
+    patches from the input's phases (`kernels.common.im2col` does at
+    every stride above 1): ``{convs, phased}``."""
+    # A conv2d record's shapes: (x.shape, cout, size, stride, pad).
+    strides = [r["shapes"][3] for r in records if r["op"] == "conv2d"]
+    return {"convs": len(strides), "phased": sum(s > 1 for s in strides)}
+
+
 # ------------------------------------------------------- pallas backend ---
 
 def _pallas_matmul(x, w, scale, shift, *, act, out_dtype, ctx):

@@ -308,6 +308,7 @@ class CompiledNetwork:
         self.op_counts = backends.counts_since(before)
         self.op_log = tuple(backends.dispatch_log()[log_mark:])
         self.gemm_padded = backends.gemm_padded(self.op_log)
+        self.im2col_phased = backends.im2col_phased(self.op_log)
         outs = jax.tree.leaves(traced.out_info)
         self.outputs = {"arrays": len(outs), "bytes_per_item": sum(
             math.prod(o.shape[1:]) * o.dtype.itemsize for o in outs)}
@@ -382,11 +383,13 @@ class CompiledNetwork:
           reps: timed repetitions after one untimed warm call.
 
         Returns `{per_call_s, reps, batch_size, trace_count, op_counts,
-        gemm_padded, outputs, layers, autotune}`; ``gemm_padded`` says how
-        many of the lowering's tiled GEMM dispatches pad an operand
-        (`backends.gemm_padded`), ``outputs`` the output arrays and their
-        bytes per batch row (``{arrays, bytes_per_item}``), ``layers`` the
-        planned layers by kind.
+        gemm_padded, im2col_phased, outputs, layers, autotune}`;
+        ``gemm_padded`` says how many of the lowering's tiled GEMM
+        dispatches pad an operand (`backends.gemm_padded`),
+        ``im2col_phased`` how many of its convolutions read their patches
+        from the input's phases (`backends.im2col_phased`), ``outputs``
+        the output arrays and their bytes per batch row (``{arrays,
+        bytes_per_item}``), ``layers`` the planned layers by kind.
         """
         if x is None:
             x = jnp.zeros(self.in_spec.shape, self.in_spec.dtype)
@@ -401,6 +404,7 @@ class CompiledNetwork:
                 "trace_count": self._trace_count,
                 "op_counts": dict(self.op_counts),
                 "gemm_padded": self.gemm_padded,
+                "im2col_phased": self.im2col_phased,
                 "outputs": dict(self.outputs),
                 "layers": dict(self.net.layer_counts),
                 "autotune": self.autotune_report()}
@@ -425,7 +429,9 @@ class CompileCache:
     reports traces, the per-bucket dispatch histogram, the pad-waste
     fraction (padded rows / total dispatched rows), ``gemm_padded``:
     the compiled buckets' tiled GEMM dispatches and how many of them pad
-    an operand (`backends.gemm_padded`), ``outputs``: the output arrays and
+    an operand (`backends.gemm_padded`), ``im2col_phased``: their
+    convolutions and how many read patches from the input's phases
+    (`backends.im2col_phased`), ``outputs``: the output arrays and
     their bytes per image (None before the first compile), and ``layers``:
     the planned layers by kind.
     """
@@ -523,13 +529,14 @@ class CompileCache:
             out.update(cn.autotune_report())
         return out
 
-    def _gemm_padded(self) -> dict:
-        """`CompiledNetwork.gemm_padded` summed over the compiled buckets
-        (read at compile time, so `stats()` stays cheap per step)."""
-        out = {"gemms": 0, "padded": 0, "operand_bytes": 0,
-               "padded_operand_bytes": 0, "shapes": []}
+    def _summed(self, counter: str) -> dict:
+        """A `CompiledNetwork` dispatch-log counter (``gemm_padded``,
+        ``im2col_phased``: `backends` functions of the same names) summed
+        over the compiled buckets (read at compile time, so `stats()`
+        stays cheap per step)."""
+        out = getattr(backends, counter)(())
         for cn in self._compiled.values():
-            for key, val in cn.gemm_padded.items():
+            for key, val in getattr(cn, counter).items():
                 out[key] += val
         return out
 
@@ -547,7 +554,8 @@ class CompileCache:
             "rows_real": self._rows_real,
             "rows_padded": self._rows_pad,
             "pad_waste": (self._rows_pad / total) if total else 0.0,
-            "gemm_padded": self._gemm_padded(),
+            "gemm_padded": self._summed("gemm_padded"),
+            "im2col_phased": self._summed("im2col_phased"),
             "outputs": next((dict(cn.outputs)
                              for cn in self._compiled.values()), None),
             "layers": dict(self.net.layer_counts),

@@ -108,9 +108,16 @@ def im2col(x, kh: int, kw: int, stride: int, pad: int):
 
     The canonical Darknet conv lowering: materialize patches, GEMM on the
     engine.  Shared by every backend's im2col-based conv2d op.  Patches are
-    strided slices of the padded input — exact copies on every platform
-    (a patches convolution would run on the TPU's MXU at the default
-    precision and round every input to bf16).
+    slices of the padded input — exact copies on every platform (a patches
+    convolution would run on the TPU's MXU at the default precision and
+    round every input to bf16).  At stride 1 each tap is a window of the
+    padded input.  At stride s > 1 the padded input is split into its
+    s x s phases (every s-th row and column, from each of the s offsets),
+    and tap (ki, kj) is a unit-stride window of phase (ki % s, kj % s) at
+    offset (ki // s, kj // s): a strided slice on the TPU's tiled W axis
+    lowers to a gather, the phase split to a reshape, a transpose and
+    plain slices.  Both give the same (kh, kw, C)-ordered patches, bit for
+    bit.
 
     Carries a custom VJP whose backward is a col2im scatter-add (the
     `deconv2d` idiom) accumulated in fp32: patch cotangents accumulate back
@@ -120,15 +127,28 @@ def im2col(x, kh: int, kw: int, stride: int, pad: int):
 
 
 def _im2col_fwd_impl(x, kh, kw, stride, pad):
-    _, h, w, _ = x.shape
-    oh = (h + 2 * pad - kh) // stride + 1
-    ow = (w + 2 * pad - kw) // stride + 1
-    xp = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
+    b, h, w, c = x.shape
+    s = stride
+    oh = (h + 2 * pad - kh) // s + 1
+    ow = (w + 2 * pad - kw) // s + 1
+    if s == 1:
+        xp = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
+        taps = [xp[:, ki:ki + oh, kj:kj + ow, :]
+                for ki in range(kh) for kj in range(kw)]
+    else:
+        # Pad (or crop) to the (s*hq, s*wq) rows and columns the taps
+        # read; the extra ones sit at the bottom and right, never read.
+        hq, wq = oh + (kh - 1) // s, ow + (kw - 1) // s
+        xp = jax.lax.pad(x, jnp.zeros((), x.dtype),
+                         ((0, 0, 0), (pad, s * hq - h - pad, 0),
+                          (pad, s * wq - w - pad, 0), (0, 0, 0)))
+        # (B, s, s, hq, wq, C): phase (p, q) holds xp[:, p::s, q::s].
+        ph = xp.reshape(b, hq, s, wq, s, c).transpose(0, 2, 4, 1, 3, 5)
+        taps = [ph[:, ki % s, kj % s, ki // s:ki // s + oh,
+                   kj // s:kj // s + ow, :]
+                for ki in range(kh) for kj in range(kw)]
     # Tap-major, channel-minor: (kh, kw, C) order, the HWIO weight layout.
-    return jnp.concatenate(
-        [xp[:, ki:ki + (oh - 1) * stride + 1:stride,
-            kj:kj + (ow - 1) * stride + 1:stride, :]
-         for ki in range(kh) for kj in range(kw)], axis=-1)
+    return jnp.concatenate(taps, axis=-1)
 
 
 def col2im(g, x_shape: tuple, kh: int, kw: int, stride: int, pad: int):

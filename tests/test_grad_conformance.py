@@ -35,6 +35,7 @@ from repro.configs.base import get_arch, reduced
 from repro.configs.darknet_ref import DARKNET_SMALL_CFG
 from repro.core import backends, make_engine
 from repro.core.darknet.network import Network
+from repro.kernels.common import apply_act
 from repro.models import transformer as tfm
 from repro.train.train_step import cnn_loss_fn
 
@@ -50,6 +51,13 @@ CONV_CASES = [
     (2, 14, 14, 16, 32, 3, 1, 1),
     (2, 7, 7, 32, 64, 3, 1, 1),
     (1, 9, 11, 5, 7, 3, 2, 1),
+]
+# Stride-2 convolutions: YOLOv3's 3x3 downsample, odd extents, and an even
+# kernel whose last input column no tap reads.
+STRIDED_CONV_CASES = [
+    (1, 16, 16, 8, 16, 3, 2, 1),
+    (2, 9, 11, 5, 7, 3, 2, 1),
+    (1, 10, 9, 4, 6, 2, 2, 0),
 ]
 # connected head + LM MLP shapes + a ragged everything-padded case
 MATMUL_CASES = [
@@ -137,9 +145,21 @@ def test_bmm_grad_parity_fp32(backend, b, m, k, n):
     _assert_tree_close(got, want, FP32_TOL, ("x", "w"))
 
 
+def _lax_conv2d(x, wt, *, scale, shift, size, stride, pad, act):
+    """conv2d by XLA's own convolution at HIGHEST precision: no im2col, no
+    col2im, so an oracle for both directions of the patch map."""
+    w4 = wt.reshape(size, size, x.shape[-1], -1)
+    y = jax.lax.conv_general_dilated(
+        x, w4, (stride, stride), [(pad, pad)] * 2,
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST)
+    return apply_act(y * scale + shift, act)
+
+
 def _conv_grads(backend, b, h, w_, cin, cout, size, stride, pad, act,
                 dtype=jnp.float32):
-    eng = make_engine(backend)
+    conv2d = (_lax_conv2d if backend == "lax"
+              else make_engine(backend).conv2d)
     ks = jax.random.split(jax.random.PRNGKey(h * 100 + cin + cout), 4)
     x = jax.random.normal(ks[0], (b, h, w_, cin), jnp.float32).astype(dtype)
     wt = (jax.random.normal(ks[1], (size * size * cin, cout))
@@ -148,8 +168,8 @@ def _conv_grads(backend, b, h, w_, cin, cout, size, stride, pad, act,
     sh = (jax.random.normal(ks[3], (cout,)) * 0.2).astype(dtype)
 
     def loss(x, wt, sc, sh):
-        y = eng.conv2d(x, wt, scale=sc, shift=sh, size=size, stride=stride,
-                       pad=pad, act=act)
+        y = conv2d(x, wt, scale=sc, shift=sh, size=size, stride=stride,
+                   pad=pad, act=act)
         return (y.astype(jnp.float32) ** 2).sum()
 
     return jax.grad(loss, argnums=(0, 1, 2, 3))(x, wt, sc, sh)
@@ -164,6 +184,17 @@ def test_conv2d_grad_parity_fp32(backend, case):
     and shift cotangents included)."""
     got = _conv_grads(backend, *case, "leaky")
     want = _conv_grads("ref", *case, "leaky")
+    _assert_tree_close(got, want, FP32_TOL, ("x", "w", "scale", "shift"))
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("case", STRIDED_CONV_CASES)
+def test_strided_conv2d_grad_parity_with_lax_conv(backend, case):
+    """At stride 2 im2col reads its taps from the input's phases while the
+    col2im backward scatters through strided slices; against XLA's own
+    convolution, which shares neither, both directions must agree."""
+    got = _conv_grads(backend, *case, "leaky")
+    want = _conv_grads("lax", *case, "leaky")
     _assert_tree_close(got, want, FP32_TOL, ("x", "w", "scale", "shift"))
 
 

@@ -67,8 +67,10 @@ def _spec(sharding, shape, dtype=jnp.float32):
 
 
 def _assert_kernel(fn, *specs):
-    compiled = jax.jit(fn).lower(*specs).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    """Compile; assert a Mosaic kernel is there.  Returns the HLO text."""
+    text = jax.jit(fn).lower(*specs).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
 
 
 def test_gemm_forward_compiles(one_chip):
@@ -135,6 +137,20 @@ def test_split_kv_decode_compiles(one_chip):
                    _spec(one_chip, (b,), jnp.int32))
 
 
+def test_stride2_conv_compiles_without_gather(one_chip):
+    """YOLOv3's 52x52x256 -> 26x26x512 downsample: im2col reads each tap
+    from one of the input's four phases with unit stride; strided slices
+    on the tiled W axis were lowered to gathers, one per tap."""
+    eng = make_engine("pallas", "fp32_strict", interpret=False)
+
+    def conv(x, w):
+        return eng.conv2d(x, w, size=3, stride=2, pad=1, act="leaky")
+
+    text = _assert_kernel(conv, _spec(one_chip, (1, 52, 52, 256)),
+                          _spec(one_chip, (9 * 256, 512)))
+    assert " gather(" not in text
+
+
 def test_darknet19_network_compiles(one_chip):
     net = Network(DARKNET19_CFG,
                   make_engine("pallas", "fp32_strict", interpret=False))
@@ -147,12 +163,15 @@ def test_yolov3_network_compiles(one_chip):
     """YOLOv3-416 at bucket 1: GEMM extents Darknet-19 never had (K = 27 at
     M = 173056, N = 255 heads, K = 768 and 384 after the cross-scale
     routes, M = 676 with no aligned row divisor, whose (676, 768, 256)
-    plan once overflowed Mosaic's scoped VMEM)."""
+    plan once overflowed Mosaic's scoped VMEM), and five stride-2
+    downsamples whose im2col holds no gather."""
     net = Network(YOLOV3_CFG,
                   make_engine("pallas", "fp32_strict", interpret=False))
     params = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
                           jax.eval_shape(net.init, jax.random.key(0)))
-    _assert_kernel(net.apply, params, _spec(one_chip, (1, 416, 416, 3)))
+    text = _assert_kernel(net.apply, params,
+                          _spec(one_chip, (1, 416, 416, 3)))
+    assert " gather(" not in text
 
 
 # (rows, query length, key length): rows 64 shard over the 4-chip data
