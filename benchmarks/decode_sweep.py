@@ -13,9 +13,8 @@ in CPU interpret mode the wall-clock ratio is reported informationally
 
   * registry dispatch: a decode-shaped `engine.attention` on the pallas
     backend selects the split-KV formulation and resolves its
-    (bk_split, n_splits) tiles under the lazy "attention_decode" autotune
-    key (benchmarks/autotune_sweep.py --check-persisted covers the same
-    keys from the persisted table);
+    (bk_split, n_splits) tiles under the lazy "attention_decode"
+    tile-plan key;
   * numerical parity of all three formulations on the same problem;
   * greedy token BIT-parity: the fixed-slot serving engine (whose decode
     cache extent >= 256 rows puts every decode step on the split path)
@@ -122,9 +121,7 @@ def _hybrid_backend(name: str):
     pallas = backends.get_backend("pallas")
     xla = backends.get_backend("xla")
     register_backend(name, dict(xla.ops, attention=pallas.op("attention")),
-                     tile_picker=pallas.tile_picker,
-                     tile_candidates=pallas.tile_candidates,
-                     tile_bench=pallas.tile_bench, overwrite=True)
+                     tile_picker=pallas.tile_picker, overwrite=True)
 
 
 def smoke():
@@ -141,11 +138,11 @@ def smoke():
     n_att = backends.counts_since(snap).get(("pallas", "attention"), 0)
     if n_att != 1:
         raise SystemExit(f"FAIL: decode dispatch count {n_att} != 1")
-    dec_keys = [k2 for k2 in backends.autotune_report()
-                if '"attention_decode"' in k2]
+    dec_keys = [k2 for k2 in backends.tile_plans()
+                if k2[0] == "attention_decode"]
     if not dec_keys:
         raise SystemExit("FAIL: decode-shaped pallas dispatch resolved no "
-                         "attention_decode autotune key (split-KV "
+                         "attention_decode tile-plan key (split-KV "
                          "formulation not selected)")
     want = make_engine("xla", "fp32_strict").attention(
         q, k, v, causal=True, kv_len=kvl)

@@ -3,8 +3,8 @@ spot; 'results with different dimensions are fully in line').
 
 Measures the XLA-backend engine on CPU across shapes and validates the
 Pallas-backend engine against it at every shape (both resolve through the
-backend registry); derives modeled v5e times and reports the autotune
-block-pick cache behaviour across the sweep.
+backend registry); derives modeled v5e times and reports the tile-plan
+cache behaviour across the sweep.
 """
 from __future__ import annotations
 
@@ -55,7 +55,7 @@ def run() -> list[tuple[str, float, str]]:
         rows.append((f"engine_sweep/{m}x{k}x{n}", t * 1e6,
                      f"GFLOPS={gf:.1f} kernel_err={err:.1e}"))
     stats = backends.cache_stats()
-    rows.append(("engine_sweep/autotune_cache", 0.0,
+    rows.append(("engine_sweep/tile_plan_cache", 0.0,
                  f"hits={stats['hits'] - stats0['hits']} "
                  f"misses={stats['misses'] - stats0['misses']}"))
     return rows

@@ -294,9 +294,7 @@ def smoke() -> list[tuple[str, float, str]]:
     from repro.core import register_backend
     register_backend("train-flash",
                      dict(xla.ops, attention=pallas.op("attention")),
-                     tile_picker=pallas.tile_picker,
-                     tile_candidates=pallas.tile_candidates,
-                     tile_bench=pallas.tile_bench, overwrite=True)
+                     tile_picker=pallas.tile_picker, overwrite=True)
     try:
         feng = make_engine("train-flash", "fp32_strict")
         snap = backends.dispatch_counts()

@@ -4,7 +4,6 @@ Prints ``name,us_per_call,derived`` CSV rows.
 
   figure3_gemm     paper Fig. 3 (FP32 GEMM perf + energy efficiency)
   engine_sweep     paper §IV any-shape flexibility claim
-  autotune_sweep   heuristic vs measured block picks (docs/autotune.md)
   cnn_inference    paper's CNN use-case end-to-end (+ fusion ablation)
   lm_step          substrate: LM train/decode steps per family
   roofline_report  §Roofline table from dry-run artifacts
@@ -17,8 +16,8 @@ import sys
 def main() -> None:
     from repro.core import enable_persistent_cache
     enable_persistent_cache()
-    mods = sys.argv[1:] or ["figure3_gemm", "engine_sweep", "autotune_sweep",
-                            "cnn_inference", "lm_step", "roofline_report"]
+    mods = sys.argv[1:] or ["figure3_gemm", "engine_sweep", "cnn_inference",
+                            "lm_step", "roofline_report"]
     print("name,us_per_call,derived")
     for name in mods:
         mod = __import__(f"benchmarks.{name}", fromlist=["run"])

@@ -11,10 +11,9 @@ Must run with the host-platform device count forced BEFORE jax initializes:
         PYTHONPATH=src python benchmarks/sharded_step.py --smoke   # CI gate
 
 The --smoke gate asserts, in order:
-  * per-SHARD autotune keys: the sharded prefill resolves block plans from
-    the LOCAL shard shapes (batch 1), never the global batch-8 problem
-    (`benchmarks/autotune_sweep.py --check-persisted` covers the same keys
-    from the persisted table);
+  * per-SHARD tile-plan keys: the sharded prefill resolves block plans
+    from the LOCAL shard shapes (batch 1), never the global batch-8
+    problem;
   * fp32 parity <= 1e-5 against the single-device pallas backend for
     prefill logits, decode logits and train-step loss + gradients;
   * greedy token streams through the slot AND paged serving engines
@@ -284,24 +283,24 @@ def collective_rows(mesh) -> list[tuple[str, float, str]]:
     return rows
 
 
-# ------------------------------------------------------ per-shard autotune ---
+# ----------------------------------------------------- per-shard tile plans ---
 
-def autotune_rows(mesh) -> list[tuple[str, float, str]]:
+def tile_plan_rows(mesh) -> list[tuple[str, float, str]]:
     """The sharded prefill must resolve attention block plans from the
     PER-SHARD shapes (batch 1), never the global batch-8 problem."""
     cfg, params, toks, _, e8 = _setup()
-    backends.clear_tile_cache()     # in-process records only, table intact
+    backends.clear_tile_cache()
     pre8 = jax.jit(make_prefill_step(e8, cfg))
     with hints.use_mesh(mesh):
         jax.block_until_ready(pre8(params, {"tokens": toks})[0])
-    att_keys = [json.loads(key) for key in backends.autotune_report()
-                if json.loads(key)[0] == "attention"]
+    att_keys = [key for key in backends.tile_plans()
+                if key[0] == "attention"]
     shard_batches = {key[1][0][0] for key in att_keys}
     _gate(bool(att_keys), "sharded prefill resolved no attention tile keys")
     _gate(shard_batches == {B // 8},
           f"attention tile keys are not per-shard: batches {shard_batches} "
           f"!= {{{B // 8}}} (global batch {B} leaked into a key)")
-    return [("sharded_step/per_shard_autotune_keys", 0.0,
+    return [("sharded_step/per_shard_tile_plan_keys", 0.0,
              f"attention_keys={len(att_keys)} "
              f"per_shard_batch={sorted(shard_batches)}")]
 
@@ -310,7 +309,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--smoke", action="store_true",
                     help="parity + serving-bitwise + collective-audit + "
-                         "per-shard-autotune gates (CI)")
+                         "per-shard tile-plan gates (CI)")
     args = ap.parse_args(argv)
     print("name,us_per_call,derived")
     if jax.device_count() < 8:
@@ -320,7 +319,7 @@ def main(argv=None) -> int:
         return 0
     mesh = data_mesh()
     rows = []
-    rows += autotune_rows(mesh)        # first: needs a clean record set
+    rows += tile_plan_rows(mesh)       # first: needs a clean plan cache
     rows += parity_rows(mesh, reps=1 if args.smoke else 3)
     rows += collective_rows(mesh)
     rows += serving_rows(mesh)

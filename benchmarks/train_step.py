@@ -2,7 +2,7 @@
 the darknet_ref layer zoo, and a full-train-step smoke gate.
 
 Every registry op now carries a custom VJP on the pallas backend (GEMM
-backward kernels under lazily-resolved "gemm_bwd" autotune keys — see
+backward kernels under lazily-resolved "gemm_bwd" tile-plan keys — see
 docs/engine_api.md), so the SAME differentiated trace can run either
 backend end to end.  `run()` times jax.grad of each darknet_ref layer on
 pallas against xla (interleaved median) and reports the max relative
@@ -144,7 +144,7 @@ def cnn_step_headtohead(*, batch=4, reps=3
 
     evidence: dict = {}
     out, fns = {}, {}
-    tuned0 = set(backends.autotune_report())
+    tuned0 = set(backends.tile_plans())
     for n, net in nets.items():
         step = jax.jit(make_cnn_train_step(net, ocfg))
         snap = backends.dispatch_counts()
@@ -169,8 +169,8 @@ def cnn_step_headtohead(*, batch=4, reps=3
         op: c for (be, op), c in out["pallas"]["counts"].items()
         if be == "pallas"}
     evidence["gemm_bwd_keys"] = [
-        k for k in backends.autotune_report()
-        if k not in tuned0 and '"gemm_bwd"' in k]
+        k for k in backends.tile_plans()
+        if k not in tuned0 and k[0] == "gemm_bwd"]
     rows = [
         ("train_step/cnn_full_step_pallas", med["pallas"] * 1e6,
          f"B={batch} loss={out['pallas']['loss']:.4f} "
@@ -207,7 +207,7 @@ def smoke() -> list[tuple[str, float, str]]:
         raise SystemExit(f"FAIL: pallas train trace dispatched {got}, "
                          f"expected at least {want}")
     if not ev["gemm_bwd_keys"]:
-        raise SystemExit("FAIL: no gemm_bwd autotune keys were resolved — "
+        raise SystemExit("FAIL: no gemm_bwd tile-plan keys were resolved — "
                          "the backward ran off the pallas kernel path")
     if ev["grad_rel"] > 1e-5:
         raise SystemExit(f"FAIL: pallas-vs-xla CNN gradient parity "

@@ -4,7 +4,7 @@ compiled network originates from a registry op.
 The paper's single-engine claim is only checkable if every dense
 contraction actually routes through `ComputeEngine` — model code calling
 `jnp.einsum` / `x @ w` directly bypasses the backend registry, the
-precision policy, and the autotune cache, silently forking the compute
+precision policy, and the tile-plan cache, silently forking the compute
 path per call site.  The engine wraps each registry dispatch in
 ``jax.named_scope(backends.op_scope(op))`` ("repro.op.<op>"), which lands
 on the traced equations' name stacks (and is INHERITED through call-like

@@ -3,14 +3,14 @@ dispatched is statically legal for its problem.
 
 The static pre-launch check the paper's toolflow lineage runs before
 committing a design to hardware: each dispatch record in the engine's
-trace-time log carries the RESOLVED tile plan (heuristic pick, measured
-winner, persisted table entry, or engine-pinned bm/bk/bn), and
+trace-time log carries the RESOLVED tile plan (the backend rule's pick
+or engine-pinned bm/bk/bn), and
 `backends.validate_tiles` re-derives the kernel legality conditions — MXU
 (8, 128) lane alignment, the `_working_set` / `_attention_working_set`
 VMEM budget, and tiles no larger than the padded problem extents (a grid
-of dead tiles) — from the same formulas the kernels use.  A corrupt
-persisted autotune table or a hand-pinned engine cannot reach
-`pallas_call` with an illegal plan unnoticed.
+of dead tiles) — from the same formulas the kernels use.  An edited
+rule or a hand-pinned engine cannot reach `pallas_call` with an illegal
+plan unnoticed.
 """
 from repro.analysis import lint
 from repro.core import backends

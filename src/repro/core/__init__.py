@@ -2,17 +2,14 @@
 
 The paper's contribution as a package surface: one `ComputeEngine` serving
 every dense layer, backed by a backend/op registry (`backends.py`), the
-non-quantization precision contract (`precision.py`), and a measured
-autotuner with per-device persisted block picks (`autotune.py`,
-docs/autotune.md).  Import from here:
+non-quantization precision contract (`precision.py`), and one rule-made
+tile plan per (op, shapes, dtype, backend), memoized in process
+(docs/engine_api.md, "Tile plans").  Import from here:
 
     from repro.core import ComputeEngine, make_engine, register_backend
-    from repro.core import set_autotune_policy, autotune_policy
 """
-from repro.core.backends import (AUTOTUNE_POLICIES, OP_SET, autotune_policy,
-                                 autotune_report, get_autotune_policy,
-                                 get_backend, list_backends, register_backend,
-                                 set_autotune_policy)
+from repro.core.backends import (OP_SET, get_backend, list_backends,
+                                 register_backend, tile_plans)
 from repro.core.compile_cache import (StepCompileCache,
                                       enable_persistent_cache,
                                       normalize_buckets, pick_bucket)
@@ -25,7 +22,6 @@ from repro.core import shard_backend as _shard_backend  # noqa: F401
 
 __all__ = ["ComputeEngine", "make_engine", "Precision", "OP_SET",
            "register_backend", "get_backend", "list_backends",
-           "AUTOTUNE_POLICIES", "autotune_policy", "autotune_report",
-           "get_autotune_policy", "set_autotune_policy",
+           "tile_plans",
            "StepCompileCache", "enable_persistent_cache",
            "normalize_buckets", "pick_bucket"]

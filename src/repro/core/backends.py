@@ -5,12 +5,9 @@ dense layer of a CNN (conv-as-im2col, FC, deconv) across a heterogeneous
 system.  This module is the software form of that claim: a fixed op set
 (`OP_SET`) that every backend must implement, a `register_backend` /
 `get_backend` API so new execution targets plug in without touching
-`ComputeEngine`, and a per-process autotune cache so block-shape picks are
-made once per (op, shapes, dtype, backend) and reused across traces.  The
-cache resolves picks under a policy (`off | heuristic | measure`, see
-`set_autotune_policy`): "measure" times a candidate set on first sight and
-persists the winner to a per-device table (core/autotune.py,
-docs/autotune.md), so second processes on the same device measure nothing.
+`ComputeEngine`, and a per-process tile-plan cache so each backend's
+block-shape rule runs once per (op, shapes, dtype, backend) and its plan
+is reused across traces (docs/engine_api.md, "Tile plans").
 
 Built-in backends:
 
@@ -26,7 +23,7 @@ backend; see docs/engine_api.md.
 
 Op contract (all impls are pure functions called at trace time; `ctx` is an
 `OpContext` carrying the engine's precision policy, interpret flag and the
-tile plan resolved from the autotune cache):
+tile plan resolved from the tile-plan cache):
 
   matmul(x, w, scale, shift, *, act, out_dtype, ctx)   (M,K)@(K,N) -> (M,N)
       fused epilogue act((x @ w) * scale + shift), scale/shift (N,) or None,
@@ -47,17 +44,13 @@ tile plan resolved from the autotune cache):
 from __future__ import annotations
 
 import collections
-import contextlib
 import dataclasses
 import functools
-import os
-import warnings
 from typing import Any, Callable, Mapping
 
 import jax
 import jax.numpy as jnp
 
-from repro.core import autotune
 from repro.core.precision import Precision
 from repro.kernels import ops as kernel_ops
 from repro.kernels.common import apply_act, im2col
@@ -90,15 +83,10 @@ class OpContext:
 
 @dataclasses.dataclass(frozen=True)
 class Backend:
-    """A registered execution target: op impls + optional autotune hooks.
+    """A registered execution target: op impls + an optional tile rule.
 
-    `tile_picker(op, shapes, dtype) -> tuple` is the instant heuristic
-    pick; `tile_candidates(op, shapes, dtype) -> [tuple, ...]` enumerates
-    the design points the measured policy times, and
-    `tile_bench(op, shapes, dtype, tiles, interpret) -> thunk | None`
-    builds a zero-arg callable running one compiled call with those tiles.
-    A backend with only a picker autotunes heuristically; one with all
-    three participates in `autotune="measure"`.
+    `tile_picker(op, shapes, dtype) -> tuple` is the backend's block-shape
+    rule, a pure function of the problem; `tiles` memoizes its plans.
 
     `differentiable` is the per-op autodiff capability: the subset of the
     registered ops that support `jax.grad` through their implementation
@@ -110,8 +98,6 @@ class Backend:
     name: str
     ops: Mapping[str, Callable]
     tile_picker: Callable[[str, tuple, Any], tuple] | None = None
-    tile_candidates: Callable[[str, tuple, Any], list] | None = None
-    tile_bench: Callable[..., Callable | None] | None = None
     differentiable: frozenset = frozenset(OP_SET)
 
     def supports_grad(self, op: str) -> bool:
@@ -131,33 +117,27 @@ class Backend:
                 f"backend {self.name!r} does not implement op {name!r} "
                 f"(has: {sorted(self.ops)})") from None
 
-    def tiles(self, op: str, shapes: tuple, dtype, *,
-              interpret: bool | None = None) -> tuple:
-        """Block plan for one dispatch, resolved through the autotune
-        cache under the active policy (see `tile_plan`)."""
+    def tiles(self, op: str, shapes: tuple, dtype) -> tuple:
+        """Block plan for one dispatch, memoized in the tile-plan cache
+        (see `tile_plan`)."""
         if self.tile_picker is None:  # untiled backend: skip the cache
             return ()
-        return tile_plan(op, shapes, dtype, self.name, self.tile_picker,
-                         candidates=self.tile_candidates,
-                         bench=self.tile_bench, interpret=interpret)
+        return tile_plan(op, shapes, dtype, self.name, self.tile_picker)
 
 
 _REGISTRY: dict[str, Backend] = {}
 
 
 def register_backend(name: str, ops: Mapping[str, Callable], *,
-                     tile_picker=None, tile_candidates=None, tile_bench=None,
-                     differentiable=None, overwrite: bool = False) -> Backend:
+                     tile_picker=None, differentiable=None,
+                     overwrite: bool = False) -> Backend:
     """Register a backend implementing (a subset of) OP_SET.
 
     Args:
       name: registry key; `make_engine(name)` selects it.
       ops: op name -> impl following the op contract above.
-      tile_picker: optional `(op, shapes, dtype) -> (bm, bk, bn)` heuristic;
-        results are memoized in the process-wide autotune cache.
-      tile_candidates / tile_bench: optional measured-autotune hooks (see
-        `Backend` and docs/autotune.md); ignored unless the autotune policy
-        is "measure".
+      tile_picker: optional `(op, shapes, dtype) -> (bm, bk, bn)` rule;
+        its plans are memoized in the process-wide tile-plan cache.
       differentiable: iterable of op names `jax.grad` may flow through, or
         None meaning ALL registered ops (the right default for plain-jnp
         backends, which JAX differentiates natively).  Kernel backends
@@ -184,7 +164,6 @@ def register_backend(name: str, ops: Mapping[str, Callable], *,
                          f"{sorted(diff - set(ops))}; registered: "
                          f"{sorted(ops)}")
     be = Backend(name=name, ops=dict(ops), tile_picker=tile_picker,
-                 tile_candidates=tile_candidates, tile_bench=tile_bench,
                  differentiable=diff)
     _REGISTRY[name] = be
     return be
@@ -257,142 +236,42 @@ def guard_grad(backend: Backend, op: str, *operands):
                  for x in operands)
 
 
-# ------------------------------------------------------- autotune cache ---
-# Block-shape picks are memoized process-wide, keyed on
-# (op, shapes, dtype, backend).  Under the default "heuristic" policy a
-# miss runs the backend's VMEM-budget picker; under "measure" a miss first
-# consults the per-device persisted table (core/autotune.py), and only when
-# that also misses times the backend's candidate set and persists the
-# winner.  Stats and per-key records are observable so benchmarks/tests can
-# assert cache behaviour and report heuristic-vs-measured picks.
-
-AUTOTUNE_POLICIES = ("off", "heuristic", "measure")
+# ----------------------------------------------------- tile-plan cache ---
+# Block-shape plans are memoized process-wide, keyed on
+# (op, shapes, dtype, backend): a miss runs the backend's rule (for pallas,
+# the exact-plan and VMEM-budget pickers in kernels/ops.py) and stores the
+# result.  Stats and the plans themselves are observable so benchmarks and
+# tests can assert cache behaviour and which keys a trace resolved.
 
 _TILE_CACHE: dict[tuple, tuple] = {}
-_TILE_RECORDS: dict[tuple, dict] = {}
 _TILE_STATS = collections.Counter()
 
 
-def _policy_from_env(value: str | None) -> str:
-    """Default policy from `REPRO_AUTOTUNE`.  A typo'd value must not
-    silently degrade to heuristic behaviour (the shipped table would never
-    be consulted), so it warns loudly before falling back."""
-    if value is None or value in AUTOTUNE_POLICIES:
-        return value or "heuristic"
-    warnings.warn(f"ignoring invalid REPRO_AUTOTUNE={value!r}; "
-                  f"choose from {AUTOTUNE_POLICIES}", stacklevel=2)
-    return "heuristic"
-
-
-_POLICY = _policy_from_env(os.environ.get("REPRO_AUTOTUNE"))
-
-
 def set_autotune_policy(policy: str) -> str:
-    """Set the process-wide autotune policy; returns the previous one.
+    """Accept the one tile policy there is: "heuristic" (the rule-made,
+    memoized plan), which it returns.  Any other value raises ValueError.
 
-      off       : call the backend picker every time, no cache, no disk.
-      heuristic : memoized picker (the default).
-      measure   : memoized; first sight of a key loads the per-device
-                  persisted pick or times the candidate set and persists
-                  the winner.
-
-    Raises ValueError for a policy outside AUTOTUNE_POLICIES.
+    A vestige kept only because bench/benchlib/harness.py still calls it;
+    it goes once the harness stops doing so.
     """
-    global _POLICY
-    if policy not in AUTOTUNE_POLICIES:
-        raise ValueError(f"unknown autotune policy {policy!r}; "
-                         f"choose from {AUTOTUNE_POLICIES}")
-    prev, _POLICY = _POLICY, policy
-    return prev
-
-
-def get_autotune_policy() -> str:
-    """The active policy (env default: `REPRO_AUTOTUNE` or "heuristic")."""
-    return _POLICY
-
-
-@contextlib.contextmanager
-def autotune_policy(policy: str):
-    """Context manager scoping a policy change (used by
-    `Network.compile(..., autotune=...)` for the measured warmup pass)."""
-    prev = set_autotune_policy(policy)
-    try:
-        yield
-    finally:
-        set_autotune_policy(prev)
-
-
-def _measure_plan(key: tuple, picker, candidates, bench,
-                  interpret: bool | None) -> tuple | None:
-    """Measured resolution of a cache miss: persisted pick if the per-device
-    table has one, else time candidates and persist the winner.  Returns
-    None when the backend has nothing to measure for this op (e.g. the
-    attention path, whose tiling is not (bm, bk, bn)-shaped)."""
-    op, shapes, dtype_str, backend = key
-    ks = autotune.key_str(op, shapes, dtype_str, backend)
-    rec = autotune.lookup(ks)
-    if rec is not None and rec.get("pick"):
-        _TILE_STATS["persisted"] += 1
-        plan = tuple(rec["pick"])
-        _TILE_RECORDS[key] = dict(rec, source="persisted")
-        return plan
-    cands = [tuple(c) for c in candidates(op, shapes, dtype_str)]
-    base = tuple(picker(op, shapes, dtype_str))
-    if base and base not in cands:
-        cands.insert(0, base)
-    timed = []
-    for cand in cands:
-        thunk = bench(op, shapes, dtype_str, cand, interpret)
-        if thunk is None:
-            continue
-        timed.append((cand, autotune.time_thunk(thunk)))
-    if not timed:
-        return None
-    plan, est_ms = min(timed, key=lambda t: t[1])
-    _TILE_STATS["measured"] += 1
-    record = {"pick": list(plan), "est_ms": est_ms,
-              "candidates_timed": [[list(c), ms] for c, ms in timed],
-              "source": "measured"}
-    _TILE_RECORDS[key] = record
-    autotune.store(ks, record)
-    return plan
+    if policy != "heuristic":
+        raise ValueError(f"unknown autotune policy {policy!r}; tile plans "
+                         f"come from the backend's rule (\"heuristic\")")
+    return policy
 
 
 def tile_plan(op: str, shapes: tuple, dtype, backend: str,
-              picker: Callable[[str, tuple, Any], tuple], *,
-              candidates=None, bench=None,
-              interpret: bool | None = None) -> tuple:
-    """Block-shape pick keyed on (op, shapes, dtype, backend), resolved
-    under the active autotune policy (see `set_autotune_policy`)."""
+              picker: Callable[[str, tuple, Any], tuple]) -> tuple:
+    """Block-shape plan keyed on (op, shapes, dtype, backend): the cached
+    plan, or on a miss `picker`'s plan, stored for the next lookup."""
     dtype_str = str(jnp.dtype(dtype))
-    if _POLICY == "off":
-        return tuple(picker(op, shapes, dtype_str))
     key = (op, shapes, dtype_str, backend)
-    hit = _TILE_CACHE.get(key)
-    if hit is not None:
+    plan = _TILE_CACHE.get(key)
+    if plan is not None:
         _TILE_STATS["hits"] += 1
-        return hit
+        return plan
     _TILE_STATS["misses"] += 1
-    plan = None
-    if _POLICY == "measure" and candidates is not None and bench is not None:
-        plan = _measure_plan(key, picker, candidates, bench, interpret)
-    if plan is None:
-        plan = tuple(picker(op, shapes, dtype_str))
-        _TILE_RECORDS[key] = {"pick": list(plan), "est_ms": None,
-                              "candidates_timed": [], "source": "heuristic"}
-    # Plan-time legality gate: a measured winner or a persisted table entry
-    # (possibly written by another device/version) must satisfy the same
-    # alignment/VMEM/extent conditions the kernels assume.  Heuristic picks
-    # are legal by construction; warn loudly rather than raise so a stale
-    # table degrades (the pick still runs) instead of bricking dispatch —
-    # the lint rule R004 turns the same condition into a hard finding.
-    problems = validate_tiles(op, shapes, dtype_str, plan)
-    if problems:
-        src = _TILE_RECORDS.get(key, {}).get("source", "?")
-        warnings.warn(
-            f"autotune pick {plan} for {key} ({src}) fails kernel "
-            f"legality: {'; '.join(problems)}", stacklevel=2)
-    _TILE_CACHE[key] = plan
+    plan = _TILE_CACHE[key] = tuple(picker(op, shapes, dtype_str))
     return plan
 
 
@@ -413,8 +292,8 @@ def validate_tiles(op: str, shapes: tuple, dtype, tiles: tuple) -> list[str]:
     Returns a list of human-readable problems (empty = legal): MXU
     (8, 128) lane alignment, the kernels' VMEM working-set budget, and
     tiles no larger than the padded problem extents.  Malformed
-    shapes/plans (a corrupt persisted table) come back as a problem
-    string, never an exception.
+    shapes/plans (a bad pin) come back as a problem string, never an
+    exception.
     """
     if not tiles:
         return []
@@ -437,27 +316,21 @@ def validate_tiles(op: str, shapes: tuple, dtype, tiles: tuple) -> list[str]:
 
 
 def cache_stats() -> dict[str, int]:
-    """Counters for the block-pick cache: `hits`/`misses` are lookups,
-    `measured`/`persisted` split the misses resolved by timing vs by the
-    per-device disk table, `entries` is the resident cache size."""
+    """Counters for the tile-plan cache: `hits`/`misses` are lookups,
+    `entries` is the resident cache size."""
     return {"hits": _TILE_STATS["hits"], "misses": _TILE_STATS["misses"],
-            "measured": _TILE_STATS["measured"],
-            "persisted": _TILE_STATS["persisted"],
             "entries": len(_TILE_CACHE)}
 
 
-def autotune_report() -> dict[str, dict]:
-    """Per-key autotune records resolved by this process, keyed by the
-    canonical JSON key string: `{key: {pick, est_ms, candidates_timed,
-    source}}` with source one of heuristic|measured|persisted."""
-    return {autotune.key_str(*k): dict(rec)
-            for k, rec in _TILE_RECORDS.items()}
+def tile_plans() -> dict[tuple, tuple]:
+    """The plans this process resolved, ``{(op, shapes, dtype, backend):
+    plan}``."""
+    return dict(_TILE_CACHE)
 
 
 def clear_tile_cache() -> None:
-    """Reset the in-process cache, records and stats (not the disk table)."""
+    """Reset the in-process cache and its stats."""
     _TILE_CACHE.clear()
-    _TILE_RECORDS.clear()
     _TILE_STATS.clear()
 
 
@@ -641,52 +514,6 @@ def _pallas_tile_picker(op: str, shapes: tuple, dtype) -> tuple:
     return kernel_ops.default_blocks(op, *dims, dtype)
 
 
-def _pallas_tile_candidates(op: str, shapes: tuple, dtype) -> list[tuple]:
-    if op == "attention":
-        return kernel_ops.candidate_attention_blocks(
-            *kernel_ops.attention_dims(shapes), dtype)
-    if op == "attention_bwd":
-        return kernel_ops.candidate_attention_bwd_blocks(
-            *kernel_ops.attention_dims(shapes), dtype)
-    if op == "attention_decode":
-        return kernel_ops.candidate_attention_decode_blocks(
-            *kernel_ops.attention_dims(shapes), dtype)
-    if op == "gemm_bwd":
-        variant, rows, kdim, cols = shapes
-        return kernel_ops.candidate_gemm_bwd_blocks(variant, rows, kdim,
-                                                    cols, dtype)
-    dims = gemm_dims(op, shapes)
-    if dims is None:
-        return []
-    return kernel_ops.candidate_blocks(op, *dims, dtype)
-
-
-def _pallas_tile_bench(op: str, shapes: tuple, dtype, tiles: tuple,
-                       interpret: bool | None):
-    if op == "attention":
-        return kernel_ops.attention_bench_thunk(
-            *kernel_ops.attention_dims(shapes), dtype, tiles,
-            interpret=interpret)
-    if op == "attention_bwd":
-        return kernel_ops.attention_bwd_bench_thunk(
-            *kernel_ops.attention_dims(shapes), dtype, tiles,
-            interpret=interpret)
-    if op == "attention_decode":
-        return kernel_ops.attention_decode_bench_thunk(
-            *kernel_ops.attention_dims(shapes), dtype, tiles,
-            interpret=interpret)
-    if op == "gemm_bwd":
-        variant, rows, kdim, cols = shapes
-        return kernel_ops.gemm_bwd_bench_thunk(variant, rows, kdim, cols,
-                                               dtype, tiles,
-                                               interpret=interpret)
-    dims = gemm_dims(op, shapes)
-    if dims is None:
-        return None
-    return kernel_ops.bench_thunk(op, *dims, dtype, tiles,
-                                  interpret=interpret)
-
-
 # ---------------------------------------------------------- xla backend ---
 
 def _xla_matmul(x, w, scale, shift, *, act, out_dtype, ctx):
@@ -765,7 +592,7 @@ def _xla_attention(q, k, v, *, causal, sm_scale, kv_len=None, ctx):
 # live in kernels/flash_attention.py, the GEMM backward kernels (dX/dW,
 # shared by matmul, bmm and conv2d-as-im2col — im2col itself backpropagates
 # through a col2im scatter in kernels/common.py) in kernels/gemm.py, with
-# backward tiles resolved lazily under "gemm_bwd"/"attention_bwd" autotune
+# backward tiles resolved lazily under "gemm_bwd"/"attention_bwd" tile-plan
 # keys.  The full op set trains on the kernel path.
 register_backend("pallas", {
     "matmul": _pallas_matmul,
@@ -773,8 +600,6 @@ register_backend("pallas", {
     "conv2d": im2col_conv2d(_pallas_matmul),
     "attention": _pallas_attention,
 }, tile_picker=_pallas_tile_picker,
-    tile_candidates=_pallas_tile_candidates,
-    tile_bench=_pallas_tile_bench,
     differentiable=("matmul", "bmm", "conv2d", "attention"))
 
 register_backend("xla", {

@@ -14,7 +14,6 @@ every subsequent call a straight executable invocation.
 from __future__ import annotations
 
 import collections
-import contextlib
 import dataclasses
 import math
 import time
@@ -194,7 +193,6 @@ class Network:
     # -------------------------------------------------------------- compile
     def compile(self, params: dict, batch_size: int = 1, *,
                 dtype=jnp.float32, donate_params: bool = False,
-                autotune: str | None = None,
                 lint: str | None = None) -> "CompiledNetwork":
         """Lower the planned layer list into a single compiled artifact.
 
@@ -208,11 +206,6 @@ class Network:
           dtype: fixed input dtype (validated at call time, like shape).
           donate_params: donate param buffers to each call (see
             `CompiledNetwork`).
-          autotune: optional autotune policy ("off" | "heuristic" |
-            "measure") scoped to this lowering; "measure" is the opt-in
-            measured warmup pass — first-seen block-pick keys are timed
-            and persisted to the per-device table (docs/autotune.md).
-            None inherits the process policy.
           lint: optional trace-lint gate (docs/lint.md) over the captured
             jaxpr/HLO/dispatch log.  "warn" emits a UserWarning listing
             any findings; "error" additionally raises
@@ -220,15 +213,14 @@ class Network:
             None (the default) skips linting.
 
         Returns a `CompiledNetwork`.  Raises ValueError for an unknown
-        autotune policy or lint mode, and `LintError` under
+        lint mode, and `LintError` under
         ``lint="error"`` when an error-severity finding survives.
         """
         if lint not in (None, "warn", "error"):
             raise ValueError(f"unknown lint mode {lint!r}; choose "
                              f"'warn', 'error' or None")
         cn = CompiledNetwork(self, params, batch_size, dtype=dtype,
-                             donate_params=donate_params,
-                             autotune=autotune)
+                             donate_params=donate_params)
         if lint is not None:
             from repro.analysis.lint import LintError
             report = cn.lint()
@@ -241,8 +233,7 @@ class Network:
 
     def compile_cache(self, params: dict,
                       buckets: Iterable[int] = (1, 2, 4, 8), *,
-                      dtype=jnp.float32,
-                      autotune: str | None = None) -> "CompileCache":
+                      dtype=jnp.float32) -> "CompileCache":
         """Bucketed compilation cache for ragged serving traffic.
 
         Each bucket batch size lazily compiles its own `CompiledNetwork`
@@ -250,11 +241,8 @@ class Network:
         ragged batch up to the smallest bucket that fits and slices the
         real rows back out.  The serving frontend
         (`repro.serve.frontend.CNNServingEngine`) dispatches through this.
-        `autotune` is forwarded to every bucket compile (see
-        `Network.compile`).
         """
-        return CompileCache(self, params, buckets, dtype=dtype,
-                            autotune=autotune)
+        return CompileCache(self, params, buckets, dtype=dtype)
 
 
 class CompiledNetwork:
@@ -271,8 +259,7 @@ class CompiledNetwork:
     """
 
     def __init__(self, net: Network, params: dict, batch_size: int, *,
-                 dtype=jnp.float32, donate_params: bool = False,
-                 autotune: str | None = None):
+                 dtype=jnp.float32, donate_params: bool = False):
         self.net = net
         self.params = params
         self.batch_size = batch_size
@@ -287,24 +274,18 @@ class CompiledNetwork:
 
         donate = (0,) if donate_params else ()
         before = backends.dispatch_counts()
-        before_tuned = set(backends.autotune_report())
         log_mark = backends.dispatch_log_size()
-        policy = (backends.autotune_policy(autotune) if autotune
-                  else contextlib.nullcontext())
-        with policy:
-            # .trace() keeps the single-trace invariant while exposing the
-            # closed jaxpr the trace linter walks; .lower().compile() on
-            # the same Traced does not retrace.
-            traced = (jax.jit(fwd, donate_argnums=donate)
-                      .trace(params, self.in_spec))
-            self._compiled = traced.lower().compile()
+        # .trace() keeps the single-trace invariant while exposing the
+        # closed jaxpr the trace linter walks; .lower().compile() on the
+        # same Traced does not retrace.
+        traced = (jax.jit(fwd, donate_argnums=donate)
+                  .trace(params, self.in_spec))
+        self._compiled = traced.lower().compile()
         self.closed_jaxpr = traced.jaxpr
         # The single trace just happened; the counter diff IS the network's
         # static engine-op plan (e.g. {('xla','conv2d'): n_conv_layers}),
-        # the log slice its per-dispatch detail (shapes/dtype/tiles — the
-        # linter's R004 input), and the autotune-report diff the block-pick
-        # keys this lowering resolved first (heuristic, measured, or
-        # served from disk).
+        # and the log slice its per-dispatch detail (shapes/dtype/tiles —
+        # the linter's R004 input).
         self.op_counts = backends.counts_since(before)
         self.op_log = tuple(backends.dispatch_log()[log_mark:])
         self.gemm_padded = backends.gemm_padded(self.op_log)
@@ -312,8 +293,6 @@ class CompiledNetwork:
         outs = jax.tree.leaves(traced.out_info)
         self.outputs = {"arrays": len(outs), "bytes_per_item": sum(
             math.prod(o.shape[1:]) * o.dtype.itemsize for o in outs)}
-        self.autotune_keys = tuple(
-            k for k in backends.autotune_report() if k not in before_tuned)
 
     @property
     def trace_count(self) -> int:
@@ -367,23 +346,16 @@ class CompiledNetwork:
             self(jnp.zeros(self.in_spec.shape, self.in_spec.dtype)))
         return self
 
-    def autotune_report(self) -> dict[str, dict]:
-        """Block-pick records first resolved during this artifact's
-        lowering: `{key: {pick, est_ms, candidates_timed, source}}` with
-        source one of heuristic|measured|persisted (docs/autotune.md)."""
-        full = backends.autotune_report()
-        return {k: full[k] for k in self.autotune_keys if k in full}
-
     def profile(self, x=None, reps: int = 3) -> dict:
         """Timed execution: per-call wall time plus the static engine
-        op-dispatch counts and the autotune records captured at compile.
+        op-dispatch counts captured at compile.
 
         Args:
           x: input batch (defaults to zeros of the compiled spec).
           reps: timed repetitions after one untimed warm call.
 
         Returns `{per_call_s, reps, batch_size, trace_count, op_counts,
-        gemm_padded, im2col_phased, outputs, layers, autotune}`;
+        gemm_padded, im2col_phased, outputs, layers}`;
         ``gemm_padded`` says how many of the lowering's tiled GEMM
         dispatches pad an operand (`backends.gemm_padded`),
         ``im2col_phased`` how many of its convolutions read their patches
@@ -406,8 +378,7 @@ class CompiledNetwork:
                 "gemm_padded": self.gemm_padded,
                 "im2col_phased": self.im2col_phased,
                 "outputs": dict(self.outputs),
-                "layers": dict(self.net.layer_counts),
-                "autotune": self.autotune_report()}
+                "layers": dict(self.net.layer_counts)}
 
 
 class CompileCache:
@@ -438,7 +409,7 @@ class CompileCache:
 
     def __init__(self, net: Network, params: dict,
                  buckets: Iterable[int] = (1, 2, 4, 8), *,
-                 dtype=jnp.float32, autotune: str | None = None):
+                 dtype=jnp.float32):
         bs = tuple(sorted({int(b) for b in buckets}))
         if not bs or bs[0] < 1:
             raise ValueError(f"buckets must be positive ints, got {buckets}")
@@ -446,7 +417,6 @@ class CompileCache:
         self.params = params
         self.buckets = bs
         self.dtype = jnp.dtype(dtype)
-        self.autotune = autotune
         self._compiled: dict[int, CompiledNetwork] = {}
         self.hits = 0
         self.misses = 0
@@ -472,7 +442,7 @@ class CompileCache:
         if cn is None:
             self.misses += 1
             cn = self.net.compile(self.params, batch_size=bucket,
-                                  dtype=self.dtype, autotune=self.autotune)
+                                  dtype=self.dtype)
             self._compiled[bucket] = cn
         else:
             self.hits += 1
@@ -521,14 +491,6 @@ class CompileCache:
             self.get(b).warmup()
         return self
 
-    def autotune_report(self) -> dict[str, dict]:
-        """Union of the block-pick records resolved by the bucket
-        compiles (see `CompiledNetwork.autotune_report`)."""
-        out: dict[str, dict] = {}
-        for cn in self._compiled.values():
-            out.update(cn.autotune_report())
-        return out
-
     def _summed(self, counter: str) -> dict:
         """A `CompiledNetwork` dispatch-log counter (``gemm_padded``,
         ``im2col_phased``: `backends` functions of the same names) summed
@@ -542,8 +504,6 @@ class CompileCache:
 
     def stats(self) -> dict:
         total = self._rows_real + self._rows_pad
-        tuned = self.autotune_report()
-        sources = collections.Counter(r["source"] for r in tuned.values())
         return {
             "buckets": self.buckets,
             "compiled": tuple(sorted(self._compiled)),
@@ -559,5 +519,4 @@ class CompileCache:
             "outputs": next((dict(cn.outputs)
                              for cn in self._compiled.values()), None),
             "layers": dict(self.net.layer_counts),
-            "autotune": {"keys": len(tuned), "sources": dict(sources)},
         }

@@ -13,7 +13,7 @@ target is `register_backend(...)` — no engine changes.  Built-in backends:
            cannot lower (the 512-host-device dry-run on the CPU backend) and
            as the A/B reference for §Perf.
 
-Block shapes come from the per-process autotune cache (keyed on
+Block shapes come from the per-process tile-plan cache (keyed on
 (op, shapes, dtype, backend)) unless pinned via bm/bk/bn.
 
 The engine is a frozen dataclass → hashable → usable as a static jit arg and
@@ -34,7 +34,7 @@ from repro.core.precision import Precision
 class ComputeEngine:
     backend: str = "xla"
     precision: Precision = Precision("fp32_strict")
-    # 0 = auto-pick via the registry's autotune cache (VMEM-budget heuristic).
+    # 0 = auto-pick via the registry's tile-plan cache (the backend's rule).
     bm: int = 0
     bk: int = 0
     bn: int = 0
@@ -44,9 +44,9 @@ class ComputeEngine:
 
     # ---------------------------------------------------------- dispatch ---
     def _resolve(self, op: str, shapes: tuple, dtype) -> backends.OpContext:
-        """Look up the backend, consult the autotune cache (under the
-        active policy — a "measure" policy may time candidates here, on
-        first sight of the key), count the dispatch (trace-time: compiled
+        """Look up the backend, take the tile plan from the cache (the
+        backend's rule runs once per key, on first sight), count the
+        dispatch (trace-time: compiled
         programs pay this once; the detail record — shapes, dtype and the
         RESOLVED tiles, pinned picks included — feeds the trace linter's
         dispatch log)."""
@@ -57,7 +57,7 @@ class ComputeEngine:
             # resolves through the cache.
             tiles = (self.bm, self.bk, self.bn)
         else:
-            tiles = be.tiles(op, shapes, dtype, interpret=self.interpret)
+            tiles = be.tiles(op, shapes, dtype)
         backends.record_dispatch(self.backend, op, shapes=shapes,
                                  dtype=dtype, tiles=tiles)
         return backends.OpContext(precision=self.precision,

@@ -11,8 +11,8 @@ to the plain single-device pallas wrapper, so `make_engine
 
 No tile hooks are registered: block plans resolve lazily INSIDE the shard
 bodies from the per-shard operand shapes, under the standard "pallas"
-autotune keys — tile picks (and the persisted per-device table) stay
-device-local instead of keying on the global problem.
+tile-plan keys — tile picks stay device-local instead of keying on the
+global problem.
 
 All four ops are differentiable: the custom-VJP kernels flow through
 shard_map, and the backward kernels resolve their own "gemm_bwd" /

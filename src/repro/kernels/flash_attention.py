@@ -313,7 +313,7 @@ class _Config:
     q_offset: int
     q_len: int
     interpret: bool
-    # Engine-layout (q_shape, k_shape) for the "attention_bwd" autotune key,
+    # Engine-layout (q_shape, k_shape) for the "attention_bwd" tile-plan key,
     # or None (direct kernel calls: backward reuses the forward tiles).
     bwd_key: tuple | None = None
 
@@ -362,9 +362,9 @@ def _forward(cfg: _Config, q, k, v, kvl, *, return_lse: bool):
 
 
 def _resolve_bwd_tiles(cfg: _Config, q, sq: int, skv: int) -> tuple[int, int]:
-    """Backward (bq, bk) tiles: the explicit pins, else the measured
-    "attention_bwd" autotune key (ops-level calls thread `bwd_key`), else
-    the forward tiles.  Whatever the source, each tile is clamped to a
+    """Backward (bq, bk) tiles: the explicit pins, else the rule-made plan
+    of the lazy "attention_bwd" key (ops-level calls thread `bwd_key`),
+    else the forward tiles.  Whatever the source, each tile is clamped to a
     divisor of the forward-padded extent (gcd keeps the 8/128 alignment:
     both operands are multiples of it)."""
     bq2, bk2 = cfg.bq_bwd, cfg.bk_bwd
@@ -372,8 +372,7 @@ def _resolve_bwd_tiles(cfg: _Config, q, sq: int, skv: int) -> tuple[int, int]:
         if cfg.bwd_key is not None:
             from repro.core import backends
             bq2, bk2 = backends.get_backend("pallas").tiles(
-                "attention_bwd", cfg.bwd_key, q.dtype,
-                interpret=cfg.interpret)
+                "attention_bwd", cfg.bwd_key, q.dtype)
         else:
             bq2, bk2 = cfg.bq, cfg.bk
     if sq % bq2:
@@ -507,8 +506,8 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale=None,
     logsumexp; two backward kernels compute dQ (query-tile grid) and the
     compact grouped dK/dV (kv-tile grid, group reduction in-kernel —
     (B, KV, Skv, D) out, no H-broadcast).  ``bq_bwd``/``bk_bwd`` pin the
-    backward tiles; 0 resolves them from the measured "attention_bwd"
-    autotune key when ``bwd_key`` (the engine-layout (q_shape, k_shape))
+    backward tiles; 0 resolves them from the lazy "attention_bwd"
+    tile-plan key when ``bwd_key`` (the engine-layout (q_shape, k_shape))
     is threaded through, else reuses (bq, bk).  Backward tiles that do not
     divide (Sq, Skv) are clamped to gcd divisors, so any MXU-aligned pick
     is safe to pin.  Fully-masked rows produce exact-0 gradients.

@@ -36,8 +36,8 @@ operand dtype (bf16 operands keep fp32 lse accumulation).
 Layout matches ``flash_attention.flash_attention``: q (B, H, Sq, D),
 k/v (B, KV, Skv, D) grouped-KV native — query head h reads kv-head
 h // (H // KV) straight from its BlockSpec, no broadcast.  ``n_splits``
-and ``bk`` ride the autotuner as the ``attention_decode`` key space
-(docs/autotune.md).
+and ``bk`` come from the rule-made plan of the lazy ``attention_decode``
+tile-plan key (docs/engine_api.md, "Tile plans").
 
 This path is inference-only: decode is never differentiated, so there is
 no VJP here (the registry routes differentiated attention through the

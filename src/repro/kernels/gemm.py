@@ -32,12 +32,12 @@ tiled pallas kernels on the forward's operands:
   dW = Xᵀ (dY ∘ act'(u) ∘ scale)    rows K, contraction M, cols N
 
 each with its own (bm, bk, bn) plan resolved LAZILY at backward-trace time
-from the measured ``"gemm_bwd"`` autotune keys (variant-tagged: ("dx", m, n,
+from the rule-made ``"gemm_bwd"`` tile-plan keys (variant-tagged: ("dx", m, n,
 k) / ("dw", k, m, n) in the backward problem's own dims), its operands
 zero-padded up to that plan's multiples where it does not divide them —
 the pattern flash_attention.py established for ``attention_bwd``.
-dscale/dshift are column reductions of the residuals (no kernel needed).  Inference-only traces never resolve (or
-measure) a backward key.
+dscale/dshift are column reductions of the residuals (no kernel needed).
+Inference-only traces never resolve a backward key.
 """
 from __future__ import annotations
 
@@ -113,7 +113,7 @@ class _Config:
     has_scale: bool
     has_shift: bool
     interpret: bool
-    # Engine-layout unpadded (m, k, n) for the "gemm_bwd" autotune keys, or
+    # Engine-layout unpadded (m, k, n) for the "gemm_bwd" tile-plan keys, or
     # None (direct kernel calls: backward permutes the forward tiles).
     bwd_key: tuple | None = None
     bwd_dx: tuple = ()     # () = resolve at backward-trace time
@@ -335,7 +335,7 @@ def gemm_bwd_problem(variant: str, m: int, k: int, n: int
                      ) -> tuple[int, int, int]:
     """Map an engine-layout (m, k, n) GEMM to the backward variant's own
     (rows, contraction, cols) problem dims — what the ``"gemm_bwd"``
-    autotune key carries and the tile roles refer to."""
+    tile-plan key carries and the tile roles refer to."""
     if variant.endswith("dx"):
         return (m, n, k)
     if variant.endswith("dw"):
@@ -346,9 +346,9 @@ def gemm_bwd_problem(variant: str, m: int, k: int, n: int
 def _resolve_bwd_tiles(cfg: _Config, variant: str, dtype
                        ) -> tuple[int, int, int]:
     """Backward (bm, bk, bn) for one variant: the explicit pin, else the
-    measured ``("gemm_bwd", (variant, rows, contraction, cols), dtype)``
-    autotune key (ops-level calls thread `bwd_key`), else the forward tiles
-    permuted into the variant's roles.  The backward pads its operands to
+    rule-made plan of the lazy ``("gemm_bwd", (variant, rows, contraction,
+    cols), dtype)`` key (ops-level calls thread `bwd_key`), else the
+    forward tiles permuted into the variant's roles.  The backward pads its operands to
     the plan's multiples (`_pad_to`), so any plan is safe."""
     pin = cfg.bwd_dx if variant.endswith("dx") else cfg.bwd_dw
     if pin:
@@ -357,7 +357,7 @@ def _resolve_bwd_tiles(cfg: _Config, variant: str, dtype
         from repro.core import backends
         key_shapes = (variant,) + gemm_bwd_problem(variant, *cfg.bwd_key)
         return backends.get_backend("pallas").tiles(
-            "gemm_bwd", key_shapes, dtype, interpret=cfg.interpret)
+            "gemm_bwd", key_shapes, dtype)
     if variant.endswith("dx"):
         return cfg.bm, cfg.bn, cfg.bk
     return cfg.bk, cfg.bm, cfg.bn
@@ -426,7 +426,7 @@ def gemm(x, w, *, scale=None, shift=None, act: str = "linear",
     (and the raw accumulator when `scale` is given) as residuals; two
     backward pallas kernels compute dX/dW from the same operands.
     ``bwd_dx``/``bwd_dw`` pin the backward (bm, bk, bn) plans; () resolves
-    them at backward-trace time from the measured ``"gemm_bwd"`` autotune
+    them at backward-trace time from the lazy ``"gemm_bwd"`` tile-plan
     keys when ``bwd_key`` (the unpadded engine (m, k, n)) is threaded
     through, else permutes the forward tiles.  The backward pads its
     operands to whatever plan it runs, so any MXU-aligned pin is safe.

@@ -84,7 +84,7 @@ def pick_blocks(m: int, k: int, n: int, dtype,
     fits, the axes that cannot be tiled exactly fall back to the padded
     pick of `padded_blocks`, each on its own.
 
-    Callers go through the process-wide autotune cache in core/backends.py
+    Callers go through the process-wide tile-plan cache in core/backends.py
     (keyed on (op, shapes, dtype, backend)) rather than invoking this
     per call; `_cached_blocks` below routes the default path there too.
     """
@@ -142,44 +142,12 @@ def gemm_padding(m: int, k: int, n: int, tiles: tuple[int, int, int]
     return tuple(_round_up(d, t) for d, t in zip((m, k, n), tiles))
 
 
-def candidate_blocks(op: str, m: int, k: int, n: int, dtype
-                     ) -> list[tuple[int, int, int]]:
-    """Candidate set for measured autotuning: the heuristic pick plus its
-    axis-wise neighbors, filtered to legal plans (`validate_gemm_tiles`).
-
-    On an axis the pick tiles exactly, the neighbors are the next smaller
-    and next larger exact blocks (aligned divisors of the extent, or the
-    extent itself); on an axis it pads, the aligned half and double.
-    Small by design (<= 7 points): measurement happens once per (op,
-    shapes, dtype, backend) key per device, ever, so the sweep only needs
-    to cover the heuristic's failure directions, not the full design space.
-    """
-    base = default_blocks(op, m, k, n, dtype)
-    cands = [base]
-    for axis, (dim, align) in enumerate(zip((m, k, n), _ALIGN)):
-        tile = base[axis]
-        if dim % tile == 0:
-            ladder = sorted(set(
-                [b for b in range(align, dim + 1, align) if dim % b == 0]
-                + [dim]))
-            i = ladder.index(tile)
-            near = ladder[max(i - 1, 0):i + 2]
-        else:
-            near = [_round_up(tile // 2, align), tile * 2]
-        for v in near:
-            cand = base[:axis] + (v,) + base[axis + 1:]
-            if cand in cands or validate_gemm_tiles(m, k, n, dtype, cand):
-                continue
-            cands.append(cand)
-    return cands
-
-
 def validate_gemm_tiles(m: int, k: int, n: int, dtype,
                         tiles: tuple) -> list[str]:
     """Static legality of a (bm, bk, bn) plan for an (m, k, n) GEMM.
 
     The conditions the tiled kernels assume (the trace linter's R004 and
-    the autotune cache's plan-time gate both call this): three positive
+    pinned-tile engines check plans against this): three positive
     ints; each block aligned (bm a multiple of 8 sublanes, bk/bn multiples
     of the 128-lane width) or equal to its whole extent, the only other
     block shape Mosaic takes; the double-buffered `_working_set` under the
@@ -246,27 +214,6 @@ def validate_attention_tiles(sq: int, skv: int, d: int, dtype,
     return problems
 
 
-def bench_thunk(op: str, m: int, k: int, n: int, dtype,
-                tiles: tuple[int, int, int], *, interpret: bool | None = None):
-    """Zero-arg thunk running one compiled call of the op's GEMM problem
-    with pinned block shapes — the measurement unit for the autotuner
-    (core/autotune.py times it with warmup + median-of-k).
-
-    conv2d is measured as its im2col GEMM (the tiled work the pallas
-    backend actually runs); bmm uses a single-batch problem, since the
-    batch grid dimension scales all candidates equally.  Operands are
-    zeros: GEMM does identical work regardless of values.
-    """
-    bm, bk, bn = tiles
-    if op == "bmm":
-        x = jnp.zeros((1, m, k), dtype)
-        w = jnp.zeros((1, k, n), dtype)
-        return lambda: bmm(x, w, bm=bm, bk=bk, bn=bn, interpret=interpret)
-    x = jnp.zeros((m, k), dtype)
-    w = jnp.zeros((k, n), dtype)
-    return lambda: matmul(x, w, bm=bm, bk=bk, bn=bn, interpret=interpret)
-
-
 # ------------------------------------------------ GEMM backward tiles ---
 # The custom-VJP backward kernels (kernels/gemm.py) re-tile the two
 # backward GEMMs — dX = dY . W^T and dW = X^T . dY — as problems in their
@@ -274,7 +221,7 @@ def bench_thunk(op: str, m: int, k: int, n: int, dtype,
 # backend) where the dims are the BACKWARD problem's own (m, k, n) (so the
 # generic (bm, bk, bn) machinery applies verbatim).  Variants: "dx"/"dw"
 # for matmul-shaped calls, "bdx"/"bdw" for bmm.  Keys resolve lazily at
-# backward-trace time — inference never touches (or measures) them.
+# backward-trace time — inference never touches them.
 
 GEMM_BWD_VARIANTS = ("dx", "dw", "bdx", "bdw")
 
@@ -301,73 +248,11 @@ def default_gemm_bwd_blocks(variant: str, rows: int, kdim: int, cols: int,
                          _op_caps(_gemm_bwd_base_op(variant)))
 
 
-def candidate_gemm_bwd_blocks(variant: str, rows: int, kdim: int, cols: int,
-                              dtype) -> list[tuple[int, int, int]]:
-    """Candidate set for measured gemm_bwd autotuning: the heuristic pick
-    plus its axis-wise half/double neighbors, clamped to MXU-aligned sizes
-    (bm mult of 8, bk/bn mult of 128) and filtered to the VMEM
-    working-set budget."""
-    base = default_gemm_bwd_blocks(variant, rows, kdim, cols, dtype)
-    itemsize = jnp.dtype(dtype).itemsize
-    bm, bk, bn = base
-    cands = [base]
-    for vm, vk, vn in ((bm // 2, bk, bn), (bm * 2, bk, bn),
-                       (bm, bk // 2, bn), (bm, bk * 2, bn),
-                       (bm, bk, bn // 2), (bm, bk, bn * 2)):
-        cand = (max(8, min(_round_up(vm, 8), 512)),
-                max(128, min(_round_up(vk, 128), 2048)),
-                max(128, min(_round_up(vn, 128), 512)))
-        if cand in cands:
-            continue
-        if _working_set(*cand, itemsize) > _GEMM_VMEM_BUDGET:
-            continue
-        cands.append(cand)
-    return cands
-
-
-def gemm_bwd_bench_thunk(variant: str, rows: int, kdim: int, cols: int,
-                         dtype, tiles: tuple[int, int, int], *,
-                         interpret: bool | None = None):
-    """Measurement unit for a gemm_bwd candidate: one compiled call of the
-    RAW backward kernel with pinned tiles on the padded problem.  Timing
-    the kernel directly (not `jax.grad` of the forward) keeps the timed
-    trace out of the autotune cache — resolving the key being measured
-    from inside its own measurement would deadlock on the process lock.
-    Operand layouts per variant (backward dims rows/kdim/cols pad with
-    bm/bk/bn respectively):
-
-      dx : dY (M, N) . W^T  with (rows, kdim, cols) = (M, N, K)
-      dw : X^T . dY (M, N)  with (rows, kdim, cols) = (K, M, N)
-      bdx/bdw: the batched forms, benched single-batch like `bench_thunk`.
-    """
-    _gemm_bwd_base_op(variant)
-    bm, bk, bn = tiles
-    rp = _round_up(rows, bm)
-    kp = _round_up(kdim, bk)
-    cp = _round_up(cols, bn)
-    kw = dict(bm=bm, bk=bk, bn=bn, interpret=interpret)
-    if variant == "dx":
-        dy, w = jnp.zeros((rp, kp), dtype), jnp.zeros((cp, kp), dtype)
-        fn = jax.jit(lambda a, b: gemm_kernel.gemm_bwd_dx(a, b, **kw))
-        return lambda: fn(dy, w)
-    if variant == "dw":
-        x, dy = jnp.zeros((kp, rp), dtype), jnp.zeros((kp, cp), dtype)
-        fn = jax.jit(lambda a, b: gemm_kernel.gemm_bwd_dw(a, b, **kw))
-        return lambda: fn(x, dy)
-    if variant == "bdx":
-        dy, w = jnp.zeros((1, rp, kp), dtype), jnp.zeros((1, cp, kp), dtype)
-        fn = jax.jit(lambda a, b: gemm_kernel.bmm_bwd_dx(a, b, **kw))
-        return lambda: fn(dy, w)
-    x, dy = jnp.zeros((1, kp, rp), dtype), jnp.zeros((1, kp, cp), dtype)
-    fn = jax.jit(lambda a, b: gemm_kernel.bmm_bwd_dw(a, b, **kw))
-    return lambda: fn(x, dy)
-
-
 # ------------------------------------------------- attention (bq, bk) ---
 # The attention op tiles by SEQUENCE, not (bm, bk, bn): (bq, bk) are the
 # query/key tile lengths the flash kernel streams through VMEM.  The same
-# autotune machinery (key, candidate sweep, bench thunk, persisted table)
-# covers them — only the dims and the working-set formula differ.
+# tile-plan cache covers them — only the dims and the working-set formula
+# differ.
 
 def attention_dims(shapes: tuple) -> tuple[int, int, int, int, int, int]:
     """Normalize the attention cache-key shapes ``(q_shape, k_shape)`` —
@@ -406,30 +291,6 @@ def _default_seq_blocks(sq: int, skv: int, d: int, dtype, working_set,
     return bq, bk
 
 
-def _candidate_seq_blocks(sq: int, skv: int, d: int, dtype, working_set,
-                          base: tuple[int, int]) -> list[tuple[int, int]]:
-    """Shared candidate sweep around a (bq, bk) base pick: axis-wise
-    half/double neighbors, MXU-aligned, capped at the padded sequence
-    extents (a tile longer than the padded sequence only adds padding),
-    filtered to `working_set` under the VMEM budget.  Small by design,
-    like `candidate_blocks`: measurement happens once per key per device.
-    """
-    itemsize = jnp.dtype(dtype).itemsize
-    bq, bk = base
-    bq_cap = min(512, _round_up(sq, 8))
-    bk_cap = min(2048, _round_up(skv, 128))
-    cands = [base]
-    for vq, vk in ((bq // 2, bk), (bq * 2, bk), (bq, bk // 2), (bq, bk * 2)):
-        cand = (max(8, min(_round_up(vq, 8), bq_cap)),
-                max(128, min(_round_up(vk, 128), bk_cap)))
-        if cand in cands:
-            continue
-        if working_set(*cand, d, itemsize) > _VMEM_BUDGET:
-            continue
-        cands.append(cand)
-    return cands
-
-
 def default_attention_blocks(b: int, sq: int, skv: int, h: int, kv: int,
                              d: int, dtype) -> tuple[int, int]:
     """Heuristic forward (bq, bk) pick under the grouped-KV working set
@@ -438,39 +299,14 @@ def default_attention_blocks(b: int, sq: int, skv: int, h: int, kv: int,
                                256, 512)
 
 
-def candidate_attention_blocks(b: int, sq: int, skv: int, h: int, kv: int,
-                               d: int, dtype) -> list[tuple[int, int]]:
-    """Forward candidate (bq, bk) set for measured attention autotuning
-    (`_candidate_seq_blocks` around the heuristic pick)."""
-    return _candidate_seq_blocks(
-        sq, skv, d, dtype, _attention_working_set,
-        default_attention_blocks(b, sq, skv, h, kv, d, dtype))
-
-
-def attention_bench_thunk(b: int, sq: int, skv: int, h: int, kv: int,
-                          d: int, dtype, tiles: tuple[int, int], *,
-                          interpret: bool | None = None):
-    """Zero-arg thunk running one compiled grouped-attention call with
-    pinned (bq, bk) — the attention measurement unit for the autotuner.
-    Benched causal (the prefill hot path); operands are zeros, which is
-    fair here because masking and the softmax do identical work per tile
-    regardless of values."""
-    bq, bk = tiles
-    q = jnp.zeros((b, sq, h, d), dtype)
-    k = jnp.zeros((b, skv, kv, d), dtype)
-    v = jnp.zeros((b, skv, kv, d), dtype)
-    return lambda: attention(q, k, v, causal=True, bq=bq, bk=bk,
-                             interpret=interpret)
-
-
 # -------------------------------------------- attention backward tiles ---
 # The custom-VJP backward kernels (flash_attention.py) re-tile the same
 # padded problem with their own (bq, bk): the backward working set is
 # larger (q, dO, k, v, dK, dV tiles plus THREE fp32 score-sized tiles are
 # live per grid step), so the forward winner is usually too big.  Backward
-# tiles get their own measured key — ("attention_bwd", (q_shape, k_shape),
-# dtype, backend) — resolved lazily at backward-trace time, so inference
-# never touches (or measures) them.
+# tiles get their own key — ("attention_bwd", (q_shape, k_shape), dtype,
+# backend) — resolved lazily at backward-trace time, so inference never
+# touches them.
 
 def _attention_bwd_working_set(bq: int, bk: int, d: int,
                                itemsize: int) -> int:
@@ -496,37 +332,6 @@ def default_attention_bwd_blocks(b: int, sq: int, skv: int, h: int, kv: int,
                                _attention_bwd_working_set, 128, 256)
 
 
-def candidate_attention_bwd_blocks(b: int, sq: int, skv: int, h: int,
-                                   kv: int, d: int, dtype
-                                   ) -> list[tuple[int, int]]:
-    """Backward candidate set: the shared sweep around the backward
-    heuristic pick, filtered to the LARGER backward VMEM working set."""
-    return _candidate_seq_blocks(
-        sq, skv, d, dtype, _attention_bwd_working_set,
-        default_attention_bwd_blocks(b, sq, skv, h, kv, d, dtype))
-
-
-def attention_bwd_bench_thunk(b: int, sq: int, skv: int, h: int, kv: int,
-                              d: int, dtype, tiles: tuple[int, int], *,
-                              interpret: bool | None = None):
-    """Measurement unit for a backward candidate: one compiled
-    `jax.grad` of the causal grouped wrapper with the backward tiles
-    PINNED (so the timed trace never re-enters the autotune cache) and
-    the forward tiles left to the cache (identical across candidates).
-    Zero operands are fair for the same reason as the forward bench."""
-    bq2, bk2 = tiles
-    q = jnp.zeros((b, sq, h, d), dtype)
-    k = jnp.zeros((b, skv, kv, d), dtype)
-    v = jnp.zeros((b, skv, kv, d), dtype)
-
-    def loss(q, k, v):
-        return attention(q, k, v, causal=True, bq_bwd=bq2, bk_bwd=bk2,
-                         interpret=interpret).astype(jnp.float32).sum()
-
-    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-    return lambda: grad(q, k, v)
-
-
 # ----------------------------------------- attention decode formulation ---
 # Short-query/long-KV problems (a decode step against a deep cache) leave
 # the forward kernel's (B*H, Sq/bq, Skv/bk) grid with B*H programs — most
@@ -534,10 +339,10 @@ def attention_bwd_bench_thunk(b: int, sq: int, skv: int, h: int, kv: int,
 # decode kernel (kernels/flash_decode.py) instead grids over
 # (B*H, n_splits) independent KV spans, each emitting a partial (o, lse)
 # combined by the logsumexp merge.  Its (bk_split, n_splits) tiles ride
-# the same autotune machinery under their own lazy key —
+# the same tile-plan cache under their own lazy key —
 # ("attention_decode", (q_shape, k_shape), dtype, backend) — resolved
 # only when a dispatch actually selects the decode formulation, so
-# prefill/training never touches (or measures) decode keys.
+# prefill/training never touches decode keys.
 
 # The decode formulation engages when the query is no longer than a
 # single sublane tile AND the key extent is deep enough that splitting
@@ -578,30 +383,6 @@ def default_attention_decode_blocks(b: int, sq: int, skv: int, h: int,
     return bk, n_splits
 
 
-def candidate_attention_decode_blocks(b: int, sq: int, skv: int, h: int,
-                                      kv: int, d: int, dtype
-                                      ) -> list[tuple[int, int]]:
-    """Candidate (bk_split, n_splits) set: the heuristic pick plus its
-    axis-wise half/double neighbors — bk 128-aligned and capped at the
-    padded key extent, n_splits capped so no span is empty.  Small by
-    design, like every candidate family here."""
-    itemsize = jnp.dtype(dtype).itemsize
-    bk, ns = default_attention_decode_blocks(b, sq, skv, h, kv, d, dtype)
-    bk_cap = min(2048, _round_up(skv, 128))
-    cands = [(bk, ns)]
-    for vk, vs in ((bk // 2, ns), (bk * 2, ns), (bk, max(1, ns // 2)),
-                   (bk, ns * 2)):
-        vk = max(128, min(_round_up(vk, 128), bk_cap))
-        vs = max(1, min(vs, max(1, -(-skv // vk))))
-        cand = (vk, vs)
-        if cand in cands:
-            continue
-        if _attention_decode_working_set(vk, d, itemsize) > _VMEM_BUDGET:
-            continue
-        cands.append(cand)
-    return cands
-
-
 def validate_attention_decode_tiles(sq: int, skv: int, d: int, dtype,
                                     tiles: tuple) -> list[str]:
     """Static legality of a (bk_split, n_splits) decode plan: two positive
@@ -632,21 +413,6 @@ def validate_attention_decode_tiles(sq: int, skv: int, d: int, dtype,
     return problems
 
 
-def attention_decode_bench_thunk(b: int, sq: int, skv: int, h: int, kv: int,
-                                 d: int, dtype, tiles: tuple[int, int], *,
-                                 interpret: bool | None = None):
-    """Measurement unit for a decode candidate: one compiled split-KV
-    call with pinned (bk_split, n_splits) against a full-extent cache
-    (kv_len = Skv, the worst-case live decode).  Zero operands are fair
-    for the same reason as the forward bench."""
-    bk, ns = tiles
-    q = jnp.zeros((b, sq, h, d), dtype)
-    k = jnp.zeros((b, skv, kv, d), dtype)
-    v = jnp.zeros((b, skv, kv, d), dtype)
-    return lambda: attention_decode(q, k, v, skv, causal=True, bk_split=bk,
-                                    n_splits=ns, interpret=interpret)
-
-
 @functools.partial(
     jax.jit, static_argnames=("causal", "bk_split", "n_splits", "interpret"))
 def attention_decode(q, k, v, kv_len=None, sm_scale=None, *,
@@ -673,7 +439,7 @@ def attention_decode(q, k, v, kv_len=None, sm_scale=None, *,
     _, skv, kvh, _ = k.shape
     if not (bk_split and n_splits):
         bk_split, n_splits = _cached_attention_decode_blocks(
-            (q.shape, k.shape), q.dtype, interpret)
+            (q.shape, k.shape), q.dtype)
     sqp = _round_up(sq, 8)
     skvp = _round_up(skv, bk_split * n_splits)
     kvl = normalize_kv_len(kv_len, b, skv)
@@ -696,15 +462,14 @@ def attention_decode(q, k, v, kv_len=None, sm_scale=None, *,
     return o[:, :, :sq].transpose(0, 2, 1, 3).astype(q.dtype)
 
 
-def _cached_attention_decode_blocks(shapes: tuple, dtype,
-                                    interpret: bool | None
+def _cached_attention_decode_blocks(shapes: tuple, dtype
                                     ) -> tuple[int, int]:
     """Default (bk_split, n_splits) pick, resolved through the registry's
-    autotune cache under the lazy ("attention_decode",
+    tile-plan cache under the lazy ("attention_decode",
     (q_shape, k_shape), dtype, "pallas") key."""
     from repro.core import backends
     return backends.get_backend("pallas").tiles("attention_decode", shapes,
-                                                dtype, interpret=interpret)
+                                                dtype)
 
 
 def validate_attention_shapes(q, k, v) -> None:
@@ -779,15 +544,14 @@ def attention(q, k, v, kv_len=None, sm_scale=None, *, causal: bool = True,
     padded-row cotangents; the pad VJP slices padded-key gradients off, and
     the synthesized ``kv_len`` masks padded keys inside the backward
     kernels too).  ``bq_bwd``/``bk_bwd`` pin the backward tiles; 0 resolves
-    them at backward-trace time from the measured ``"attention_bwd"``
-    autotune key — forward-only callers never touch that key.
+    them at backward-trace time from the lazy ``"attention_bwd"``
+    tile-plan key — forward-only callers never touch that key.
     """
     validate_attention_shapes(q, k, v)
     b, sq, h, d = q.shape
     _, skv, kvh, _ = k.shape
     if not (bq and bk):
-        bq, bk = _cached_attention_blocks((q.shape, k.shape), q.dtype,
-                                          interpret)
+        bq, bk = _cached_attention_blocks((q.shape, k.shape), q.dtype)
     sqp, skvp = _round_up(sq, bq), _round_up(skv, bk)
     kvl = normalize_kv_len(kv_len, b, skv)
     if kvl is None and skvp != skv:
@@ -836,7 +600,7 @@ def attention_partial(q, k, v, kv_len, sm_scale=None, *, causal: bool = True,
     validate_attention_shapes(q, k, v)
     b, sq, h, d = q.shape
     _, skv, kvh, _ = k.shape
-    bq, bk = _cached_attention_blocks((q.shape, k.shape), q.dtype, interpret)
+    bq, bk = _cached_attention_blocks((q.shape, k.shape), q.dtype)
     if sq % bq:
         bq = math.gcd(sq, bq)
     if skv % bk:
@@ -864,28 +628,25 @@ def attention_partial(q, k, v, kv_len, sm_scale=None, *, causal: bool = True,
     return o, lse
 
 
-def _cached_attention_blocks(shapes: tuple, dtype, interpret: bool | None
-                             ) -> tuple[int, int]:
+def _cached_attention_blocks(shapes: tuple, dtype) -> tuple[int, int]:
     """Default (bq, bk) pick for direct `attention` calls, resolved through
-    the registry's autotune cache under the same ("attention",
+    the registry's tile-plan cache under the same ("attention",
     (q_shape, k_shape), dtype, "pallas") key engine dispatch uses."""
     from repro.core import backends
-    return backends.get_backend("pallas").tiles("attention", shapes, dtype,
-                                                interpret=interpret)
+    return backends.get_backend("pallas").tiles("attention", shapes, dtype)
 
 
-def _cached_blocks(op: str, m: int, k: int, n: int, dtype,
-                   interpret: bool | None) -> tuple[int, int, int]:
-    """Default block pick, resolved through the registry's autotune cache
-    (same hooks and cache key as engine dispatch, so both paths agree and
-    the "measure" policy covers direct kernel calls too).
+def _cached_blocks(op: str, m: int, k: int, n: int, dtype
+                   ) -> tuple[int, int, int]:
+    """Default block pick, resolved through the registry's tile-plan
+    cache (same picker and cache key as engine dispatch, so both paths
+    agree).
 
     Imported lazily: core/backends.py imports this module at load time, and
     by the time a kernel wrapper actually executes the registry is loaded.
     """
     from repro.core import backends
-    return backends.get_backend("pallas").tiles(op, (m, k, n), dtype,
-                                                interpret=interpret)
+    return backends.get_backend("pallas").tiles(op, (m, k, n), dtype)
 
 
 @functools.partial(
@@ -898,7 +659,7 @@ def matmul(x, w, scale=None, shift=None, *, act: str = "linear",
     """Fused GEMM on the compute engine, arbitrary (M, K) x (K, N).
 
     DIFFERENTIABLE end-to-end: the kernel carries a custom VJP (backward
-    GEMM kernels under lazily-resolved ``"gemm_bwd"`` autotune keys — the
+    GEMM kernels under lazily-resolved ``"gemm_bwd"`` tile-plan keys — the
     unpadded (m, k, n) threads through as the key), and this wrapper's
     pad/slice (only where the plan does not tile an extent exactly) are
     gradient-transparent.  ``bwd_dx``/``bwd_dw`` pin the
@@ -908,7 +669,7 @@ def matmul(x, w, scale=None, shift=None, *, act: str = "linear",
     _, n = w.shape
     out_dtype = out_dtype or x.dtype
     if not (bm and bk and bn):
-        bm, bk, bn = _cached_blocks("matmul", m, k, n, x.dtype, interpret)
+        bm, bk, bn = _cached_blocks("matmul", m, k, n, x.dtype)
     mp, kp, np_ = gemm_padding(m, k, n, (bm, bk, bn))
     if (mp, kp) != (m, k):
         x = jnp.pad(x, ((0, mp - m), (0, kp - k)))
@@ -939,7 +700,7 @@ def bmm(x, w, *, out_dtype=None, bm: int = 0, bk: int = 0, bn: int = 0,
     _, _, n = w.shape
     out_dtype = out_dtype or x.dtype
     if not (bm and bk and bn):
-        bm, bk, bn = _cached_blocks("bmm", m, k, n, x.dtype, interpret)
+        bm, bk, bn = _cached_blocks("bmm", m, k, n, x.dtype)
     mp, kp, np_ = gemm_padding(m, k, n, (bm, bk, bn))
     if (mp, kp) != (m, k):
         x = jnp.pad(x, ((0, 0), (0, mp - m), (0, kp - k)))

@@ -27,7 +27,7 @@ kernel-backed path at every scale):
                programs.
 
 Inside the shard bodies the kernel wrappers resolve their block plans from
-the PER-SHARD shapes under the usual "pallas" autotune keys, so tile picks
+the PER-SHARD shapes under the usual "pallas" tile-plan keys, so tile picks
 stay device-local (a (1, 4096)-row shard never inherits the global
 problem's tiles).
 """
@@ -140,7 +140,7 @@ def _local_attention(q, k, v, kv_len, sm_scale, *, causal, interpret):
     decode-shaped per-shard problems take the split-KV kernel, everything
     else the custom-VJP forward kernel.  Shard bodies run this on
     per-shard operands, so block plans resolve from LOCAL shapes under the
-    same "pallas" autotune keys engine dispatch uses."""
+    same "pallas" tile-plan keys engine dispatch uses."""
     if kernel_ops.use_decode_formulation(q.shape[1], k.shape[1]):
         return kernel_ops.attention_decode(q, k, v, kv_len, sm_scale,
                                            causal=causal,

@@ -354,7 +354,7 @@ def mla_decode(engine: ComputeEngine, p, x, cache, pos, cos, sin, cfg):
     # head shares ONE kv "head" — the cache row concat(c_kv, k_rope)
     # (lora + rope_d wide) — and the value is c_kv itself.  Route it
     # through the registry `attention` op so the decode formulation
-    # (split-KV kernel), autotune, and mesh distribution all apply.  The
+    # (split-KV kernel), tile plans, and mesh distribution all apply.  The
     # op requires matching K/V widths; zero-padding V's trailing rope_d
     # columns is exact (softmax weights times zero columns) and the pad
     # is sliced off below.

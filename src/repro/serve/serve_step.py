@@ -60,7 +60,7 @@ def make_decode_step(engine: ComputeEngine, cfg):
     scale; on the pallas backend a decode-shaped dispatch (Sq <= 8
     against a cache buffer >= 256 rows) selects the split-KV
     flash-decoding formulation (kernels/flash_decode.py) — same
-    contract, tiles under the lazy "attention_decode" autotune key.
+    contract, tiles under the lazy "attention_decode" tile-plan key.
     Under a mesh the sharded_pallas backend shards the slot batch (and
     KV-head groups) via shard_map around those same kernels."""
     def decode_step(params, caches, token, pos):

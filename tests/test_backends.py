@@ -6,7 +6,7 @@ Covers the API-redesign acceptance criteria:
   * parametrized backend parity on matmul+epilogue, bmm, attention, and a
     2-conv darknet net through `CompiledNetwork`;
   * `Network.compile` produces exactly ONE jit trace;
-  * the autotune block-pick cache is hit on the second identical-shape call.
+  * the tile-plan cache is hit on the second identical-shape call.
 """
 import jax
 import jax.numpy as jnp
@@ -210,7 +210,7 @@ def test_darknet_reference_net_compiles_once():
     assert cn.op_counts[("xla", "conv2d")] == n_convs
 
 
-# ---------------------------------------------------------- autotune cache
+# --------------------------------------------------------- tile-plan cache
 
 def test_autotune_cache_hit_on_second_identical_shape():
     backends.clear_tile_cache()
@@ -241,13 +241,12 @@ def test_autotune_cache_keyed_per_op():
 
 def test_untiled_backends_skip_autotune_cache():
     """Backends without a tile_picker (xla, ref) don't pollute the
-    block-pick cache — its stats measure real autotune reuse only."""
+    tile-plan cache — its stats measure real plan reuse only."""
     backends.clear_tile_cache()
     x, w = _rand(0, (64, 48)), _rand(1, (48, 32))
     make_engine("xla").matmul(x, w)
     make_engine("ref").matmul(x, w)
-    assert backends.cache_stats() == {"hits": 0, "misses": 0, "measured": 0,
-                                      "persisted": 0, "entries": 0}
+    assert backends.cache_stats() == {"hits": 0, "misses": 0, "entries": 0}
 
 
 def test_causal_attention_rejects_more_queries_than_keys():

@@ -13,10 +13,10 @@ the logsumexp combine.  This suite pins:
     to the forward kernel; bf16 operands keep fp32 partials and lse;
   * registry selection: a decode-shaped `engine.attention` dispatch on
     the pallas backend resolves (bk_split, n_splits) tiles under the lazy
-    "attention_decode" autotune key, while prefill shapes keep the
+    "attention_decode" tile-plan key, while prefill shapes keep the
     forward (bq, bk) plan — and both agree with the xla formulation;
-  * the (bk_split, n_splits) tile family: heuristic legality, candidate
-    legality, and validator rejections (mis-alignment, dead splits).
+  * the (bk_split, n_splits) tile family: the rule's legality and
+    validator rejections (mis-alignment, dead splits).
 """
 import jax
 import jax.numpy as jnp
@@ -171,15 +171,15 @@ def test_registry_selects_decode_formulation_lazily():
     got = make_engine("pallas").attention(q, k, v, causal=True, kv_len=kvl)
     want = make_engine("xla").attention(q, k, v, causal=True, kv_len=kvl)
     _assert_close(got, want, jnp.float32)
-    keys = [k2 for k2 in backends.autotune_report()
-            if '"attention_decode"' in k2]
+    keys = [k2 for k2 in backends.tile_plans()
+            if k2[0] == "attention_decode"]
     assert len(keys) == 1, keys
 
     backends.clear_tile_cache()
     qp, kp, vp = _mk(21, 1, 512, 512, 8, 2, 32)
     make_engine("pallas").attention(qp, kp, vp, causal=True)
-    assert not [k2 for k2 in backends.autotune_report()
-                if '"attention_decode"' in k2]
+    assert not [k2 for k2 in backends.tile_plans()
+                if k2[0] == "attention_decode"]
 
 
 # ----------------------------------------------------- tile machinery ---
@@ -189,11 +189,7 @@ def test_decode_tile_heuristic_and_candidates_are_legal():
         dims = ops.attention_dims(((2, 1, 8, 64), (2, skv, 1, 64)))
         pick = ops.default_attention_decode_blocks(*dims, jnp.float32)
         assert ops.validate_attention_decode_tiles(
-            1, skv, 64, jnp.float32, pick) == []
-        for cand in ops.candidate_attention_decode_blocks(
-                *dims, jnp.float32):
-            assert ops.validate_attention_decode_tiles(
-                1, skv, 64, jnp.float32, cand) == [], (skv, cand)
+            1, skv, 64, jnp.float32, pick) == [], (skv, pick)
 
 
 def test_decode_tile_validator_rejects_illegal_plans():

@@ -15,7 +15,7 @@ this suite proves it numerically and structurally:
     when installed, seeded deterministic fallback always);
   * trace-level regressions: the backward jaxpr of a full pallas train
     step (CNN and LM) carries a `repro.op.*` scope on every dense
-    contraction (the R002 condition), and `gemm_bwd` autotune keys are
+    contraction (the R002 condition), and `gemm_bwd` tile-plan keys are
     created lazily — an inference-only trace registers none.
 """
 import dataclasses
@@ -343,7 +343,7 @@ def test_lm_train_backward_trace_r002_clean():
 
 def test_gemm_bwd_keys_created_lazily():
     """Backward tiles resolve at backward-trace time only: an
-    inference-only trace registers ZERO gemm_bwd autotune keys; the first
+    inference-only trace registers ZERO gemm_bwd tile-plan keys; the first
     differentiated trace of the same problem adds exactly dx + dw."""
     backends.clear_tile_cache()
     jax.clear_caches()
@@ -352,11 +352,9 @@ def test_gemm_bwd_keys_created_lazily():
         x = jnp.ones((24, 40), jnp.float32)
         w = jnp.ones((40, 16), jnp.float32)
         eng.matmul(x, w, act="leaky")
-        assert not [k for k in backends.autotune_report()
-                    if k.startswith('["gemm_bwd"')]
+        assert not [k for k in backends.tile_plans() if k[0] == "gemm_bwd"]
         jax.grad(lambda x: (eng.matmul(x, w, act="leaky") ** 2).sum())(x)
-        bwd = [k for k in backends.autotune_report()
-               if k.startswith('["gemm_bwd"')]
+        bwd = [k for k in backends.tile_plans() if k[0] == "gemm_bwd"]
         assert len(bwd) == 2, bwd
     finally:
         backends.clear_tile_cache()

@@ -6,7 +6,6 @@ gradient parity, seq-split correctness, R002-clean sharded traces and
 mesh-threaded serving.
 """
 import dataclasses
-import json
 
 import jax
 import jax.numpy as jnp
@@ -212,7 +211,6 @@ def test_per_shard_autotune_keys(mesh8):
         jax.block_until_ready(
             jax.jit(lambda *a: sharded.attention(*a, None, None,
                                                  causal=True))(q, k, v))
-    att = [json.loads(key) for key in backends.autotune_report()
-           if json.loads(key)[0] == "attention"]
+    att = [key for key in backends.tile_plans() if key[0] == "attention"]
     assert att, "no attention tile key resolved"
     assert {a[1][0][0] for a in att} == {1}, att   # per-shard batch only
