@@ -75,8 +75,8 @@ def run() -> list[tuple[str, float, str]]:
 
     # fused vs unfused epilogue on the SAME conv algorithm (im2col+GEMM),
     # isolating the paper's stream-fusion claim; the native-XLA conv row is
-    # the backend reference (on TPU, kernels/conv_direct.py replaces the
-    # materialized im2col entirely).
+    # the backend reference (on TPU, the pallas backend forms a stride-1
+    # 3x3 conv's patches inside the GEMM instead: ops.conv2d_implicit).
     from repro.core.darknet import layers as L
     eng = make_engine("xla", "fp32_strict")
     x = jnp.asarray(np.random.default_rng(1).standard_normal(
@@ -115,7 +115,7 @@ def run() -> list[tuple[str, float, str]]:
     rows.append(("cnn/conv_bn_act_unfused_im2col_gemm", tu * 1e6,
                  f"fused_speedup={tu / tf:.2f}x"))
     rows.append(("cnn/conv_bn_act_xla_native_ref", tn * 1e6,
-                 "backend reference (TPU target uses conv_direct kernel)"))
+                 "backend reference (pallas: implicit-patch GEMM)"))
     rows.extend(_serving_sweep())
     return rows
 

@@ -76,8 +76,9 @@ class OpContext:
     # None: kernels derive interpret mode from the platform
     # (kernels.common.default_interpret).
     interpret: bool | None = None
-    # (bm, bk, bn) for GEMM-shaped ops on tiled backends, (bq, bk)
-    # sequence tiles for attention, () otherwise.
+    # (bm, bk, bn) for GEMM-shaped ops on tiled backends, (bm, bn) for a
+    # conv2d that forms its patches in VMEM, (bq, bk) sequence tiles for
+    # attention, () otherwise.
     tiles: tuple = ()
 
 
@@ -285,9 +286,9 @@ def validate_tiles(op: str, shapes: tuple, dtype, tiles: tuple) -> list[str]:
         `kernel_ops.attention_dims` for the accepted forms).
       dtype: operand dtype (anything `jnp.dtype` accepts).
       tiles: the resolved plan — (bm, bk, bn) for GEMM-shaped ops,
-        (bq, bk) for attention, (bk_split, n_splits) for the decode
-        formulation.  An empty plan is vacuously legal (untiled
-        backend).
+        (bm, bn) for an implicit-patch conv2d, (bq, bk) for attention,
+        (bk_split, n_splits) for the decode formulation.  An empty plan
+        is vacuously legal (untiled backend).
 
     Returns a list of human-readable problems (empty = legal): MXU
     (8, 128) lane alignment, the kernels' VMEM working-set budget, and
@@ -307,6 +308,9 @@ def validate_tiles(op: str, shapes: tuple, dtype, tiles: tuple) -> list[str]:
             return kernel_ops.validate_attention_tiles(
                 sq, skv, d, dtype, tuple(tiles),
                 bwd=(op == "attention_bwd"))
+        if op == "conv2d" and len(tiles) == 2:
+            return kernel_ops.validate_conv_implicit_tiles(
+                *shapes, dtype, tuple(tiles))
         dims = gemm_dims(op, shapes)
         if dims is None:
             return []
@@ -421,8 +425,10 @@ def reset_dispatch_counts() -> None:
 
 def im2col_conv2d(matmul_impl: Callable) -> Callable:
     """Build a conv2d op from a matmul op via materialized im2col — the
-    paper's canonical conv lowering.  Backend authors with a direct conv
-    kernel can register their own conv2d instead (see kernels/conv_direct)."""
+    paper's canonical conv lowering.  The pallas backend's conv2d forms
+    the patches inside the GEMM where its tile rule gives a (bm, bn)
+    implicit-patch plan, and runs this under a (bm, bk, bn) plan
+    otherwise."""
 
     def conv2d(x, w, scale, shift, *, size, stride, pad, act, out_dtype,
                ctx):
@@ -444,6 +450,15 @@ def im2col_phased(records) -> dict:
     return {"convs": len(strides), "phased": sum(s > 1 for s in strides)}
 
 
+def conv_implicit(records) -> dict:
+    """How many of a dispatch-log slice's conv2d dispatches form their
+    patches inside the GEMM (those that ran under an implicit-patch
+    (bm, bn) plan): ``{convs, implicit}``."""
+    convs = [r for r in records if r["op"] == "conv2d"]
+    return {"convs": len(convs),
+            "implicit": sum(len(r["tiles"]) == 2 for r in convs)}
+
+
 # ------------------------------------------------------- pallas backend ---
 
 def _pallas_matmul(x, w, scale, shift, *, act, out_dtype, ctx):
@@ -457,6 +472,23 @@ def _pallas_bmm(x, w, *, out_dtype, ctx):
     bm, bk, bn = ctx.tiles or (0, 0, 0)
     return kernel_ops.bmm(x, w, out_dtype=out_dtype, bm=bm, bk=bk, bn=bn,
                           interpret=ctx.interpret)
+
+
+_pallas_im2col_conv2d = im2col_conv2d(_pallas_matmul)
+
+
+def _pallas_conv2d(x, w, scale, shift, *, size, stride, pad, act, out_dtype,
+                   ctx):
+    # A (bm, bn) plan forms the patches in VMEM; a (bm, bk, bn) plan runs
+    # im2col + the GEMM.
+    if len(ctx.tiles) == 2:
+        bm, bn = ctx.tiles
+        return kernel_ops.conv2d_implicit(
+            x, w, scale, shift, size=size, pad=pad, act=act,
+            out_dtype=out_dtype, bm=bm, bn=bn, interpret=ctx.interpret)
+    return _pallas_im2col_conv2d(x, w, scale, shift, size=size,
+                                 stride=stride, pad=pad, act=act,
+                                 out_dtype=out_dtype, ctx=ctx)
 
 
 def _pallas_attention(q, k, v, *, causal, sm_scale, kv_len=None, ctx):
@@ -504,6 +536,10 @@ def _pallas_tile_picker(op: str, shapes: tuple, dtype) -> tuple:
     if op == "attention_decode":
         return kernel_ops.default_attention_decode_blocks(
             *kernel_ops.attention_dims(shapes), dtype)
+    if op == "conv2d":
+        plan = kernel_ops.default_conv_implicit_blocks(*shapes, dtype)
+        if plan:
+            return plan
     if op == "gemm_bwd":
         variant, rows, kdim, cols = shapes
         return kernel_ops.default_gemm_bwd_blocks(variant, rows, kdim,
@@ -591,13 +627,14 @@ def _xla_attention(q, k, v, *, causal, sm_scale, kv_len=None, ctx):
 # Every pallas op carries a custom VJP: flash attention's backward kernels
 # live in kernels/flash_attention.py, the GEMM backward kernels (dX/dW,
 # shared by matmul, bmm and conv2d-as-im2col — im2col itself backpropagates
-# through a col2im scatter in kernels/common.py) in kernels/gemm.py, with
+# through a col2im scatter in kernels/common.py, and the implicit-patch
+# conv through the im2col formulation's VJP) in kernels/gemm.py, with
 # backward tiles resolved lazily under "gemm_bwd"/"attention_bwd" tile-plan
 # keys.  The full op set trains on the kernel path.
 register_backend("pallas", {
     "matmul": _pallas_matmul,
     "bmm": _pallas_bmm,
-    "conv2d": im2col_conv2d(_pallas_matmul),
+    "conv2d": _pallas_conv2d,
     "attention": _pallas_attention,
 }, tile_picker=_pallas_tile_picker,
     differentiable=("matmul", "bmm", "conv2d", "attention"))

@@ -290,6 +290,7 @@ class CompiledNetwork:
         self.op_log = tuple(backends.dispatch_log()[log_mark:])
         self.gemm_padded = backends.gemm_padded(self.op_log)
         self.im2col_phased = backends.im2col_phased(self.op_log)
+        self.conv_implicit = backends.conv_implicit(self.op_log)
         outs = jax.tree.leaves(traced.out_info)
         self.outputs = {"arrays": len(outs), "bytes_per_item": sum(
             math.prod(o.shape[1:]) * o.dtype.itemsize for o in outs)}
@@ -355,13 +356,15 @@ class CompiledNetwork:
           reps: timed repetitions after one untimed warm call.
 
         Returns `{per_call_s, reps, batch_size, trace_count, op_counts,
-        gemm_padded, im2col_phased, outputs, layers}`;
+        gemm_padded, im2col_phased, conv_implicit, outputs, layers}`;
         ``gemm_padded`` says how many of the lowering's tiled GEMM
         dispatches pad an operand (`backends.gemm_padded`),
         ``im2col_phased`` how many of its convolutions read their patches
-        from the input's phases (`backends.im2col_phased`), ``outputs``
-        the output arrays and their bytes per batch row (``{arrays,
-        bytes_per_item}``), ``layers`` the planned layers by kind.
+        from the input's phases (`backends.im2col_phased`),
+        ``conv_implicit`` how many form their patches inside the GEMM
+        (`backends.conv_implicit`), ``outputs`` the output arrays and
+        their bytes per batch row (``{arrays, bytes_per_item}``),
+        ``layers`` the planned layers by kind.
         """
         if x is None:
             x = jnp.zeros(self.in_spec.shape, self.in_spec.dtype)
@@ -377,6 +380,7 @@ class CompiledNetwork:
                 "op_counts": dict(self.op_counts),
                 "gemm_padded": self.gemm_padded,
                 "im2col_phased": self.im2col_phased,
+                "conv_implicit": self.conv_implicit,
                 "outputs": dict(self.outputs),
                 "layers": dict(self.net.layer_counts)}
 
@@ -402,7 +406,9 @@ class CompileCache:
     the compiled buckets' tiled GEMM dispatches and how many of them pad
     an operand (`backends.gemm_padded`), ``im2col_phased``: their
     convolutions and how many read patches from the input's phases
-    (`backends.im2col_phased`), ``outputs``: the output arrays and
+    (`backends.im2col_phased`), ``conv_implicit``: their convolutions and
+    how many form their patches inside the GEMM
+    (`backends.conv_implicit`), ``outputs``: the output arrays and
     their bytes per image (None before the first compile), and ``layers``:
     the planned layers by kind.
     """
@@ -493,9 +499,9 @@ class CompileCache:
 
     def _summed(self, counter: str) -> dict:
         """A `CompiledNetwork` dispatch-log counter (``gemm_padded``,
-        ``im2col_phased``: `backends` functions of the same names) summed
-        over the compiled buckets (read at compile time, so `stats()`
-        stays cheap per step)."""
+        ``im2col_phased``, ``conv_implicit``: `backends` functions of the
+        same names) summed over the compiled buckets (read at compile
+        time, so `stats()` stays cheap per step)."""
         out = getattr(backends, counter)(())
         for cn in self._compiled.values():
             for key, val in getattr(cn, counter).items():
@@ -516,6 +522,7 @@ class CompileCache:
             "pad_waste": (self._rows_pad / total) if total else 0.0,
             "gemm_padded": self._summed("gemm_padded"),
             "im2col_phased": self._summed("im2col_phased"),
+            "conv_implicit": self._summed("conv_implicit"),
             "outputs": next((dict(cn.outputs)
                              for cn in self._compiled.values()), None),
             "layers": dict(self.net.layer_counts),

@@ -88,17 +88,24 @@ def _gemm_kernel(x_ref, w_ref, scale_ref, shift_ref, o_ref, g_ref, racc_ref,
 
     @pl.when(pl.program_id(2) == nsteps - 1)
     def _epilogue():
-        acc = acc_ref[...]
-        u = acc
-        if scale_ref is not None:
-            u = u * scale_ref[...]
-        if shift_ref is not None:
-            u = u + shift_ref[...]
-        o_ref[...] = apply_act(u, act).astype(out_dtype)
-        if g_ref is not None:
-            g_ref[...] = act_deriv(u, act)
-        if racc_ref is not None:
-            racc_ref[...] = acc
+        _store_epilogue(acc_ref[...], scale_ref, shift_ref, o_ref, g_ref,
+                        racc_ref, act=act, out_dtype=out_dtype)
+
+
+def _store_epilogue(acc, scale_ref, shift_ref, o_ref, g_ref=None,
+                    racc_ref=None, *, act: str, out_dtype):
+    """Write act(acc * scale + shift) to ``o_ref`` and the optional
+    residuals (act'(pre-act), the raw accumulator) beside it."""
+    u = acc
+    if scale_ref is not None:
+        u = u * scale_ref[...]
+    if shift_ref is not None:
+        u = u + shift_ref[...]
+    o_ref[...] = apply_act(u, act).astype(out_dtype)
+    if g_ref is not None:
+        g_ref[...] = act_deriv(u, act)
+    if racc_ref is not None:
+        racc_ref[...] = acc
 
 
 @dataclasses.dataclass(frozen=True)
@@ -521,3 +528,122 @@ def bmm(x, w, *, out_dtype=None, bm: int = 256, bk: int = 256, bn: int = 256,
                   interpret=resolve_interpret(interpret), bwd_key=bwd_key,
                   bwd_dx=tuple(bwd_dx), bwd_dw=tuple(bwd_dw), batched=True)
     return _bmm(cfg, x, w)
+
+
+# ------------------------------------------------- implicit-patch conv ---
+# A stride-1 KxK convolution as the fused GEMM without im2col's KH*KW-fold
+# patch matrix in HBM.  Each image's padded input is flattened to
+# (rows * Wp, Cin): output pixel (i, j) sits at flat row r = i * Wp + j,
+# and tap (ki, kj) reads flat row r + ki * Wp + kj.  So a block of bm
+# output rows reads one contiguous window of its bm rows plus a halo of
+# (K - 1) * (Wp + 1), and every tap is that window at a static offset: the
+# patches are formed in VMEM.  The kernel computes Wp outputs per image
+# row; those past OW read past the row's end and are discarded.
+#
+# The pitch Wp (W + 2 * pad) is rounded up to a multiple of 8 where that
+# discards at most 2 of every 9 flat rows (a 7-wide 3x3's own share): the
+# flat views of the padded input and of the output are then bitcasts of
+# their (8, 128)-tiled 4-D layouts rather than copies.
+_MAX_DISCARD = 2 / 9
+
+
+def conv_layout(h: int, w: int, size: int, pad: int
+                ) -> tuple[int, int, int]:
+    """How `conv_gemm` lays out a stride-1 size x size convolution of an
+    (h, w) image: (wp, rows, halo) — the flat rows per image row, the flat
+    output rows per image, and the halo rows a block's window reads beyond
+    its own."""
+    oh, ow = h + 2 * pad - size + 1, w + 2 * pad - size + 1
+    wp = w + 2 * pad
+    aligned = -(-wp // 8) * 8
+    if aligned - ow <= _MAX_DISCARD * aligned:
+        wp = aligned
+    return wp, oh * wp, (size - 1) * (wp + 1)
+
+
+def _conv_gemm_kernel(x_ref, w_ref, scale_ref, shift_ref, o_ref, *,
+                      offs: tuple, act: str, out_dtype):
+    """One (bm, bn) output block from its input window x_ref (win, Cin):
+    tap t is the window's rows offs[t] .. offs[t] + bm."""
+    bm = o_ref.shape[0]
+    taps = [x_ref[pl.ds(off, bm), :] for off in offs]
+    if x_ref.shape[1] % 128:
+        # Rows short of the MXU depth: one dot over the (bm, taps * Cin)
+        # patch tile, the im2col GEMM's shape, not shallow ones per tap.
+        acc = _acc_dot(jnp.concatenate(taps, axis=1),
+                       w_ref[...].reshape(-1, w_ref.shape[-1]),
+                       ((1,), (0,)))
+    else:
+        acc = _acc_dot(taps[0], w_ref[0], ((1,), (0,)))
+        for t in range(1, len(taps)):
+            acc += _acc_dot(taps[t], w_ref[t], ((1,), (0,)))
+    _store_epilogue(acc, scale_ref, shift_ref, o_ref, act=act,
+                    out_dtype=out_dtype)
+
+
+def conv_gemm(x, w, scale=None, shift=None, *, size: int, pad: int,
+              act: str = "linear", out_dtype=None, bm: int, bn: int,
+              interpret: bool | None = None):
+    """Fused stride-1 convolution act(conv(x, w) * scale + shift) with the
+    patches formed in VMEM.
+
+    x: (B, H, W, Cin); w: (size*size*Cin, N) in (kh, kw, Cin) row order;
+    scale/shift: (N,) or None.  bm flat output rows of one image per block
+    (a multiple of 8, or all of `conv_layout`'s rows), bn | N.  Forward
+    only: `ops.conv2d_implicit` gives it its VJP.  The kernel is named
+    `matmul`, the fused GEMM's name, so that traces file convolution time
+    under one kernel whichever form runs it.
+    """
+    b, h, wd, c = x.shape
+    n = w.shape[-1]
+    oh, ow = h + 2 * pad - size + 1, wd + 2 * pad - size + 1
+    wp, rows, halo = conv_layout(h, wd, size, pad)
+    nm = -(-rows // bm)
+    win = -(-(bm + halo) // 8) * 8
+    # Image rows in the flat layout: enough that every window lies inside
+    # its image, and a multiple of 8 flat rows, so each window starts on
+    # a sublane tile.
+    hx = h + 2 * pad
+    while hx * wp < (nm - 1) * bm + win or hx * wp % 8:
+        hx += 1
+    lx = hx * wp
+    xf = jnp.pad(x, ((0, 0), (pad, hx - h - pad), (pad, wp - wd - pad),
+                     (0, 0))).reshape(b * lx, c)
+    wt = w.reshape(size * size, c, n)
+    offs = tuple(ki * wp + kj for ki in range(size) for kj in range(size))
+    out_dtype = jnp.dtype(out_dtype or x.dtype)
+
+    # Grid (column block, image, row block), columns outermost: each
+    # weight block is fetched once; the input windows once per column
+    # block.  Windows overlap by the halo, hence element offsets.
+    in_specs = [
+        pl.BlockSpec((pl.Element(win), pl.Element(c)),
+                     lambda j, g, i: (pl.multiple_of(g * lx + i * bm, 8), 0)),
+        pl.BlockSpec((size * size, c, bn), lambda j, g, i: (0, 0, j)),
+    ]
+    args = [xf, wt]
+    for vec in (scale, shift):
+        if vec is not None:
+            in_specs.append(pl.BlockSpec((1, bn), lambda j, g, i: (0, j)))
+            args.append(vec.reshape(1, n).astype(jnp.float32))
+
+    def kernel_fn(*refs):
+        x_ref, w_ref, *vecs, o_ref = refs
+        vecs = iter(vecs)
+        s_ref = next(vecs) if scale is not None else None
+        b_ref = next(vecs) if shift is not None else None
+        _conv_gemm_kernel(x_ref, w_ref, s_ref, b_ref, o_ref, offs=offs,
+                          act=act, out_dtype=out_dtype)
+
+    out = pl.pallas_call(
+        kernel_fn,
+        grid=(n // bn, b, nm),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((None, bm, bn), lambda j, g, i: (g, i, j)),
+        out_shape=jax.ShapeDtypeStruct((b, nm * bm, n), out_dtype),
+        interpret=resolve_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
+        name="matmul",
+    )(*args)
+    return out[:, :rows].reshape(b, oh, wp, n)[:, :, :ow]

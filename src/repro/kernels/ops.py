@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from repro.kernels import flash_attention as flash_kernel
 from repro.kernels import flash_decode as decode_kernel
 from repro.kernels import gemm as gemm_kernel
+from repro.kernels.common import im2col
 
 
 def _round_up(x: int, m: int) -> int:
@@ -211,6 +212,120 @@ def validate_attention_tiles(sq: int, skv: int, d: int, dtype,
     if bk > _round_up(skv, 128):
         problems.append(f"bk={bk} exceeds the padded key extent "
                         f"{_round_up(skv, 128)} (dead grid steps)")
+    return problems
+
+
+# ---------------------------------------- implicit-patch conv tiles ---
+# A stride-1 KxK convolution (K > 1) runs the fused GEMM on patches formed
+# in VMEM from a halo window of its input (`gemm_kernel.conv_gemm`) rather
+# than on im2col's KH*KW-fold matrix in HBM.  Its plan is (bm, bn): bm
+# output rows of one image's flat layout (`gemm_kernel.conv_layout`),
+# bn output columns.  The rule gives () where the convolution keeps
+# im2col: a 1x1 (its im2col is a reshape), a stride above 1, Cin below
+# IMPLICIT_MIN_CIN (the RGB stems), a dtype other than 32-bit (the taps
+# sit at unaligned offsets of 32-bit sublanes), a batch above 1 of
+# images with fewer flat output rows than the MXU has rows, or no plan
+# under the GEMM's VMEM budget.  A row block never spans two images, so
+# there each image streams the weights through the MXU for one short
+# block where the im2col GEMM runs the batch's rows as one, and the input
+# saved is small: Darknet-19's 7x7 512->1024 layers at batch 8 took
+# 179 us implicit against 161 us im2col on a TPU v5e, and 32.5 against
+# 43.4 us at batch 1.
+
+# Cin from which the implicit path runs: the narrowest Cin of the
+# published networks' stride-1 3x3 convolutions past their stems.  Below
+# 128 it forms one (bm, K*K*Cin) patch tile per block, so the MXU sees the
+# im2col GEMM's dot.  On a TPU v5e this beat im2col at every Cin 32/64
+# shape of the networks, by 1.5x to 2.6x (per-tap dots at K = Cin ran
+# 1.6x to 2.2x slower than the patch tile).
+IMPLICIT_MIN_CIN = 32
+_MXU_ROWS = 128
+# Row-block cap: a window reads (K - 1) * (Wp + 1) halo rows beside its
+# bm, 42 % more at bm 1024 for a 208-wide 3x3.
+_CONV_BM_CAP = 1024
+
+
+def _conv_working_set(bm: int, bn: int, c: int, kk: int, halo: int,
+                      itemsize: int) -> int:
+    """Bytes resident in VMEM for one implicit-conv grid step: the
+    double-buffered input window (bm + halo rows), weight block (kk, Cin,
+    bn) and output block, plus the fp32 dot result and the patch tile
+    (one tap where Cin fills the MXU depth) the dot reads; lanes padded
+    to 128 and rows to 8, as in `_working_set`."""
+    rm, rn = _round_up(bm, 8), _round_up(bn, 128)
+    window = _round_up(bm + halo, 8) * _round_up(c, 128)
+    weights = kk * _round_up(c, 8) * rn
+    patch = rm * _round_up(kk * c if c % 128 else c, 128)
+    return 2 * (window + weights + rm * rn) * itemsize + (rm * rn
+                                                          + patch) * 4
+
+
+def default_conv_implicit_blocks(x_shape: tuple, n: int, size: int,
+                                 stride: int, pad: int, dtype) -> tuple:
+    """Implicit-patch plan (bm, bn) of a conv2d, or () where it keeps
+    im2col (see above).  bm splits one image's flat output rows
+    (`gemm_kernel.conv_layout`): first the largest multiple of 8 that
+    divides them within `_CONV_BM_CAP` and at least half of it (or all
+    rows, within the cap), which leaves no rows past them to slice off;
+    then the fewest even blocks of at most the cap, more while the working
+    set does not fit.  bn: the `_exact_blocks` column blocks, largest
+    first."""
+    b, h, w, c = x_shape
+    itemsize = jnp.dtype(dtype).itemsize
+    if size == 1 or stride != 1 or c < IMPLICIT_MIN_CIN or itemsize != 4:
+        return ()
+    _, rows, halo = gemm_kernel.conv_layout(h, w, size, pad)
+    if rows < _MXU_ROWS and b > 1:
+        return ()
+    bns = _exact_blocks(n, _ALIGN[2], _CAPS["matmul"][2])
+    exact = [bm for bm in _exact_blocks(rows, _ALIGN[0], _CONV_BM_CAP)
+             if bm <= _CONV_BM_CAP and (bm == rows
+                                        or 2 * bm >= _CONV_BM_CAP)]
+    nm0 = -(-rows // _CONV_BM_CAP)
+    even = [rows if nm == 1 else _round_up(-(-rows // nm), 8)
+            for nm in range(nm0, max(nm0, rows // 128) + 1)]
+    for bm in exact[:1] + even:
+        for bn in bns:
+            if (_conv_working_set(bm, bn, c, size * size, halo, itemsize)
+                    <= _GEMM_VMEM_BUDGET):
+                return bm, bn
+    return ()
+
+
+def validate_conv_implicit_tiles(x_shape: tuple, n: int, size: int,
+                                 stride: int, pad: int, dtype,
+                                 tiles: tuple) -> list[str]:
+    """Static legality of an implicit-patch (bm, bn) plan for a conv2d
+    with the engine's key shapes, as `validate_gemm_tiles` is for a GEMM:
+    a stride-1 convolution of a 32-bit dtype; bm a multiple of 8 or all
+    of one image's flat output rows, and no longer than them (padded to
+    8); bn a multiple of 128 or N, dividing N; `_conv_working_set` under
+    the VMEM budget.  Returns problem strings; empty means legal."""
+    if len(tiles) != 2 or not all(
+            isinstance(t, int) and not isinstance(t, bool) and t > 0
+            for t in tiles):
+        return [f"plan {tiles!r} is not two positive ints (bm, bn)"]
+    bm, bn = tiles
+    _, h, w, c = x_shape
+    itemsize = jnp.dtype(dtype).itemsize
+    problems = []
+    if stride != 1 or itemsize != 4:
+        return [f"an implicit-patch plan needs stride 1 and a 32-bit "
+                f"dtype, not stride {stride} {jnp.dtype(dtype)}"]
+    _, rows, halo = gemm_kernel.conv_layout(h, w, size, pad)
+    if bm % 8 and bm != rows:
+        problems.append(f"bm={bm} is not a multiple of 8 sublanes nor the "
+                        f"image's {rows} flat output rows")
+    if bm > _round_up(rows, 8):
+        problems.append(f"bm={bm} exceeds the image's padded flat output "
+                        f"rows {_round_up(rows, 8)} (dead grid steps)")
+    if (bn % 128 and bn != n) or n % bn:
+        problems.append(f"bn={bn} is not a multiple of the 128-lane width "
+                        f"dividing N={n}, nor N")
+    ws = _conv_working_set(bm, bn, c, size * size, halo, itemsize)
+    if ws > _GEMM_VMEM_BUDGET:
+        problems.append(f"working set {ws} B exceeds the VMEM budget "
+                        f"{_GEMM_VMEM_BUDGET:.0f} B")
     return problems
 
 
@@ -710,3 +825,54 @@ def bmm(x, w, *, out_dtype=None, bm: int = 0, bk: int = 0, bn: int = 0,
                           interpret=interpret, bwd_key=(m, k, n),
                           bwd_dx=bwd_dx, bwd_dw=bwd_dw)
     return out if (mp, np_) == (m, n) else out[:, :m, :n]
+
+
+# ------------------------------------------- implicit-patch convolution ---
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _conv_implicit(x, w, scale, shift, size, pad, act, out_dtype, bm, bn,
+                   interpret):
+    return gemm_kernel.conv_gemm(x, w, scale, shift, size=size, pad=pad,
+                                 act=act, out_dtype=out_dtype, bm=bm, bn=bn,
+                                 interpret=interpret)
+
+
+def _conv_implicit_fwd(x, w, scale, shift, *statics):
+    return _conv_implicit(x, w, scale, shift, *statics), (x, w, scale, shift)
+
+
+def _conv_implicit_bwd(size, pad, act, out_dtype, bm, bn, interpret, res,
+                       dy):
+    """The im2col formulation's VJP: col2im over the fused GEMM's
+    backward kernels, as every other convolution trains (traced under the
+    GEMM backward's dispatch scope, as those kernels are)."""
+    def im2col_conv(x, w, scale, shift):
+        cols = im2col(x, size, size, 1, pad)
+        b, oh, ow, kk = cols.shape
+        y = matmul(cols.reshape(-1, kk), w, scale, shift, act=act,
+                   out_dtype=out_dtype, interpret=interpret)
+        return y.reshape(b, oh, ow, -1)
+
+    with jax.named_scope(gemm_kernel.GEMM_BWD_SCOPE):
+        return jax.vjp(im2col_conv, *res)[1](dy)
+
+
+_conv_implicit.defvjp(_conv_implicit_fwd, _conv_implicit_bwd)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("size", "pad", "act", "out_dtype", "bm", "bn",
+                              "interpret"))
+def conv2d_implicit(x, w, scale=None, shift=None, *, size: int, pad: int,
+                    act: str = "linear", out_dtype=None, bm: int, bn: int,
+                    interpret: bool | None = None):
+    """Fused stride-1 convolution act(conv(x, w) * scale + shift) with its
+    patches formed in VMEM (`gemm_kernel.conv_gemm`): x (B, H, W, Cin), w
+    (size*size*Cin, N) in (kh, kw, Cin) row order, scale/shift (N,) or
+    None; returns (B, OH, OW, N).  (bm, bn) is the plan
+    `default_conv_implicit_blocks` gives (the pallas backend's conv2d
+    plan where the convolution takes this path).
+    DIFFERENTIABLE: the custom VJP is the im2col formulation's.
+    """
+    return _conv_implicit(x, w, scale, shift, size, pad, act,
+                          jnp.dtype(out_dtype or x.dtype), bm, bn, interpret)

@@ -287,7 +287,7 @@ def test_nondifferentiable_backend_gemm_raises_clear_error():
     not the bare AssertionError pallas_call used to die with deep inside
     autodiff.  Registered here on purpose: the built-in pallas backend
     now differentiates its whole op set, so the guard is exercised via a
-    deliberately grad-less registration (the conv_direct.py situation)."""
+    deliberately grad-less registration."""
     xla = get_backend("xla")
     register_backend("nodiff-gemm", dict(xla.ops), differentiable=(),
                      overwrite=True)

@@ -1,4 +1,5 @@
-"""im2col's patches and the `im2col_phased` counter.
+"""im2col's patches, the `im2col_phased` counter, and the `conv_implicit`
+counter of the convolutions that form their patches inside the GEMM.
 
 At stride s > 1 `kernels.common.im2col` reads each tap as a unit-stride
 window of one of the padded input's s x s phases; the oracle here is the
@@ -77,3 +78,53 @@ def test_profile_and_stats_report_im2col_phased(cfg, want):
     cache = net.compile_cache(params, buckets=(1,))
     assert cache.get(1).profile(reps=1)["im2col_phased"] == want
     assert cache.stats()["im2col_phased"] == want
+
+
+def _traced_log(cfg, batch):
+    """The dispatch log of one abstract trace on the pallas engine."""
+    net = Network(cfg, make_engine("pallas"))
+    h, w, c = net.in_shape
+    params = jax.eval_shape(net.init, jax.random.PRNGKey(0))
+    mark = backends.dispatch_log_size()
+    jax.eval_shape(net.apply, params,
+                   jax.ShapeDtypeStruct((batch, h, w, c), jnp.float32))
+    return backends.dispatch_log()[mark:]
+
+
+PUBLISHED = pytest.mark.parametrize("cfg, batch, want", [
+    # 32 stride-1 3x3 convolutions past the stem; 37 1x1, 5 stride-2 and
+    # the stem keep im2col.
+    (YOLOV3_CFG, 1, {"convs": 75, "implicit": 32}),
+    # 11 stride-1 3x3 with Cin >= 32; the stem and the 7 1x1 keep im2col.
+    (DARKNET19_CFG, 1, {"convs": 19, "implicit": 11}),
+    # At batch 8 the three 7x7 (63 flat rows an image) keep im2col too.
+    (DARKNET19_CFG, 8, {"convs": 19, "implicit": 8}),
+], ids=["yolov3_416_b1", "darknet19_b1", "darknet19_b8"])
+
+
+@PUBLISHED
+def test_conv_implicit_counts_published_networks(cfg, batch, want):
+    assert backends.conv_implicit(_traced_log(cfg, batch)) == want
+
+
+@PUBLISHED
+def test_gemm_padded_counts_only_im2col_gemms(cfg, batch, want):
+    """A convolution that forms its patches in VMEM runs no tiled GEMM:
+    `gemm_padded` counts the other convolutions (and no head GEMM here)."""
+    gemms = backends.gemm_padded(_traced_log(cfg, batch))
+    assert gemms["gemms"] == want["convs"] - want["implicit"]
+
+
+@pytest.mark.parametrize("backend, want", [
+    # At batch 1 every stride-1 3x3 past the stem, down to 1x1 images.
+    ("pallas", {"convs": 19, "implicit": 11}),
+    ("xla", {"convs": 19, "implicit": 0}),      # every conv on im2col
+], ids=["pallas", "xla"])
+def test_profile_and_stats_report_conv_implicit(backend, want):
+    cfg = (DARKNET19_CFG.replace("height=224", "height=32")
+           .replace("width=224", "width=32"))
+    net = Network(cfg, make_engine(backend))
+    params = net.init(jax.random.PRNGKey(0))
+    cache = net.compile_cache(params, buckets=(1,))
+    assert cache.get(1).profile(reps=1)["conv_implicit"] == want
+    assert cache.stats()["conv_implicit"] == want

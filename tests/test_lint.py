@@ -147,9 +147,37 @@ def test_darknet19_pallas_dispatch_plans_lint_clean(batch):
     jax.jit(net.apply).trace(
         params, jax.ShapeDtypeStruct((batch, 224, 224, 3), jnp.float32))
     log = tuple(backends.dispatch_log()[mark:])
-    assert sum("gemm" in r for r in log) == 19
+    # The implicit convolutions (at batch 8 not the three 7x7) form their
+    # patches in VMEM under a (bm, bn) plan, the others run im2col GEMMs:
+    # R004 checks both kinds.
+    implicit = {1: 11, 8: 8}[batch]
+    assert sum("gemm" in r for r in log) == 19 - implicit
+    assert sum(len(r["tiles"]) == 2 for r in log) == implicit
     report = lint.run_lint(lint.LintContext(op_log=log), rules=("R004",))
     assert report.findings == [], report.format()
+
+
+@pytest.mark.parametrize("tiles, bad", [
+    ((224, 256), None),           # the rule's plan: legal
+    ((100, 256), "bm=100"),       # unaligned and not the image's rows
+    ((232, 256), "bm=232"),       # past the image's 224 flat rows
+    ((224, 384), "bn=384"),       # does not divide N
+    ((224, 256, 128), None),      # an im2col plan for the same conv
+])
+def test_r004_checks_implicit_conv_plans(tiles, bad):
+    """A conv2d record's implicit-patch (bm, bn) plan is checked against
+    the implicit kernel's own conditions, an im2col (bm, bk, bn) plan
+    against the GEMM's."""
+    ctx = lint.LintContext(op_log=(
+        {"backend": "pallas", "op": "conv2d",
+         "shapes": ((8, 14, 14, 256), 512, 3, 1, 1), "dtype": "float32",
+         "tiles": tiles},))
+    report = lint.run_lint(ctx, rules=("R004",))
+    if bad is None:
+        assert report.findings == [], report.format()
+    else:
+        _only_rule(report, "R004")
+        assert bad in report.findings[0].message
 
 
 def test_r004_catches_pinned_engine_tiles_via_dispatch_log():

@@ -123,7 +123,9 @@ def test_compiled_network_reports_gemm_padded():
     assert prof["gemm_padded"]["gemms"] == 4         # three convs, a head
     assert prof["gemm_padded"]["padded"] == 0
     stats = cache.stats()["gemm_padded"]
-    assert stats["gemms"] == 8 and stats["padded"] == 0
+    # At bucket 1 the 7x7 Cin 32 conv forms its patches in VMEM and runs
+    # no tiled GEMM: 3 + 4.
+    assert stats["gemms"] == 7 and stats["padded"] == 0
     assert stats["operand_bytes"] == stats["padded_operand_bytes"]
 
 

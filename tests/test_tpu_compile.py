@@ -13,6 +13,8 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, and every test worker imports
 this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -151,27 +153,102 @@ def test_stride2_conv_compiles_without_gather(one_chip):
     assert " gather(" not in text
 
 
-def test_darknet19_network_compiles(one_chip):
-    net = Network(DARKNET19_CFG,
-                  make_engine("pallas", "fp32_strict", interpret=False))
+def _entry_names(text):
+    """Name stems of the entry computation's instructions."""
+    entry = text[text.index("\nENTRY"):]
+    return [re.sub(r"(\.\d+)+$", "", m.group(1))
+            for m in re.finditer(r"^\s*(?:ROOT )?%([\w\-.]+) = ", entry,
+                                 re.M)]
+
+
+def _kernel_stems(text):
+    """Name stems of the Mosaic kernels, which the device trace files
+    their time under."""
+    return [re.sub(r"(\.\d+)+$", "", m.group(1))
+            for m in re.finditer(r"%([\w\-.]+) = \S+ custom-call\(.*"
+                                 r"custom_call_target=\"tpu_custom_call\"",
+                                 text)]
+
+
+def _im2col_copies(text):
+    """The entry's concatenations, as XLA lowers them: `concatenate` and
+    the fusions of dynamic-update-slices."""
+    return sum(n == "concatenate" or "dynamic-update-slice" in n
+               for n in _entry_names(text))
+
+
+# Entry instructions that write no array of their own: the kernels'
+# custom-calls (their outputs are the GEMM's), views and async starts.
+_NOT_WRITTEN = {"parameter", "bitcast", "get-tuple-element", "tuple",
+                "constant", "custom-call", "copy-start", "slice-start"}
+
+
+def _entry_bytes_written(text):
+    """Logical bytes of the arrays the entry computation's instructions
+    outside the kernels write: im2col's matrices (whatever fusion XLA
+    files them under), pads, pooling, copies; every array here is
+    float32."""
+    entry = text[text.index("\nENTRY"):]
+    total = 0
+    for m in re.finditer(r"^\s*(?:ROOT )?%[\w\-.]+ = \w+\[([\d,]*)\]\{[^}]*\}"
+                         r" ([\w\-]+)\(", entry, re.M):
+        dims, op = m.groups()
+        if op not in _NOT_WRITTEN:
+            total += 4 * int(np.prod([int(d) for d in dims.split(",") if d]))
+    return total
+
+
+def _compile_network(one_chip, cfg, batch, hw):
+    net = Network(cfg, make_engine("pallas", "fp32_strict", interpret=False))
     params = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
                           jax.eval_shape(net.init, jax.random.key(0)))
-    _assert_kernel(net.apply, params, _spec(one_chip, (8, 224, 224, 3)))
+    return _assert_kernel(net.apply, params,
+                          _spec(one_chip, (batch, hw, hw, 3)))
+
+
+def test_darknet19_network_compiles(one_chip):
+    """Darknet-19 at bucket 8: every convolution's kernel is named
+    `matmul`, the eight implicit-patch ones included, and only the im2col
+    of the stem and the three 7x7 convolutions (at most one copy per tap)
+    is left to concatenate."""
+    text = _compile_network(one_chip, DARKNET19_CFG, 8, 224)
+    assert _kernel_stems(text) == ["matmul"] * 19
+    assert _im2col_copies(text) <= 9 * 4
+    # 884 MB per step (with im2col for every 3x3 conv: 2,022 MB).
+    assert _entry_bytes_written(text) <= 1_000e6
 
 
 def test_yolov3_network_compiles(one_chip):
     """YOLOv3-416 at bucket 1: GEMM extents Darknet-19 never had (K = 27 at
     M = 173056, N = 255 heads, K = 768 and 384 after the cross-scale
     routes, M = 676 with no aligned row divisor, whose (676, 768, 256)
-    plan once overflowed Mosaic's scoped VMEM), and five stride-2
-    downsamples whose im2col holds no gather."""
-    net = Network(YOLOV3_CFG,
-                  make_engine("pallas", "fp32_strict", interpret=False))
-    params = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
-                          jax.eval_shape(net.init, jax.random.key(0)))
-    text = _assert_kernel(net.apply, params,
-                          _spec(one_chip, (1, 416, 416, 3)))
+    plan once overflowed Mosaic's scoped VMEM), five stride-2 downsamples
+    whose im2col holds no gather, and 32 implicit-patch convolutions from
+    208 down to 13 wide.  Every kernel is named `matmul`; what concatenates
+    is the im2col of the six convolutions that keep it (the stem, the
+    downsamples; at most one copy per tap) and the four routes."""
+    text = _compile_network(one_chip, YOLOV3_CFG, 1, 416)
     assert " gather(" not in text
+    assert _kernel_stems(text) == ["matmul"] * 75
+    assert _im2col_copies(text) <= 9 * 6 + 4
+    # 875 MB per image (with im2col for every 3x3 conv: 3,212 MB).
+    assert _entry_bytes_written(text) <= 1_000e6
+
+
+def test_implicit_conv_backward_compiles(one_chip):
+    """The implicit-patch convolution at YOLOv3's 52-wide Cin 128 and its
+    custom VJP (the im2col formulation's backward)."""
+    eng = make_engine("pallas", "fp32_strict", interpret=False)
+
+    def loss(x, w, scale, shift):
+        return eng.conv2d(x, w, scale=scale, shift=shift, size=3, pad=1,
+                          act="leaky").sum()
+
+    text = _assert_kernel(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)),
+                          _spec(one_chip, (1, 52, 52, 128)),
+                          _spec(one_chip, (9 * 128, 256)),
+                          _spec(one_chip, (256,)), _spec(one_chip, (256,)))
+    assert "matmul" in _kernel_stems(text)
 
 
 # (rows, query length, key length): rows 64 shard over the 4-chip data
