@@ -357,3 +357,115 @@ YOLOV3_CFG = yolov3_cfg()
 # stage, widths / 16, 64x64 input (heads at 2, 4, 8), 2 classes.
 YOLOV3_SMALL_CFG = yolov3_cfg(size=64, width_div=16, blocks=(1, 1, 1, 1, 1),
                               classes=2)
+
+
+# ------------------------------------------------------------------- YOLOv4
+
+# yolov4.cfg's anchors (w, h in pixels of the 608 input), largest last; the
+# stride-8 head takes the first three.
+YOLOV4_ANCHORS = (12, 16, 19, 36, 40, 28, 36, 75, 76, 55, 72, 146, 142, 110,
+                  192, 243, 459, 401)
+
+
+def yolov4_cfg(size: int = 608, width_div: int = 1,
+               blocks=(1, 2, 8, 8, 4), classes: int = 80) -> str:
+    """The layer sections of darknet's yolov4.cfg (Bochkovskiy, Wang, Liao,
+    "YOLOv4: Optimal Speed and Accuracy of Object Detection", 2020) at
+    `size`.  Backbone CSPDarknet53, every convolution mish: per stage a
+    stride-2 3x3 downsample, a split into two 1x1 branches, `blocks[i]`
+    residual blocks (1x1, 3x3, [shortcut]) on one, and a [route] that
+    rejoins them.  Neck, leaky: SPP (stride-1 max-pools of 5, 9 and 13
+    [route]d with their input) and PANet, a top-down path of [upsample]s
+    and [route]s to the stage outputs and a bottom-up path of stride-2 3x3
+    convolutions.  Three [yolo] heads at strides 8, 16 and 32, the finest
+    first, with scale_x_y 1.2, 1.1 and 1.05.  `width_div` divides every
+    width but the heads' 3 * (5 + classes) outputs (tests use a smaller
+    cut)."""
+    sections = [["[net]", f"height={size}", f"width={size}", "channels=3"]]
+
+    def add(*lines):
+        sections.append(list(lines))
+        return len(sections) - 2          # the layer's index
+
+    def c(f):
+        return f // width_div
+
+    def conv(f, k, stride=1, activation="mish"):
+        return add(*_conv(c(f), k, stride, activation))
+
+    conv(32, 3)
+    stage_out = []
+    for i, n in enumerate(blocks):
+        f = 64 << i                        # the stage's output width
+        part = f if i == 0 else f // 2     # each branch's width
+        down = conv(f, 3, stride=2)
+        split = conv(part, 1)
+        add("[route]", f"layers = {down - split - 1}")
+        conv(part, 1)
+        for _ in range(n):
+            conv(f // 2, 1)
+            conv(part, 3)
+            add("[shortcut]", "from=-3", "activation=linear")
+        last = conv(part, 1)
+        add("[route]", f"layers = -1,{split - last - 1}")
+        stage_out.append(conv(f, 1))
+
+    def leaky(f, k, stride=1):
+        return conv(f, k, stride, "leaky")
+
+    def five(f):                           # 1x1, 3x3, 1x1, 3x3, 1x1
+        for k in (1, 3, 1, 3):
+            leaky(f if k == 1 else 2 * f, k)
+        return leaky(f, 1)
+
+    leaky(512, 1)
+    leaky(1024, 3)
+    leaky(512, 1)
+    add("[maxpool]", "stride=1", "size=5")          # SPP
+    for k, back in ((9, 2), (13, 4)):              # each from the 1x1
+        add("[route]", f"layers = -{back}")
+        add("[maxpool]", "stride=1", f"size={k}")
+    add("[route]", "layers = -1,-3,-5,-6")
+    leaky(512, 1)
+    leaky(1024, 3)
+    top = [leaky(512, 1)]
+    for f, lateral in ((256, stage_out[3]), (128, stage_out[2])):
+        leaky(f, 1)
+        add("[upsample]", "stride=2")
+        add("[route]", f"layers = {lateral}")
+        leaky(f, 1)
+        add("[route]", "layers = -1, -3")
+        top.append(five(f))
+    anchors = ",  ".join(f"{w},{h}" for w, h in zip(YOLOV4_ANCHORS[::2],
+                                                    YOLOV4_ANCHORS[1::2]))
+    out = 3 * (5 + classes)
+    heads = [(128, "0,1,2", 1.2), (256, "3,4,5", 1.1), (512, "6,7,8", 1.05)]
+    for h, (f, mask, scale) in enumerate(heads):
+        if h:
+            add("[route]", "layers = -4")
+            here = leaky(f, 3, stride=2)
+            add("[route]", f"layers = -1, {top[-1 - h] - here - 1}")
+            five(f)
+        leaky(2 * f, 3)
+        add(*_conv(out, 1, activation="linear", bn=False))
+        add("[yolo]", f"mask = {mask}", f"anchors = {anchors}",
+            f"classes={classes}", "num=9", "jitter=.3",
+            "ignore_thresh = .7", "truth_thresh = 1", f"scale_x_y = {scale}",
+            "iou_thresh=0.213", "cls_normalizer=1.0", "iou_normalizer=0.07",
+            "iou_loss=ciou", "nms_kind=greedynms", "beta_nms=0.6",
+            "max_delta=5")
+    return "\n" + "\n\n".join("\n".join(s) for s in sections) + "\n"
+
+
+# YOLOv4-608 at its published widths: 162 layers (110 convolutions: 72
+# mish, 35 leaky, 3 linear; 23 shortcuts, 21 routes, 3 max-pools, 2
+# upsamples, 3 [yolo] heads at 76, 38 and 19).
+YOLOV4_CFG = yolov4_cfg()
+
+# The same layer pattern at a CPU test's size: one residual block per
+# stage, widths / 8 (so that some mish convolutions have the 32 input
+# channels of the implicit-patch kernel), 256x256 input (heads at 32, 16,
+# 8, so that the SPP's 5 and 9 windows do not cover the 8-wide grid), 2
+# classes.
+YOLOV4_SMALL_CFG = yolov4_cfg(size=256, width_div=8, blocks=(1, 1, 1, 1, 1),
+                              classes=2)

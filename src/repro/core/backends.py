@@ -353,10 +353,12 @@ _DISPATCH_LOG_LIMIT = 65536
 
 
 def record_dispatch(backend: str, op: str, shapes: tuple | None = None,
-                    dtype=None, tiles: tuple = ()) -> None:
+                    dtype=None, tiles: tuple = (),
+                    act: str | None = None) -> None:
     """Count one engine dispatch and append its detail record
-    ``{backend, op, shapes, dtype, tiles}`` to the bounded log (oldest
-    records win; past the limit only the counter advances).  A GEMM-shaped
+    ``{backend, op, shapes, dtype, tiles, act}`` to the bounded log (oldest
+    records win; past the limit only the counter advances); ``act`` is
+    the fused activation of a matmul or conv2d.  A GEMM-shaped
     dispatch with a (bm, bk, bn) plan also records ``gemm``, its real
     (m, k, n), and ``padded``, the extents the kernel runs on
     (`kernel_ops.gemm_padding`; equal to ``gemm`` for an exact plan)."""
@@ -364,7 +366,7 @@ def record_dispatch(backend: str, op: str, shapes: tuple | None = None,
     if len(_DISPATCH_LOG) < _DISPATCH_LOG_LIMIT:
         rec = {"backend": backend, "op": op, "shapes": shapes,
                "dtype": None if dtype is None else str(jnp.dtype(dtype)),
-               "tiles": tuple(tiles or ())}
+               "tiles": tuple(tiles or ()), "act": act}
         dims = gemm_dims(op, shapes) if len(rec["tiles"]) == 3 else None
         if dims is not None:
             rec["gemm"] = dims
@@ -457,6 +459,13 @@ def conv_implicit(records) -> dict:
     convs = [r for r in records if r["op"] == "conv2d"]
     return {"convs": len(convs),
             "implicit": sum(len(r["tiles"]) == 2 for r in convs)}
+
+
+def conv_act(records) -> dict:
+    """A dispatch-log slice's conv2d dispatches by fused activation:
+    ``{act: convs}``."""
+    return dict(collections.Counter(r["act"] for r in records
+                                    if r["op"] == "conv2d"))
 
 
 # ------------------------------------------------------- pallas backend ---

@@ -8,10 +8,13 @@ Supported sections: net, convolutional, deconvolutional, maxpool, avgpool,
 upsample, route, shortcut, connected, softmax, dropout (inference no-op),
 yolo.
 
-``[yolo]`` is a detection head as darknet's ``forward_yolo_layer`` runs it
+``[maxpool]`` follows darknet's padding (`maxpool_pad`), so a stride-1
+pool keeps its grid, as YOLOv4's SPP block needs.  ``[yolo]`` is a
+detection head as darknet's ``forward_yolo_layer`` runs it
 at inference: the logistic on entries 0-1 (x, y) and 4.. (objectness and
 the classes) of each of its ``len(mask)`` anchors, which lie anchor-major
-on the channel axis; entries 2-3 (w, h) pass through.  A network with
+on the channel axis, with x and y then scaled by ``scale_x_y`` about 0.5;
+entries 2-3 (w, h) pass through.  A network with
 ``[yolo]`` sections returns the tuple of their outputs.  Box decoding
 (anchors, grid offsets, exp of w and h) and NMS are the host's work after
 the forward, as in darknet, and are not done here.
@@ -75,6 +78,14 @@ def conv_pad(options: dict[str, Any] | Section, size: int) -> int:
     if get("pad", 0):
         return size // 2
     return get("padding", 0)
+
+
+def maxpool_pad(options: dict[str, Any] | Section, size: int) -> int:
+    """Darknet [maxpool] padding rule, in one place: ``padding`` is the
+    total over both sides, defaulting to ``size - 1``.  The output is
+    ``(h + pad - size) // stride + 1``; windows start ``pad // 2`` before
+    the input (`layers.maxpool`)."""
+    return options.get("padding", size - 1)
 
 
 def parse_cfg(text: str) -> list[Section]:

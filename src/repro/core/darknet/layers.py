@@ -19,8 +19,8 @@ from repro.core import ComputeEngine
 from repro.kernels.common import apply_act, im2col  # noqa: F401  (re-export)
 
 _BN_EPS = 1e-5
-# The named scope of each glue layer (route, shortcut, upsample, yolo) in a
-# lowered network, beside the engine ops' "repro.op.<op>".
+# The named scope of each glue layer (maxpool, route, shortcut, upsample,
+# yolo) in a lowered network, beside the engine ops' "repro.op.<op>".
 LAYER_SCOPE_PREFIX = "repro.layer."
 
 
@@ -91,13 +91,26 @@ def deconv2d(engine: ComputeEngine, params: dict, x, *, size: int,
     return apply_act(out, act).astype(x.dtype)
 
 
-def maxpool(x, *, size: int, stride: int, pad: int = 0):
-    if pad:
-        x = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)),
-                    constant_values=-jnp.inf)
+def pool_padding(h: int, size: int, stride: int, pad: int) -> tuple[int, int]:
+    """(low, high) -inf border of darknet's [maxpool] along an axis of
+    extent `h`, with `pad` its total padding (`cfg.maxpool_pad`): windows
+    start ``pad // 2`` before the input, and the high side reaches only as
+    far as the last of the ``(h + pad - size) // stride + 1`` windows
+    reads."""
+    lo = pad // 2
+    out = (h + pad - size) // stride + 1
+    return lo, max(0, (out - 1) * stride + size - lo - h)
+
+
+@_scoped
+def maxpool(x, *, size: int, stride: int, pad: int):
+    """Darknet [maxpool] (``forward_maxpool_layer``) with total padding
+    `pad`; the border reads -inf, so it never wins a window."""
+    _, h, w, _ = x.shape
     return jax.lax.reduce_window(
         x, -jnp.inf, jax.lax.max, (1, size, size, 1), (1, stride, stride, 1),
-        "VALID")
+        ((0, 0), pool_padding(h, size, stride, pad),
+         pool_padding(w, size, stride, pad), (0, 0)))
 
 
 def avgpool_global(x):
@@ -120,13 +133,20 @@ def route(tensors):
 
 
 @_scoped
-def yolo(x, *, classes: int):
-    """Darknet [yolo] at inference (``forward_yolo_layer``): of each
-    anchor's ``5 + classes`` entries, anchor-major on the channel axis,
-    the logistic on 0-1 (x, y) and 4.. (objectness, classes); 2-3 (w, h)
-    stay linear."""
+def yolo(x, *, classes: int, scale_x_y: float = 1.0):
+    """Darknet [yolo] at inference (``forward_yolo_layer``, new_coords=0):
+    of each anchor's ``5 + classes`` entries, anchor-major on the channel
+    axis, the logistic on 0-1 (x, y) and 4.. (objectness, classes); 2-3
+    (w, h) stay linear.  Entries 0-1 are then ``σ·s - (s - 1) / 2`` for
+    ``s = scale_x_y``, in darknet's float32 arithmetic; at s = 1 that is σ
+    and nothing more is computed."""
     entry = np.arange(x.shape[-1]) % (5 + classes)
-    return jnp.where((entry < 2) | (entry >= 4), jax.nn.sigmoid(x), x)
+    y = jnp.where((entry < 2) | (entry >= 4), jax.nn.sigmoid(x), x)
+    if scale_x_y == 1:
+        return y
+    s = np.float32(scale_x_y)
+    shift = np.float32(-0.5) * (s - np.float32(1))
+    return jnp.where(entry < 2, y * s + shift, y)
 
 
 def connected(engine: ComputeEngine, params: dict, x, *, act: str):

@@ -74,7 +74,7 @@ class Network:
                 c = f
             elif t == "maxpool":
                 size, stride = s.get("size", 2), s.get("stride", 2)
-                pad = s.get("padding", 0)
+                pad = cfg_mod.maxpool_pad(s, size)
                 h = (h + pad - size) // stride + 1
                 w = (w + pad - size) // stride + 1
             elif t == "avgpool":
@@ -160,9 +160,9 @@ class Network:
                                act=o.get("activation", "leaky"),
                                batch_normalize=bool(o.get("batch_normalize", 0)))
             elif t == "maxpool":
-                x = L.maxpool(x, size=o.get("size", 2),
-                              stride=o.get("stride", 2),
-                              pad=o.get("padding", 0))
+                size = o.get("size", 2)
+                x = L.maxpool(x, size=size, stride=o.get("stride", 2),
+                              pad=cfg_mod.maxpool_pad(o, size))
             elif t == "avgpool":
                 x = L.avgpool_global(x)
             elif t == "upsample":
@@ -182,7 +182,8 @@ class Network:
             elif t == "dropout":
                 pass  # inference no-op
             elif t == "yolo":
-                x = L.yolo(x, classes=o.get("classes", 20))
+                x = L.yolo(x, classes=o.get("classes", 20),
+                           scale_x_y=o.get("scale_x_y", 1.0))
                 heads.append(x)
             outputs.append(x)
         return tuple(heads) if heads else x
@@ -291,6 +292,7 @@ class CompiledNetwork:
         self.gemm_padded = backends.gemm_padded(self.op_log)
         self.im2col_phased = backends.im2col_phased(self.op_log)
         self.conv_implicit = backends.conv_implicit(self.op_log)
+        self.conv_act = backends.conv_act(self.op_log)
         outs = jax.tree.leaves(traced.out_info)
         self.outputs = {"arrays": len(outs), "bytes_per_item": sum(
             math.prod(o.shape[1:]) * o.dtype.itemsize for o in outs)}
@@ -356,14 +358,16 @@ class CompiledNetwork:
           reps: timed repetitions after one untimed warm call.
 
         Returns `{per_call_s, reps, batch_size, trace_count, op_counts,
-        gemm_padded, im2col_phased, conv_implicit, outputs, layers}`;
+        gemm_padded, im2col_phased, conv_implicit, conv_act, outputs,
+        layers}`;
         ``gemm_padded`` says how many of the lowering's tiled GEMM
         dispatches pad an operand (`backends.gemm_padded`),
         ``im2col_phased`` how many of its convolutions read their patches
         from the input's phases (`backends.im2col_phased`),
         ``conv_implicit`` how many form their patches inside the GEMM
-        (`backends.conv_implicit`), ``outputs`` the output arrays and
-        their bytes per batch row (``{arrays, bytes_per_item}``),
+        (`backends.conv_implicit`), ``conv_act`` its convolutions by
+        fused activation (`backends.conv_act`), ``outputs`` the output
+        arrays and their bytes per batch row (``{arrays, bytes_per_item}``),
         ``layers`` the planned layers by kind.
         """
         if x is None:
@@ -381,6 +385,7 @@ class CompiledNetwork:
                 "gemm_padded": self.gemm_padded,
                 "im2col_phased": self.im2col_phased,
                 "conv_implicit": self.conv_implicit,
+                "conv_act": dict(self.conv_act),
                 "outputs": dict(self.outputs),
                 "layers": dict(self.net.layer_counts)}
 
@@ -408,7 +413,8 @@ class CompileCache:
     convolutions and how many read patches from the input's phases
     (`backends.im2col_phased`), ``conv_implicit``: their convolutions and
     how many form their patches inside the GEMM
-    (`backends.conv_implicit`), ``outputs``: the output arrays and
+    (`backends.conv_implicit`), ``conv_act``: their convolutions by fused
+    activation (`backends.conv_act`), ``outputs``: the output arrays and
     their bytes per image (None before the first compile), and ``layers``:
     the planned layers by kind.
     """
@@ -499,13 +505,13 @@ class CompileCache:
 
     def _summed(self, counter: str) -> dict:
         """A `CompiledNetwork` dispatch-log counter (``gemm_padded``,
-        ``im2col_phased``, ``conv_implicit``: `backends` functions of the
-        same names) summed over the compiled buckets (read at compile
-        time, so `stats()` stays cheap per step)."""
+        ``im2col_phased``, ``conv_implicit``, ``conv_act``: `backends`
+        functions of the same names) summed over the compiled buckets
+        (read at compile time, so `stats()` stays cheap per step)."""
         out = getattr(backends, counter)(())
         for cn in self._compiled.values():
             for key, val in getattr(cn, counter).items():
-                out[key] += val
+                out[key] = out[key] + val if key in out else val
         return out
 
     def stats(self) -> dict:
@@ -523,6 +529,7 @@ class CompileCache:
             "gemm_padded": self._summed("gemm_padded"),
             "im2col_phased": self._summed("im2col_phased"),
             "conv_implicit": self._summed("conv_implicit"),
+            "conv_act": self._summed("conv_act"),
             "outputs": next((dict(cn.outputs)
                              for cn in self._compiled.values()), None),
             "layers": dict(self.net.layer_counts),

@@ -43,13 +43,14 @@ class ComputeEngine:
     interpret: bool | None = None
 
     # ---------------------------------------------------------- dispatch ---
-    def _resolve(self, op: str, shapes: tuple, dtype) -> backends.OpContext:
+    def _resolve(self, op: str, shapes: tuple, dtype,
+                 act: str | None = None) -> backends.OpContext:
         """Look up the backend, take the tile plan from the cache (the
         backend's rule runs once per key, on first sight), count the
         dispatch (trace-time: compiled
-        programs pay this once; the detail record — shapes, dtype and the
-        RESOLVED tiles, pinned picks included — feeds the trace linter's
-        dispatch log)."""
+        programs pay this once; the detail record — shapes, dtype, the
+        fused activation and the RESOLVED tiles, pinned picks included —
+        feeds the trace linter's dispatch log)."""
         be = backends.get_backend(self.backend)
         if self.bm and self.bk and self.bn and op != "attention":
             # Pinned (bm, bk, bn) applies to the GEMM-shaped ops only;
@@ -59,7 +60,7 @@ class ComputeEngine:
         else:
             tiles = be.tiles(op, shapes, dtype)
         backends.record_dispatch(self.backend, op, shapes=shapes,
-                                 dtype=dtype, tiles=tiles)
+                                 dtype=dtype, tiles=tiles, act=act)
         return backends.OpContext(precision=self.precision,
                                   interpret=self.interpret, tiles=tiles)
 
@@ -96,7 +97,7 @@ class ComputeEngine:
         xc = x.astype(self.precision.compute_dtype).reshape(-1, k)
         wc = w.astype(self.precision.compute_dtype)
         xc, wc, scale, shift = self._guard("matmul", xc, wc, scale, shift)
-        ctx = self._resolve("matmul", (xc.shape[0], k, n), xc.dtype)
+        ctx = self._resolve("matmul", (xc.shape[0], k, n), xc.dtype, act)
         with jax.named_scope(backends.op_scope("matmul")):
             y = self._op("matmul")(xc, wc, scale, shift, act=act,
                                    out_dtype=out_dtype, ctx=ctx)
@@ -138,7 +139,8 @@ class ComputeEngine:
         wc = w.astype(self.precision.compute_dtype)
         xc, wc, scale, shift = self._guard("conv2d", xc, wc, scale, shift)
         ctx = self._resolve(
-            "conv2d", (xc.shape, wc.shape[-1], size, stride, pad), xc.dtype)
+            "conv2d", (xc.shape, wc.shape[-1], size, stride, pad), xc.dtype,
+            act)
         with jax.named_scope(backends.op_scope("conv2d")):
             return self._op("conv2d")(xc, wc, scale, shift, size=size,
                                       stride=stride, pad=pad, act=act,

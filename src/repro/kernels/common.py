@@ -39,8 +39,23 @@ def resolve_interpret(interpret: bool | None) -> bool:
 
 
 # Activations supported by the fused epilogue.  Darknet's default conv
-# activation is leaky ReLU with slope 0.1; LM blocks use silu/gelu.
+# activation is leaky ReLU with slope 0.1; YOLOv4's backbone uses mish;
+# LM blocks use silu/gelu.
 _LEAKY_SLOPE = 0.1
+# Darknet's softplus threshold inside mish (MISH_THRESHOLD).
+_MISH_THRESHOLD = 20.0
+
+
+def _mish_parts(x):
+    """(e, sp) of mish as darknet computes it: e = exp(min(x, 20)) and
+    softplus(x) = x above 20, exp(x) below -20, log1p(exp(x)) between
+    (darknet's CUDA kernel; its C code writes log(exp(x) + 1)).  The
+    exponent is capped so that no branch `where` leaves out overflows and
+    turns the gradient into NaN."""
+    e = jnp.exp(jnp.minimum(x, _MISH_THRESHOLD))
+    sp = jnp.where(x > _MISH_THRESHOLD, x,
+                   jnp.where(x < -_MISH_THRESHOLD, e, jnp.log1p(e)))
+    return e, sp
 
 
 def apply_act(x, act: str):
@@ -62,10 +77,13 @@ def apply_act(x, act: str):
         # tanh approximation, matches jax.nn.gelu(approximate=True)
         c = jnp.sqrt(2.0 / jnp.pi).astype(x.dtype)
         return 0.5 * x * (1.0 + jnp.tanh(c * (x + 0.044715 * x**3)))
+    if act == "mish":
+        # x * tanh(softplus(x)) (Misra 2019), darknet's thresholds.
+        return x * jnp.tanh(_mish_parts(x)[1])
     raise ValueError(f"unknown activation: {act!r}")
 
 
-ACTIVATIONS = ("linear", "relu", "leaky", "silu", "gelu")
+ACTIVATIONS = ("linear", "relu", "leaky", "silu", "gelu", "mish")
 
 
 def act_deriv(x, act: str):
@@ -89,6 +107,13 @@ def act_deriv(x, act: str):
         t = jnp.tanh(inner)
         return (0.5 * (1.0 + t)
                 + 0.5 * x * (1.0 - t**2) * c * (1.0 + 3 * 0.044715 * x**2))
+    if act == "mish":
+        # tanh(sp) + x * (1 - tanh(sp)^2) * sp'.  sp' is e / (1 + e) in
+        # all three branches to float32's precision: 1 above 20, exp(x)
+        # below -20 (darknet's backward writes it 1 - exp(-sp)).
+        e, sp = _mish_parts(x)
+        t = jnp.tanh(sp)
+        return t + x * (1.0 - t * t) * (e / (1.0 + e))
     raise ValueError(f"unknown activation: {act!r}")
 
 

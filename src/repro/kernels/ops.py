@@ -44,6 +44,15 @@ _GEMM_VMEM_BUDGET = 6.5 * 1024 * 1024
 # aligned or that spans its array's whole dimension.
 _ALIGN = (8, 128, 128)
 
+# The longest row block that spans a whole M extent no aligned block
+# divides.  Mosaic's scratch for a float32 HIGHEST dot grows with the
+# block's rows faster than `_working_set` does: YOLOv4-608's 38x38 GEMMs
+# (M = 1444) under (1444, 256, 256) needed 15.1 MiB of the 16 MiB scoped
+# limit compiled alone and 20.8 MiB inside the network (described v5e),
+# where 676 rows (YOLOv3-416's 26x26) need 7.2.  A longer extent takes the
+# padded row block.
+_FULL_BM_MAX = 1024
+
 # Block caps (bm, bk, bn) per op.  bmm runs smaller blocks: the batch grid
 # dimension multiplies the working set's live tiles.
 _CAPS = {"matmul": (256, 2048, 256), "bmm": (128, 256, 128)}
@@ -81,7 +90,8 @@ def pick_blocks(m: int, k: int, n: int, dtype,
     and slices nothing.  Among such plans: the first, in each axis's
     `_exact_blocks` order (bm outermost, bk innermost), whose
     double-buffered `_working_set` fits `_GEMM_VMEM_BUDGET` — large row blocks
-    first, since they read the weights fewest times.  Where no exact plan
+    first, since they read the weights fewest times — and whose row block
+    is at most `_FULL_BM_MAX` rows.  Where no exact plan
     fits, the axes that cannot be tiled exactly fall back to the padded
     pick of `padded_blocks`, each on its own.
 
@@ -92,6 +102,7 @@ def pick_blocks(m: int, k: int, n: int, dtype,
     itemsize = jnp.dtype(dtype).itemsize
     exact = [_exact_blocks(d, a, c) for d, a, c in zip((m, k, n), _ALIGN,
                                                         caps)]
+    exact[0] = [bm for bm in exact[0] if bm <= max(caps[0], _FULL_BM_MAX)]
     padded = padded_blocks(m, k, n, dtype, caps)
     # First every axis exact; then each axis may take its padded pick.
     for prefs in (exact, [e + [p] for e, p in zip(exact, padded)]):

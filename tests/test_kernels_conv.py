@@ -74,6 +74,11 @@ CASES = [
     (1, 52, 128, 128, "leaky", True),
     (1, 112, 32, 32, "leaky", True),
     (8, 14, 512, 128, "linear", True),
+    # YOLOv4's mish convolutions: Cin 32 and 64 (nine taps in one dot),
+    # Cin 256 (a dot per tap).
+    (1, 38, 32, 64, "mish", True),
+    (1, 19, 64, 64, "mish", False),
+    (1, 19, 256, 128, "mish", True),
 ]
 
 
@@ -109,10 +114,26 @@ def test_implicit_conv_pinned_plans(b, h, w_, cin, cout, size, pad, bm, bn):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("cin", [32, 256])
+def test_conv_gemm_mish_epilogue_matches_jax_nn(cin):
+    """The implicit-patch kernel's mish epilogue (interpret) against
+    mish(conv(x, w) * scale + shift) in `jax.nn` terms, on inputs wide
+    enough that pre-activations pass both of darknet's thresholds."""
+    x, wt, scale, shift = _operands(1, 19, 19, cin, 128, 3, True, seed=3)
+    x = 12.0 * x
+    got = _implicit_conv(x, wt, scale, shift, size=3, pad=1, act="mish")
+    u = _lax_conv(x, wt, scale, shift, size=3, pad=1, act="linear")
+    assert (u > 20).any() and (u < -20).any()
+    want = u * jnp.tanh(jax.nn.softplus(u))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-4)
+
+
 @pytest.mark.parametrize("b,h,cin,cout,act,affine", [
     (2, 7, 128, 128, "leaky", True),
     (1, 13, 32, 64, "linear", True),
     (2, 9, 64, 32, "leaky", False),
+    (1, 11, 32, 64, "mish", True),
 ])
 def test_implicit_conv_grads_equal_im2col(b, h, cin, cout, act, affine):
     """The custom VJP is the im2col formulation's: the same gradients."""

@@ -14,7 +14,7 @@ except ImportError:  # optional dev dep — property tests skip without it
     from hypothesis_stub import given, settings, st
 
 from repro.kernels import ops, ref
-from repro.kernels.common import ACTIVATIONS
+from repro.kernels.common import ACTIVATIONS, act_deriv, apply_act
 
 jax.config.update("jax_enable_x64", False)
 
@@ -59,6 +59,81 @@ def test_gemm_fused_epilogue(act):
     want = ref.matmul_ref(x, w, scale=scale, shift=shift, act=act)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
+
+
+def _mish_nn(x):
+    return x * jnp.tanh(jax.nn.softplus(x))
+
+
+def test_mish_matches_jax_nn_across_thresholds():
+    """`apply_act(x, "mish")` is x * tanh(softplus(x)) with darknet's
+    thresholds at +-20; over +-30 it agrees with the `jax.nn` form, and
+    it is exact at and beyond both thresholds."""
+    x = jnp.concatenate([jnp.linspace(-30.0, 30.0, 6001),
+                         jnp.array([-20.0, 20.0, -20.001, 20.001, 0.0])])
+    got = apply_act(x, "mish")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(_mish_nn(x)),
+                               rtol=2e-6, atol=1e-30)
+    big = jnp.array([25.0, 88.0, 1e4])
+    np.testing.assert_array_equal(np.asarray(apply_act(big, "mish")),
+                                  np.asarray(big))
+
+
+def test_mish_deriv_matches_grad():
+    """`act_deriv(x, "mish")`, the fused GEMM's backward residual, equals
+    jax.grad of `apply_act` and of the `jax.nn` form over +-30, and stays
+    finite far beyond."""
+    x = jnp.linspace(-30.0, 30.0, 6001)
+    got = act_deriv(x, "mish")
+    auto = jax.vmap(jax.grad(lambda v: apply_act(v, "mish")))(x)
+    ref_grad = jax.vmap(jax.grad(_mish_nn))(x)
+    # Near the derivative's zero (x ~ -1.19) only an absolute bound
+    # means anything; the far negative tail, ~1e-12, is held relatively.
+    tail = np.asarray(x) < -10
+    for want in (auto, ref_grad):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-7)
+        np.testing.assert_allclose(np.asarray(got)[tail],
+                                   np.asarray(want)[tail], rtol=1e-5)
+    far = jnp.array([-1e4, -100.0, 100.0, 1e4])
+    for g in (act_deriv(far, "mish"),
+              jax.vmap(jax.grad(lambda v: apply_act(v, "mish")))(far)):
+        assert np.all(np.isfinite(np.asarray(g)))
+
+
+def test_gemm_mish_epilogue_matches_jax_nn():
+    """The fused GEMM with the mish epilogue (interpret) against
+    mish(x @ w * scale + shift) in `jax.nn` terms, with pre-activations
+    past both thresholds; and its gradients against jax.grad of that."""
+    kx, kw, ks, kb = jax.random.split(jax.random.PRNGKey(5), 4)
+    m, k, n = 200, 96, 256
+    x = _rand(kx, (m, k), jnp.float32)
+    w = _rand(kw, (k, n), jnp.float32) * 3.0       # |pre-act| up to ~100
+    scale = jax.random.uniform(ks, (n,), jnp.float32, 0.5, 1.5)
+    shift = _rand(kb, (n,), jnp.float32)
+
+    def want_fn(x, w, scale, shift):
+        u = jnp.dot(x, w, precision=jax.lax.Precision.HIGHEST)
+        return _mish_nn(u * scale + shift)
+
+    def got_fn(x, w, scale, shift):
+        return ops.matmul(x, w, scale, shift, act="mish", interpret=True)
+
+    u = np.asarray(jnp.dot(x, w, precision=jax.lax.Precision.HIGHEST)
+                   * scale + shift)
+    assert (u > 20).any() and (u < -20).any()
+    np.testing.assert_allclose(np.asarray(got_fn(x, w, scale, shift)),
+                               np.asarray(want_fn(x, w, scale, shift)),
+                               rtol=1e-5, atol=1e-4)
+    cot = _rand(jax.random.PRNGKey(6), (m, n), jnp.float32)
+    got = jax.grad(lambda *a: jnp.sum(got_fn(*a) * cot),
+                   (0, 1, 2, 3))(x, w, scale, shift)
+    want = jax.grad(lambda *a: jnp.sum(want_fn(*a) * cot),
+                    (0, 1, 2, 3))(x, w, scale, shift)
+    for g, wt in zip(got, want):
+        wt = np.asarray(wt)
+        np.testing.assert_allclose(np.asarray(g), wt, rtol=0,
+                                   atol=2e-5 * np.max(np.abs(wt)))
 
 
 def test_gemm_fp32_strict_is_exactly_xla_dot():

@@ -22,8 +22,8 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs.darknet_ref import DARKNET19_CFG, YOLOV3_CFG
-from repro.core import make_engine
+from repro.configs.darknet_ref import DARKNET19_CFG, YOLOV3_CFG, YOLOV4_CFG
+from repro.core import backends, make_engine
 from repro.core.darknet.network import Network
 from repro.kernels import ops, sharded
 
@@ -198,12 +198,20 @@ def _entry_bytes_written(text):
     return total
 
 
+_COMPILED: dict = {}
+
+
 def _compile_network(one_chip, cfg, batch, hw):
-    net = Network(cfg, make_engine("pallas", "fp32_strict", interpret=False))
-    params = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
-                          jax.eval_shape(net.init, jax.random.key(0)))
-    return _assert_kernel(net.apply, params,
-                          _spec(one_chip, (batch, hw, hw, 3)))
+    """The network's compiled HLO text, compiled once per module run."""
+    key = (cfg, batch, hw)
+    if key not in _COMPILED:
+        net = Network(cfg, make_engine("pallas", "fp32_strict",
+                                       interpret=False))
+        params = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
+                              jax.eval_shape(net.init, jax.random.key(0)))
+        _COMPILED[key] = _assert_kernel(net.apply, params,
+                                        _spec(one_chip, (batch, hw, hw, 3)))
+    return _COMPILED[key]
 
 
 def test_darknet19_network_compiles(one_chip):
@@ -233,6 +241,63 @@ def test_yolov3_network_compiles(one_chip):
     assert _im2col_copies(text) <= 9 * 6 + 4
     # 875 MB per image (with im2col for every 3x3 conv: 3,212 MB).
     assert _entry_bytes_written(text) <= 1_000e6
+
+
+# (entry instructions, pad operations, reduce-windows) of the compiled
+# programs before darknet's [maxpool] padding and [yolo] scale_x_y came
+# in: Darknet-19's size-2 stride-2 pools read no border, and a head at
+# scale_x_y 1 is the logistic alone.
+_BEFORE = {"darknet19": (587, 40, 5), "yolov3": (2107, 49, 0)}
+
+
+@pytest.mark.parametrize("name, cfg, batch, hw", [
+    ("darknet19", DARKNET19_CFG, 8, 224), ("yolov3", YOLOV3_CFG, 1, 416)])
+def test_pools_and_heads_lower_as_before(one_chip, name, cfg, batch, hw):
+    """Darknet-19 at bucket 8 and YOLOv3-416 at bucket 1 compile to as many
+    entry instructions, pads and reduce-windows as before, and no pool
+    window reads a border."""
+    text = _compile_network(one_chip, cfg, batch, hw)
+    assert (len(_entry_names(text)), text.count(" pad("),
+            text.count("reduce-window(")) == _BEFORE[name]
+    assert not re.search(r"reduce-window\(.*window=\{[^}]*pad=", text)
+
+
+def test_yolov4_network_compiles(one_chip):
+    """YOLOv4-608 at bucket 1: shapes no other network has (a 608 stem,
+    304- and 76-wide implicit convolutions, 38x38 GEMMs of 1444 rows, which
+    no aligned block divides), 110 kernels named `matmul` (72 with the mish
+    epilogue), seven stride-2 downsamples with no gather, and the SPP's
+    three stride-1 pools over a -inf border inside the reduce-window."""
+    mark = backends.dispatch_log_size()
+    text = _compile_network(one_chip, YOLOV4_CFG, 1, 608)
+    log = backends.dispatch_log()[mark:]
+    assert backends.conv_act(log) == {"mish": 72, "leaky": 35, "linear": 3}
+    assert backends.conv_implicit(log) == {"convs": 110, "implicit": 36}
+    assert backends.im2col_phased(log) == {"convs": 110, "phased": 7}
+    assert " gather(" not in text
+    assert _kernel_stems(text) == ["matmul"] * 110
+    windows = re.findall(r"reduce-window\(.*window=\{([^}]*)\}", text)
+    assert sorted(windows) == [f"size=1x{k}x{k}x1 pad=0_0x{k // 2}_{k // 2}"
+                               f"x{k // 2}_{k // 2}x0_0" for k in (13, 5, 9)]
+
+
+def test_mish_implicit_conv_compiles(one_chip):
+    """YOLOv4's 304-wide Cin-32 3x3 convolution with the mish epilogue on
+    the implicit-patch kernel: a 2 * 306 + 2 halo (at the pitch 312, 626
+    rows) beside each row block."""
+    eng = make_engine("pallas", "fp32_strict", interpret=False)
+    mark = backends.dispatch_log_size()
+
+    def conv(x, w, scale, shift):
+        return eng.conv2d(x, w, scale=scale, shift=shift, size=3, pad=1,
+                          act="mish")
+
+    text = _assert_kernel(conv, _spec(one_chip, (1, 304, 304, 32)),
+                          _spec(one_chip, (9 * 32, 64)),
+                          _spec(one_chip, (64,)), _spec(one_chip, (64,)))
+    assert _kernel_stems(text) == ["matmul"]
+    assert backends.conv_implicit(backends.dispatch_log()[mark:]) == \
+        {"convs": 1, "implicit": 1}
 
 
 def test_implicit_conv_backward_compiles(one_chip):

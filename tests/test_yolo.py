@@ -1,12 +1,18 @@
-"""YOLOv3 through the Darknet path: the [yolo] section, multi-output
-`Network` / `CompileCache` / `CNNServingEngine`, against a plain reference.
+"""YOLOv3 and YOLOv4 through the Darknet path: the [yolo] section with
+`scale_x_y`, darknet's [maxpool] padding, mish, multi-output `Network` /
+`CompileCache` / `CNNServingEngine`, against a plain reference.
 
-The reference below is YOLOv3's forward in straightforward `jax.numpy` and
-`lax.conv_general_dilated` under ``jax.default_matmul_precision("highest")``
-over its own reading of the cfg text; it imports nothing of the program.
-Departures from darknet's C code: batch-norm's eps inside the root
-(darknet adds 1e-6 to sqrt(var)); box decoding and NMS, which darknet runs
-on the host after the forward, are left out here as in the program.
+The reference below is the detectors' forward in straightforward
+`jax.numpy` and `lax.conv_general_dilated` under
+``jax.default_matmul_precision("highest")`` over its own reading of the
+cfg text; it imports nothing of the program.  It follows darknet's C code
+(`forward_maxpool_layer`, `activate_array_mish`, `forward_yolo_layer` with
+new_coords=0), with these departures: batch-norm's eps inside the root
+(darknet adds 1e-6 to sqrt(var)); softplus inside mish as log1p(exp(x))
+between the thresholds (darknet's CUDA kernel; its C code writes
+log(exp(x) + 1)); the shift -(s - 1) / 2 of [yolo]'s x, y rounded to
+float32 from float64 (darknet computes it in float32); box decoding and NMS, which darknet
+runs on the host after the forward, are left out here as in the program.
 """
 import math
 
@@ -16,14 +22,17 @@ import numpy as np
 import pytest
 
 from repro.configs.darknet_ref import (DARKNET_SMALL_CFG, YOLOV3_CFG,
-                                       YOLOV3_SMALL_CFG)
-from repro.core import make_engine
+                                       YOLOV3_SMALL_CFG, YOLOV4_CFG,
+                                       YOLOV4_SMALL_CFG)
+from repro.core import backends, make_engine
 from repro.core.darknet import cfg as cfg_mod
+from repro.core.darknet import layers as L
 from repro.core.darknet.network import Network
 from repro.serve import frontend as fe
 
 SMALL_HW, SMALL_CLASSES = 64, 2
-EPS, SLOPE = 1e-5, 0.1
+V4_HW = 256
+EPS, SLOPE, MISH_THRESHOLD = 1e-5, 0.1, 20.0
 
 
 # --------------------------------------------------------------- reference
@@ -43,6 +52,15 @@ def _sections(text):
 
 def _ints(v):
     return [int(s) for s in v.split(",")]
+
+
+def _mish(x):
+    """darknet's mish: x * tanh(softplus(x)), softplus x above 20, exp(x)
+    below -20."""
+    sp = jnp.where(x > MISH_THRESHOLD, x,
+                   jnp.where(x < -MISH_THRESHOLD, jnp.exp(x),
+                             jnp.log1p(jnp.exp(x))))
+    return x * jnp.tanh(sp)
 
 
 def reference(text, params, x):
@@ -65,6 +83,11 @@ def reference(text, params, x):
                     x = x + p["b"]
                 if o["activation"] == "leaky":
                     x = jnp.where(x > 0, x, SLOPE * x)
+                elif o["activation"] == "mish":
+                    x = _mish(x)
+            elif t == "maxpool":
+                k, s = int(o["size"]), int(o["stride"])
+                x = _darknet_maxpool(x, k, s, int(o.get("padding", k - 1)))
             elif t == "shortcut":
                 x = x + outs[i + int(o["from"])]
             elif t == "route":
@@ -76,11 +99,25 @@ def reference(text, params, x):
             elif t == "yolo":
                 entry = np.arange(x.shape[-1]) % (5 + int(o["classes"]))
                 x = jnp.where((entry < 2) | (entry >= 4), jax.nn.sigmoid(x), x)
+                sxy = float(o.get("scale_x_y", 1))
+                x = jnp.where(entry < 2, x * sxy - 0.5 * (sxy - 1), x)
                 heads.append(x)
             else:
                 raise ValueError(t)
             outs.append(x)
     return tuple(heads)
+
+
+def _darknet_maxpool(x, size, stride, pad):
+    """darknet's [maxpool] with total padding `pad`: a -inf border of
+    pad // 2 rows and columns before the input and pad - pad // 2 after,
+    then every size x size window at `stride`."""
+    lo, hi = pad // 2, pad - pad // 2
+    x = jnp.pad(x, ((0, 0), (lo, hi), (lo, hi), (0, 0)),
+                constant_values=-jnp.inf)
+    return jax.lax.reduce_window(x, -jnp.inf, jax.lax.max,
+                                 (1, size, size, 1), (1, stride, stride, 1),
+                                 "VALID")
 
 
 def _params(net, seed=0, residual_gamma=0.2):
@@ -250,3 +287,156 @@ def test_outputs_and_layers_counters():
     cn = clf.compile(clf.init(jax.random.PRNGKey(0)), batch_size=2)
     assert cn.profile(reps=1)["outputs"] == {"arrays": 1,
                                              "bytes_per_item": 40}
+
+
+# ------------------------------------------------------------------ YOLOv4
+
+def _small_v4(backend="xla"):
+    net = Network(YOLOV4_SMALL_CFG, make_engine(backend, "fp32_strict"))
+    return net, _params(net)
+
+
+def _v4_images(n, seed=1):
+    return np.random.default_rng(seed).standard_normal(
+        (n, V4_HW, V4_HW, 3)).astype(np.float32)
+
+
+def _assert_near_reference(got, want, classes=SMALL_CLASSES):
+    """Both sides compute in float32 at full precision; only the order of
+    the sums differs (the program's GEMMs against XLA's convolution),
+    which after YOLOv4-small's 74 convolutions moves a head value by up
+    to 2.3e-6 of the head's largest |value| (both backends, on the CPU).
+    Products in bf16x3, the next precision down, read 3.5e-5 to 4.9e-5."""
+    assert len(got) == len(want) == 3
+    for g, w in zip(got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        scale = np.max(np.abs(w))
+        np.testing.assert_allclose(g, w, rtol=0, atol=1e-5 * scale)
+        entry = np.arange(w.shape[-1]) % (5 + classes)
+        logistic = entry >= 4
+        assert np.all((g[..., logistic] > 0) & (g[..., logistic] < 1))
+
+
+def test_yolov4_608_plan():
+    """yolov4.cfg at 608: 162 layers (110 convolutions, 23 shortcuts, 21
+    routes, 3 max-pools, 2 upsamples, 3 [yolo]), 64,429,405 parameters,
+    the SPP pools keep the 19x19 grid, heads at 76, 38 and 19 with
+    3 * (5 + 80) channels and their scale_x_y, the finest first."""
+    net = Network(YOLOV4_CFG)
+    assert len(net.plans) == 162
+    assert net.layer_counts == {"convolutional": 110, "shortcut": 23,
+                                "route": 21, "maxpool": 3, "upsample": 2,
+                                "yolo": 3}
+    assert [p.out_shape for p in net.plans if p.type == "maxpool"] == \
+        [(19, 19, 512)] * 3
+    assert net.plans[113].out_shape == (19, 19, 2048)
+    yolos = [p for p in net.plans if p.type == "yolo"]
+    assert [p.out_shape for p in yolos] == [(76, 76, 255), (38, 38, 255),
+                                            (19, 19, 255)]
+    assert [p.options["scale_x_y"] for p in yolos] == [1.2, 1.1, 1.05]
+    shapes = jax.eval_shape(net.init, jax.random.PRNGKey(0))
+    assert sum(math.prod(a.shape) for a in jax.tree.leaves(shapes)) == \
+        64_429_405
+    secs = cfg_mod.parse_cfg(YOLOV4_CFG)
+    assert [(s.type, s.options) for s in secs] == \
+        [(s.type, s.options) for s in cfg_mod.parse_cfg(
+            cfg_mod.dump_cfg(secs))]
+
+
+def test_yolov4_608_conv_act_counter():
+    """`conv_act` of YOLOv4-608's lowering, read from the dispatch log of
+    one trace (shapes only, nothing compiled): 72 mish, 35 leaky, 3
+    linear; and the activations YOLOv3-416 fuses."""
+    for cfg, want in ((YOLOV4_CFG, {"mish": 72, "leaky": 35, "linear": 3}),
+                      (YOLOV3_CFG, {"leaky": 72, "linear": 3})):
+        net = Network(cfg, make_engine("xla", "fp32_strict"))
+        params = jax.eval_shape(net.init, jax.random.PRNGKey(0))
+        mark = backends.dispatch_log_size()
+        jax.eval_shape(net.apply, params, jax.ShapeDtypeStruct(
+            (1,) + net.in_shape, jnp.float32))
+        assert backends.conv_act(backends.dispatch_log()[mark:]) == want
+
+
+@pytest.mark.parametrize("backend", ["pallas", "xla"])
+def test_yolov4_small_matches_reference(backend):
+    net, params = _small_v4(backend)
+    x = jnp.asarray(_v4_images(2))
+    got = jax.jit(net.apply)(params, x)
+    want = reference(YOLOV4_SMALL_CFG, params, x)
+    assert [g.shape for g in got] == [(2, 32, 32, 21), (2, 16, 16, 21),
+                                      (2, 8, 8, 21)]
+    _assert_near_reference(got, want)
+
+
+@pytest.mark.parametrize("backend", ["pallas", "xla"])
+def test_yolov4_small_served_matches_reference(backend):
+    """Three requests through `CompileCache` (buckets 1, 2) and
+    `CNNServingEngine`: each gets its rows of every head, near the
+    reference; `stats()` and `profile()` count the convolutions by fused
+    activation."""
+    net, params = _small_v4(backend)
+    cache = net.compile_cache(params, buckets=(1, 2))
+    eng = fe.CNNServingEngine(cache)
+    imgs = _v4_images(3, seed=5)
+    reqs = [fe.ImageRequest(rid=i, image=im) for i, im in enumerate(imgs)]
+    eng.run(reqs)
+    want = reference(YOLOV4_SMALL_CFG, params, jnp.asarray(imgs))
+    for i, r in enumerate(reqs):
+        assert r.done
+        _assert_near_reference([h[None] for h in r.result],
+                               [w[i:i + 1] for w in want])
+    acts = {"mish": 36, "leaky": 35, "linear": 3}
+    assert cache.get(1).profile(reps=1)["conv_act"] == acts
+    assert cache.stats()["conv_act"] == {k: 2 * v for k, v in acts.items()}
+
+
+@pytest.mark.parametrize("h, size, stride", [
+    (19, 5, 1), (19, 9, 1), (19, 13, 1), (8, 9, 1), (8, 13, 1),
+    (224, 2, 2), (13, 2, 2), (13, 3, 2), (12, 3, 2), (7, 2, 1)])
+def test_maxpool_follows_darknet_padding(h, size, stride):
+    """[maxpool] with darknet's default padding (size - 1): the planned
+    shape, and the values against a plain reduce_window over a -inf
+    border of pad // 2 before and pad - pad // 2 after."""
+    cfg = (f"[net]\nheight={h}\nwidth={h + 1}\nchannels=4\n"
+           f"[maxpool]\nsize={size}\nstride={stride}\n")
+    net = Network(cfg)
+    x = jax.random.normal(jax.random.PRNGKey(h * size), (2, h, h + 1, 4))
+    got = net.apply({}, x)
+    want = _darknet_maxpool(x, size, stride, size - 1)
+    assert got.shape == want.shape == (2,) + net.out_shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert np.all(np.isfinite(np.asarray(got)))
+
+
+def test_maxpool_explicit_padding_and_scope():
+    """An explicit ``padding`` wins over darknet's default, and the pool
+    runs under the `repro.layer.maxpool` scope."""
+    assert cfg_mod.maxpool_pad({"padding": 0}, 3) == 0
+    assert cfg_mod.maxpool_pad({}, 3) == 2
+    net = Network("[net]\nheight=9\nwidth=9\nchannels=2\n"
+                  "[maxpool]\nsize=3\nstride=2\npadding=0\n")
+    assert net.out_shape == (4, 4, 2)
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 9, 9, 2))
+    np.testing.assert_array_equal(np.asarray(net.apply({}, x)),
+                                  np.asarray(_darknet_maxpool(x, 3, 2, 0)))
+    hlo = jax.jit(net.apply).lower({}, x).as_text(debug_info=True)
+    assert L.LAYER_SCOPE_PREFIX + "maxpool" in hlo
+
+
+def test_yolo_scale_x_y():
+    """x, y = σ * s - (s - 1) / 2; the rest as at s = 1, whose program is
+    the logistic alone, as before `scale_x_y`."""
+    x = jnp.linspace(-4.0, 4.0, 2 * 21).reshape(1, 1, 2, 21)
+    y = np.asarray(L.yolo(x, classes=2, scale_x_y=1.2)).reshape(-1, 7)
+    base = np.asarray(L.yolo(x, classes=2)).reshape(-1, 7)
+    sig = 1 / (1 + np.exp(-np.asarray(x, np.float64).reshape(-1, 7)))
+    np.testing.assert_allclose(y[:, :2], sig[:, :2] * 1.2 - 0.1,
+                               rtol=0, atol=1e-7)
+    np.testing.assert_array_equal(y[:, 2:], base[:, 2:])
+
+    def logistic_only(x):
+        entry = np.arange(x.shape[-1]) % 7
+        return jnp.where((entry < 2) | (entry >= 4), jax.nn.sigmoid(x), x)
+    assert str(jax.make_jaxpr(
+        lambda x: L.yolo(x, classes=2, scale_x_y=1.0))(x)) == \
+        str(jax.make_jaxpr(logistic_only)(x))
